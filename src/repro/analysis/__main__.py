@@ -18,6 +18,7 @@ import argparse
 import pathlib
 import sys
 
+from ..compile_cache import enable_compile_cache
 from . import AUDIT_CHANNELS, AUDIT_PLACEMENTS, AUDIT_ROUNDS, \
     audit_registry
 
@@ -49,6 +50,7 @@ def main(argv=None) -> int:
     ap.add_argument("--no-report", action="store_true",
                     help="print the verdict but write no files")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     report = audit_registry(
         channels=tuple(args.channels or AUDIT_CHANNELS),

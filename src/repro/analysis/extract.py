@@ -18,7 +18,7 @@ import dataclasses
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
-import jax
+import jax.extend.core as jex
 import jax.numpy as jnp
 
 from ..core.comm import CommLedger, parse_comm_scope
@@ -37,9 +37,9 @@ def _sub_jaxprs(eqn) -> Iterator[Tuple[str, Any]]:
         many = isinstance(val, (list, tuple))
         for j, item in enumerate(items):
             sub = None
-            if isinstance(item, jax.core.ClosedJaxpr):
+            if isinstance(item, jex.ClosedJaxpr):
                 sub = item.jaxpr
-            elif isinstance(item, jax.core.Jaxpr):
+            elif isinstance(item, jex.Jaxpr):
                 sub = item
             if sub is not None:
                 yield (f"{key}[{j}]" if many else key), sub

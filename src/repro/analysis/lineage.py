@@ -32,7 +32,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, FrozenSet, List, Tuple
 
-import jax
+import jax.extend.core as jex
 
 from .extract import TracedStep, comm_token, format_eqn, iter_eqns
 from .findings import Finding
@@ -99,7 +99,7 @@ class _Env:
         self._d: Dict[Any, Dims] = {}
 
     def read(self, v) -> Dims:
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, jex.Literal):
             return _EMPTY
         return self._d.get(v, _EMPTY)
 

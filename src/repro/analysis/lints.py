@@ -23,7 +23,7 @@ from __future__ import annotations
 
 from typing import List, Tuple
 
-import jax
+import jax.extend.core as jex
 
 from .extract import TracedStep, format_eqn, iter_eqns
 from .findings import Finding
@@ -58,7 +58,7 @@ def lint_weak_literals(steps: List[TracedStep], algorithm: str = "",
         seen = set()
         for eqn, path in iter_eqns(ts.closed.jaxpr):
             for v in eqn.invars:
-                if not isinstance(v, jax.core.Literal):
+                if not isinstance(v, jex.Literal):
                     continue
                 aval = v.aval
                 if getattr(aval, "weak_type", False) \

@@ -20,10 +20,10 @@ kernel applies channel stage ``rnd + 1`` to the upload it emits — the
 stage the composed path would apply when that upload is actually sent.
 
 The ledger cannot tell the difference by construction (metadata-only
-records, identical tags/shapes/pricing); the iterates are bit-identical
-to the composed ``kernel`` backend because the kernel's dots see the
-same single-tile padded operands and the epilogue/update runs the same
-f32 op order (``tests/test_ledger_invariance.py`` pins both).
+records, identical tags/shapes/pricing); the iterates agree with the
+composed ``kernel`` backend's to f32 rounding, since the whole-block
+dots and the composed tilings may round their sums differently in the
+last ulp (``tests/test_ledger_invariance.py`` pins both).
 """
 from __future__ import annotations
 

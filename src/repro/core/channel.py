@@ -99,8 +99,37 @@ def _hash_uniform(x: jnp.ndarray) -> jnp.ndarray:
     h = (h ^ (h >> 16)) * jnp.uint32(0x45D9F3B)
     h = (h ^ (h >> 16)) * jnp.uint32(0x45D9F3B)
     h = h ^ (h >> 16)
-    # keep 24 bits so the uniform is exact in f32
-    return (h >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    # keep 24 bits so the uniform is exact in f32; below 2^24 the bits
+    # read the same as int32, and Mosaic converts only signed ints
+    top = lax.bitcast_convert_type(h >> 8, jnp.int32)
+    return top.astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+
+
+def _fp16_round_trip(x: jnp.ndarray) -> jnp.ndarray:
+    """``x.astype(float16).astype(float32)`` for f32 ``x`` in int32 ops.
+
+    Mosaic cannot lower an f16 vector cast, so the whole-round kernel
+    rounds to the half grid on the f32 bit pattern instead: nearest-even
+    to 10 mantissa bits in the normal range (overflow to inf), nearest-
+    even to multiples of 2^-24 below 2^-14 (half subnormals), sign kept
+    (so -0 stays -0).  NaNs pass through as NaN.  Equal bit for bit to
+    the cast everywhere else (``tests/test_channel.py`` pins it)."""
+    bits = lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    sign = bits & jnp.int32(-0x80000000)
+    a = bits & jnp.int32(0x7FFFFFFF)
+    exp = a >> 23
+    # normal half: drop 13 mantissa bits, ties to even
+    r = (a + jnp.int32(0x0FFF) + ((a >> 13) & 1)) & jnp.int32(0x7FFFE000)
+    r = jnp.where(r >= jnp.int32(0x47800000), jnp.int32(0x7F800000), r)
+    # subnormal half: q = round(|x| * 2^24), ties to even
+    mant = (a & jnp.int32(0x7FFFFF)) | jnp.int32(0x800000)
+    s = jnp.minimum(jnp.int32(126) - exp, jnp.int32(25))
+    q = (mant + (jnp.int32(1) << (s - 1)) - 1 + ((mant >> s) & 1)) >> s
+    sub = lax.bitcast_convert_type(
+        q.astype(jnp.float32) * jnp.float32(2.0 ** -24), jnp.int32)
+    out = jnp.where(exp >= 113, r, sub)
+    out = jnp.where(a > jnp.int32(0x7F800000), a, out)       # NaN
+    return lax.bitcast_convert_type(out | sign, jnp.float32).astype(x.dtype)
 
 
 def stochastic_round(y: jnp.ndarray, u: jnp.ndarray) -> jnp.ndarray:
@@ -142,7 +171,7 @@ class Channel:
         if self.kind == "identity":
             return x
         if self.kind == "fp16":
-            return x.astype(jnp.float16).astype(x.dtype)
+            return _fp16_round_trip(x)
         if self.kind == "bf16":
             return x.astype(jnp.bfloat16).astype(x.dtype)
         if self.kind == "int8":
@@ -483,7 +512,9 @@ def parse_channel(channel: Union[None, str, AnyChannel]) -> AnyChannel:
         return _IDENTITY
     if isinstance(channel, (Channel, ScheduledChannel, GapChannel)):
         return channel
-    name = str(channel).strip()
+    # exact names only: "identity " is not a channel (the env var path
+    # strips at its own boundary, api/_axes.py)
+    name = str(channel)
     if name.startswith("sched:"):
         return _parse_sched(name)
     if name.startswith("gap:"):

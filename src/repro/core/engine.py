@@ -281,6 +281,47 @@ def scheduled_channel(dist):
     return chan if getattr(chan, "scheduled", False) else None
 
 
+# Closed-over arrays at least this large enter a compiled program as
+# arguments (``hoisted_jit``); smaller ones stay embedded constants.
+HOIST_BYTES = 1 << 20
+
+
+def hoisted_jit(fn: Callable) -> Callable:
+    """``jax.jit(fn)``, with every large array ``fn`` closes over passed
+    to the compiled program as an argument.
+
+    Captured, XLA embeds each closed-over array (a cell's A_j blocks,
+    labels, the objective's data) in the program as a constant: at
+    deployment sizes that copies gigabytes through lowering, compile and
+    the compilation cache, and again into device memory.  Here ``fn`` is
+    traced once per argument signature and its consts of ``HOIST_BYTES``
+    or more are fed back as arguments of one jitted evaluator.  Smaller
+    consts (step sizes, small instances) stay constants, so the program
+    XLA compiles for them is the one ``jax.jit(fn)`` would."""
+    traced = {}
+
+    def call(*args):
+        leaves, tree = jax.tree.flatten(args)
+        key = (tree, tuple(jax.typeof(x) for x in leaves))
+        if key not in traced:
+            closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(*args)
+            big = [i for i, c in enumerate(closed.consts)
+                   if getattr(c, "nbytes", 0) >= HOIST_BYTES]
+
+            def run(hoisted, *flat):
+                consts = list(closed.consts)
+                for i, c in zip(big, hoisted):
+                    consts[i] = c
+                return jax.core.eval_jaxpr(closed.jaxpr, consts, *flat)
+
+            traced[key] = (jax.jit(run), [closed.consts[i] for i in big],
+                           jax.tree.structure(out_shape))
+        run, hoisted, out_tree = traced[key]
+        return jax.tree.unflatten(out_tree, run(hoisted, *leaves))
+
+    return call
+
+
 def _build_runner(dist, step: Callable, measure, history, scheduled: bool):
     collect_w = history and measure is None
 
@@ -295,7 +336,7 @@ def _build_runner(dist, step: Callable, measure, history, scheduled: bool):
             return carry, measure(w)
         return carry, (w if collect_w else None)
 
-    return jax.jit(lambda carry, xs: lax.scan(body, carry, xs))
+    return hoisted_jit(lambda carry, xs: lax.scan(body, carry, xs))
 
 
 def _run_scan(dist, program, measure, history,

@@ -22,6 +22,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Optional
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 
@@ -120,8 +121,15 @@ class ERMProblem:
         return self.A.T @ (h * (self.A @ v)) / self.n + self.lam * v
 
     def smoothness_bound(self) -> float:
-        """L <= ell''_max * sigma_max(A)^2 / n + lam."""
-        smax = jnp.linalg.norm(self.A, ord=2)
+        """L <= ell''_max * sigma_max(A)^2 / n + lam.
+
+        One scalar of set-up.  Off the CPU platform it is taken by the
+        host's LAPACK: the TPU's SVD takes minutes to compile at d in the
+        thousands, LAPACK seconds to run."""
+        if jax.default_backend() == "cpu":
+            smax = jnp.linalg.norm(self.A, ord=2)
+        else:
+            smax = np.linalg.norm(np.asarray(self.A), ord=2)
         return float(self.loss.smoothness * smax ** 2 / self.n + self.lam)
 
     # ---- feature-partitioned oracles (machine-local pieces) ------------

@@ -27,6 +27,7 @@ from jax import lax
 from repro.core import (ChainInstance, ERMProblem, make_random_erm,
                         squared_loss)
 from repro.core.algorithms import soft_threshold
+from repro.core.engine import hoisted_jit
 from repro.core.partition import FeaturePartition, even_partition
 
 from .registry import AlgoContext
@@ -62,10 +63,10 @@ class InstanceBundle:
 # Shared construction helpers
 # --------------------------------------------------------------------------
 
-def _make_context(prob: ERMProblem, part: FeaturePartition,
+def _make_context(prob: ERMProblem, part: FeaturePartition, L: float,
                   prox: Optional[Callable] = None) -> AlgoContext:
-    """Derive every constant the registered adapters may ask for."""
-    L = prob.smoothness_bound()
+    """Derive every constant the registered adapters may ask for, given
+    ``L = prob.smoothness_bound()`` (each builder computes it once)."""
     sm = prob.loss.smoothness
     A = np.asarray(prob.A)
     block_L = np.array(
@@ -113,11 +114,10 @@ def smooth_chain_erm(d: int, L: float):
     return prob, jnp.asarray(wstar)
 
 
-def _reference_solution(prob: ERMProblem, iters: int,
+def _reference_solution(prob: ERMProblem, iters: int, L: float,
                         prox: Optional[Callable] = None) -> jnp.ndarray:
     """High-accuracy reference minimizer for workloads with no closed form:
     full-vector (non-distributed) FISTA / accelerated gradient, jitted."""
-    L = prob.smoothness_bound()
     lam = prob.lam
     grad = jax.grad(prob.value) if prox is None else prob.gradient
     px = prox if prox is not None else (lambda w, s: w)
@@ -131,7 +131,8 @@ def _reference_solution(prob: ERMProblem, iters: int,
             return x_new, x_new + beta * (x_new - x)
 
         x0 = jnp.zeros((prob.d,))
-        x, _ = jax.jit(lambda c: lax.fori_loop(0, iters, body, c))((x0, x0))
+        x, _ = hoisted_jit(lambda c: lax.fori_loop(0, iters, body, c))(
+            (x0, x0))
         return x
 
     def body(_, carry):
@@ -142,7 +143,7 @@ def _reference_solution(prob: ERMProblem, iters: int,
         return x_new, y_new, t_new
 
     x0 = jnp.zeros((prob.d,))
-    x, _, _ = jax.jit(lambda c: lax.fori_loop(0, iters, body, c))(
+    x, _, _ = hoisted_jit(lambda c: lax.fori_loop(0, iters, body, c))(
         (x0, x0, jnp.asarray(1.0)))
     return x
 
@@ -161,7 +162,8 @@ def build_thm2_chain(d: int = 160, kappa: float = 64.0, lam: float = 0.5,
     fstar = float(prob.value(wstar))
     return InstanceBundle(
         kind="thm2_chain", hard=True, prob=prob, part=part,
-        ctx=_make_context(prob, part), objective=prob.value,
+        ctx=_make_context(prob, part, prob.smoothness_bound()),
+        objective=prob.value,
         fstar=fstar, wstar_norm=float(jnp.linalg.norm(wstar)),
         params=dict(d=d, kappa=kappa, lam=lam, m=m, n=prob.n))
 
@@ -174,7 +176,8 @@ def build_thm3_chain(d: int = 128, L: float = 1.0, m: int = 4
     fstar = float(prob.value(wstar))
     return InstanceBundle(
         kind="thm3_chain", hard=True, prob=prob, part=part,
-        ctx=_make_context(prob, part), objective=prob.value,
+        ctx=_make_context(prob, part, prob.smoothness_bound()),
+        objective=prob.value,
         fstar=fstar, wstar_norm=float(jnp.linalg.norm(wstar)),
         params=dict(d=d, L=L, m=m, n=prob.n))
 
@@ -189,10 +192,11 @@ def build_thm4_separable(n: int = 32, kappa: float = 64.0, lam: float = 0.5,
     part = even_partition(prob.d, m)
     wstar = jnp.asarray(ci.w_star())
     fstar = float(prob.value(wstar))
-    kappa_erm = prob.smoothness_bound() / prob.lam
+    L = prob.smoothness_bound()
+    kappa_erm = L / prob.lam
     return InstanceBundle(
         kind="thm4_separable", hard=True, prob=prob, part=part,
-        ctx=_make_context(prob, part), objective=prob.value,
+        ctx=_make_context(prob, part, L), objective=prob.value,
         fstar=fstar, wstar_norm=float(jnp.linalg.norm(wstar)),
         params=dict(n=n, kappa=kappa_erm, lam=lam, m=m, d=prob.d))
 
@@ -220,13 +224,14 @@ def build_lasso(n: int = 128, d: int = 256, m: int = 4, tau: float = 2e-3,
     def objective(w):
         return prob.value(w) + tau * jnp.sum(jnp.abs(w))
 
-    wref = _reference_solution(prob, ref_iters, prox=prox)
+    L = prob.smoothness_bound()
+    wref = _reference_solution(prob, ref_iters, L, prox=prox)
     return InstanceBundle(
         kind="lasso", hard=False, prob=prob, part=part,
-        ctx=_make_context(prob, part, prox=prox), objective=objective,
+        ctx=_make_context(prob, part, L, prox=prox), objective=objective,
         fstar=float(objective(wref)),
         wstar_norm=float(jnp.linalg.norm(wref)),
-        params=dict(n=n, d=d, m=m, tau=tau, L=prob.smoothness_bound()))
+        params=dict(n=n, d=d, m=m, tau=tau, L=L))
 
 
 def build_logistic(n: int = 256, d: int = 96, m: int = 4, lam: float = 1e-2,
@@ -235,11 +240,12 @@ def build_logistic(n: int = 256, d: int = 96, m: int = 4, lam: float = 1e-2,
     data — the paper's motivating GLM workload."""
     prob = make_random_erm(n=n, d=d, loss="logistic", lam=lam, seed=seed)
     part = even_partition(d, m)
-    wref = _reference_solution(prob, ref_iters)
-    kappa = prob.smoothness_bound() / lam
+    L = prob.smoothness_bound()
+    wref = _reference_solution(prob, ref_iters, L)
+    kappa = L / lam
     return InstanceBundle(
         kind="logistic", hard=False, prob=prob, part=part,
-        ctx=_make_context(prob, part), objective=prob.value,
+        ctx=_make_context(prob, part, L), objective=prob.value,
         fstar=float(prob.value(wref)),
         wstar_norm=float(jnp.linalg.norm(wref)),
         params=dict(n=n, d=d, m=m, lam=lam, kappa=kappa))
@@ -253,7 +259,8 @@ def build_random_ridge(n: int = 256, d: int = 64, m: int = 8,
     part = even_partition(d, m)
     return InstanceBundle(
         kind="random_ridge", hard=False, prob=prob, part=part,
-        ctx=_make_context(prob, part), objective=prob.value,
+        ctx=_make_context(prob, part, prob.smoothness_bound()),
+        objective=prob.value,
         fstar=None, wstar_norm=None,
         params=dict(n=n, d=d, m=m, lam=lam))
 

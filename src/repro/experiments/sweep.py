@@ -41,6 +41,7 @@ import sys
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 from repro import api
+from repro.compile_cache import enable_compile_cache
 
 from .instances import build_instance
 
@@ -529,6 +530,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                         help="run and print, but write nothing")
     parser.add_argument("-q", "--quiet", action="store_true")
     args = parser.parse_args(argv)
+    enable_compile_cache()
 
     for flag, field, value in (("--backend", "backend", args.backend),
                                ("--engine", "engine", args.engine)):

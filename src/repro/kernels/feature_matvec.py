@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
 
@@ -47,8 +48,7 @@ def _matvec_kernel(a_ref, w_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += jnp.dot(a_ref[...], w_ref[...],
-                          preferred_element_type=o_ref.dtype)
+    o_ref[...] += _dot(a_ref[...], w_ref[...], o_ref.dtype)
 
 
 def feature_matvec(A_j, w_j, *, block_n: int = BLOCK_N,
@@ -90,8 +90,7 @@ def _rmatvec_kernel(a_ref, r_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += jnp.dot(a_ref[...].T, r_ref[...],
-                          preferred_element_type=o_ref.dtype)
+    o_ref[...] += _dot(a_ref[...].T, r_ref[...], o_ref.dtype)
 
 
 def feature_rmatvec(A_j, r, *, block_n: int = BLOCK_N,
@@ -134,8 +133,7 @@ def _hvp_kernel(a_ref, h_ref, r_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += jnp.dot(a_ref[...].T, h_ref[...] * r_ref[...],
-                          preferred_element_type=o_ref.dtype)
+    o_ref[...] += _dot(a_ref[...].T, h_ref[...] * r_ref[...], o_ref.dtype)
 
 
 def feature_hvp(A_j, h, av, *, block_n: int = BLOCK_N,
@@ -175,6 +173,16 @@ def feature_hvp(A_j, h, av, *, block_n: int = BLOCK_N,
 
 
 # ---- helpers ---------------------------------------------------------------
+
+def _dot(a, b, out_dtype):
+    """An MXU product exact to the operands' precision.  Mosaic's default
+    takes f32 operands through one bf16 pass (relative error ~1e-3),
+    enough to keep a certified solve from ever reaching eps = 1e-6; bf16
+    operands are exact by default (and refuse HIGHEST)."""
+    f32 = a.dtype == jnp.float32 and b.dtype == jnp.float32
+    return jnp.dot(a, b, preferred_element_type=out_dtype,
+                   precision=lax.Precision.HIGHEST if f32 else None)
+
 
 def _rup(x: int, to: int = 128) -> int:
     return max(to, (x + to - 1) // to * to)

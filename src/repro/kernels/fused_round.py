@@ -32,18 +32,21 @@ kernels here collapse all of that:
   (``/n + lam v``, block mask) folded into the last contraction block —
   one A-read per oracle instead of an extra d-vector HBM round-trip.
 
-Bit-identity contract: wherever ``round_step_supported`` admits a cell,
-the fused step's iterates, uploads and ledger stream are bit-identical
-to the composed ``kernel`` backend.  That holds because (a) the single
-whole-block dots see the same padded operands as the one-block tilings
-of ``feature_matvec``/``feature_rmatvec`` (the support gate caps blocks
-at one tile), (b) the epilogue/update arithmetic runs in the same f32
-op order as the composed jnp epilogues, and (c) ``Channel.apply`` is
-invoked verbatim inside the kernel body — elementwise transforms do not
-care that the payload is the padded (n_pad, 1) column (int8's
-per-message max is unchanged by |0| padding; pad lanes are sliced off
-before the wire).  ``tests/test_ledger_invariance.py`` and
-``tests/test_kernel_properties.py`` pin all of this.
+Conformance contract: wherever ``round_step_fits`` and
+``channel_stages`` admit a cell, the fused step's ledger stream and
+round marks are identical to the composed ``kernel`` backend's, and its
+iterates and uploads agree with them to f32 rounding.  The ledger holds
+by construction (metadata-only records, identical tags, shapes and
+pricing).  The iterates are not bit-identical because the whole-block
+dots and the composed kernels' tilings are different programs whose
+sums may round differently in the last ulp; the epilogue and update
+arithmetic runs in the same f32 op order as the composed jnp epilogues,
+and ``Channel.apply`` runs verbatim inside the kernel body — elementwise
+transforms do not care that the payload is the padded (n_pad, 1) column
+(int8's per-message max is unchanged by |0| padding; pad lanes are
+sliced off before the wire).  ``tests/test_ledger_invariance.py`` and
+``tests/test_kernel_properties.py`` pin all of this, and
+``tests/test_tpu_compile.py`` compiles the kernel for a TPU v5e.
 """
 from __future__ import annotations
 
@@ -54,15 +57,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .feature_matvec import (BLOCK_B, BLOCK_D, BLOCK_N, _acc_dtype,
-                             _interp, _pad2, _rup)
+                             _dot, _interp, _pad2, _rup)
 from ..core.channel import Channel, ScheduledChannel
 
 # The whole-round kernel keeps machine j's entire padded A_j block in
 # one VMEM tile, so it only engages when that tile is a single
-# MXU-aligned block (which is also what makes its dots bit-identical to
-# the composed kernels' one-block tilings).
+# MXU-aligned block.
 ROUND_STEP_MAX_N = BLOCK_N
 ROUND_STEP_MAX_D = BLOCK_D
+
+# The whole-round kernel's name in compiled programs and device traces.
+KERNEL_NAME = "fused_round_step"
 
 # VMEM budget for one grid step (A block + vectors, double-buffered).
 # ~16 MiB/core on current TPUs; stay at half to leave room for the
@@ -70,9 +75,11 @@ ROUND_STEP_MAX_D = BLOCK_D
 ROUND_STEP_VMEM_BYTES = 8 * 1024 * 1024
 
 # Channel stages the kernel can reproduce bit-identically in-body:
-# everything elementwise (plus int8's per-message max).  topk needs
-# lax.top_k over the full message — not a Mosaic-friendly shape — so
-# topk cells fall back to the composed path.
+# everything elementwise (plus int8's per-message max).  fp16 qualifies
+# because ``Channel.apply`` rounds to the half grid with int32 ops
+# (Mosaic has no f16 vector cast).  topk needs lax.top_k over the full
+# message — not a Mosaic-friendly shape — so topk cells fall back to the
+# composed path.
 IN_KERNEL_STAGES = ("identity", "fp16", "bf16", "int8")
 
 
@@ -145,7 +152,7 @@ def make_round_step(A_stk, mask, y_data, loss, *, n: int, lam: float,
     A_p = jnp.pad(jnp.asarray(A_stk, jnp.float32),
                   ((0, 0), (0, n_pad - n), (0, d_pad - d_max)))
     mask_p = jnp.pad(jnp.asarray(mask, jnp.float32),
-                     ((0, 0), (0, d_pad - d_max)))
+                     ((0, 0), (0, d_pad - d_max)))[:, None, :]
     yd_p = jnp.pad(jnp.asarray(y_data, jnp.float32)[:, None],
                    ((0, n_pad - n), (0, 0)))
     # pad rows contribute nothing to the dots (A pad rows are zero), but
@@ -156,10 +163,10 @@ def make_round_step(A_stk, mask, y_data, loss, *, n: int, lam: float,
 
     def _round_math(a, z, yd, nm, x, y, mk, coeff, rnd):
         lg = loss.grad(z, yd) * nm
-        g = jnp.dot(a.T, lg, preferred_element_type=jnp.float32).T / n
+        g = _dot(a.T, lg, jnp.float32).T / n
         g = (g + lam * y) * mk
         x_new, y_new = update(x, y, g, coeff)
-        zloc = jnp.dot(a, y_new.T, preferred_element_type=jnp.float32)
+        zloc = _dot(a, y_new.T, jnp.float32)
         zloc = _apply_stage(stages, zloc, rnd + 1)
         return x_new, y_new, zloc.T
 
@@ -169,8 +176,7 @@ def make_round_step(A_stk, mask, y_data, loss, *, n: int, lam: float,
     # Pallas body cannot capture such constants, so trace the round
     # math once, hoist the jaxpr's consts, and feed each back in as an
     # extra kernel operand (reshaped to a (1, size) VMEM row).  The
-    # body replays the jaxpr verbatim — same ops, same order, so the
-    # bit-identity argument above is unchanged.
+    # body replays the jaxpr verbatim — same ops, same order.
     z = jnp.zeros
     closed = jax.make_jaxpr(_round_math)(
         z((n_pad, d_pad), jnp.float32),
@@ -196,39 +202,39 @@ def make_round_step(A_stk, mask, y_data, loss, *, n: int, lam: float,
         cvals = [cr[0, 0] if c.ndim == 0 else cr[...].reshape(c.shape)
                  for cr, c in zip(c_refs, consts)]
         x_new, y_new, zloc_t = math_fn(
-            a_ref[0], z_ref[...], yd_ref[...], nm_ref[...],
+            a_ref[...], z_ref[...], yd_ref[...], nm_ref[...],
             x_ref[...], y_ref[...], mk_ref[...],
             cf_ref[0, 0], rn_ref[0, 0], *cvals)
         xo_ref[...] = x_new
         yo_ref[...] = y_new
         zo_ref[...] = zloc_t
 
+    # Per-machine rows travel as (m, 1, width) with the machine axis
+    # squeezed out of the block, so each block's last two dims equal the
+    # array's — the only way Mosaic accepts a one-row block.
+    def row(width):
+        return pl.BlockSpec((pl.Squeezed(), 1, width), lambda j: (j, 0, 0))
+
+    def shared(shape):
+        return pl.BlockSpec(shape, lambda j: (0,) * len(shape))
+
     call = pl.pallas_call(
         body,
         grid=(m,),
         in_specs=[
-            pl.BlockSpec((1, n_pad, d_pad), lambda j: (j, 0, 0)),
-            pl.BlockSpec((n_pad, 1), lambda j: (0, 0)),
-            pl.BlockSpec((n_pad, 1), lambda j: (0, 0)),
-            pl.BlockSpec((n_pad, 1), lambda j: (0, 0)),
-            pl.BlockSpec((1, d_pad), lambda j: (j, 0)),
-            pl.BlockSpec((1, d_pad), lambda j: (j, 0)),
-            pl.BlockSpec((1, d_pad), lambda j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda j: (0, 0)),
-            pl.BlockSpec((1, 1), lambda j: (0, 0)),
-        ] + [pl.BlockSpec(c.shape, lambda j: (0, 0))
-             for c in const_rows],
-        out_specs=[
-            pl.BlockSpec((1, d_pad), lambda j: (j, 0)),
-            pl.BlockSpec((1, d_pad), lambda j: (j, 0)),
-            pl.BlockSpec((1, n_pad), lambda j: (j, 0)),
-        ],
+            pl.BlockSpec((pl.Squeezed(), n_pad, d_pad), lambda j: (j, 0, 0)),
+            shared((n_pad, 1)), shared((n_pad, 1)), shared((n_pad, 1)),
+            row(d_pad), row(d_pad), row(d_pad),
+            shared((1, 1)), shared((1, 1)),
+        ] + [shared(c.shape) for c in const_rows],
+        out_specs=[row(d_pad), row(d_pad), row(n_pad)],
         out_shape=[
-            jax.ShapeDtypeStruct((m, d_pad), jnp.float32),
-            jax.ShapeDtypeStruct((m, d_pad), jnp.float32),
-            jax.ShapeDtypeStruct((m, n_pad), jnp.float32),
+            jax.ShapeDtypeStruct((m, 1, d_pad), jnp.float32),
+            jax.ShapeDtypeStruct((m, 1, d_pad), jnp.float32),
+            jax.ShapeDtypeStruct((m, 1, n_pad), jnp.float32),
         ],
         interpret=_interp(interpret),
+        name=KERNEL_NAME,
     )
 
     # The cell's data (A_p, labels, masks, hoisted algorithm consts)
@@ -243,13 +249,13 @@ def make_round_step(A_stk, mask, y_data, loss, *, n: int, lam: float,
               rnd):
         z_col = jnp.asarray(z, jnp.float32)[:, None]
         z_p = jnp.pad(z_col, ((0, n_pad - n), (0, 0)))
-        x_p = _pad2(jnp.asarray(x_stk, jnp.float32), 1, d_pad)
-        y_p = _pad2(jnp.asarray(y_stk, jnp.float32), 1, d_pad)
+        x_p = _pad2(jnp.asarray(x_stk, jnp.float32), 1, d_pad)[:, None, :]
+        y_p = _pad2(jnp.asarray(y_stk, jnp.float32), 1, d_pad)[:, None, :]
         cf = jnp.asarray(coeff, jnp.float32).reshape(1, 1)
         rn = jnp.asarray(rnd, jnp.int32).reshape(1, 1)
         x_new, y_new, zloc = call(A_p, z_p, yd_p, nmask, x_p, y_p,
                                   mask_p, cf, rn, *crows)
-        return (x_new[:, :d_max], y_new[:, :d_max], zloc[:, :n])
+        return (x_new[:, 0, :d_max], y_new[:, 0, :d_max], zloc[:, 0, :n])
 
     def step(z, x_stk, y_stk, coeff, rnd):
         return _step(A_p, yd_p, nmask, mask_p, tuple(const_rows),
@@ -273,8 +279,7 @@ def _pgrad_kernel(a_ref, r_ref, w_ref, mk_ref, o_ref, *, n, lam):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += jnp.dot(a_ref[...].T, r_ref[...],
-                          preferred_element_type=o_ref.dtype)
+    o_ref[...] += _dot(a_ref[...].T, r_ref[...], o_ref.dtype)
 
     @pl.when(i == pl.num_programs(2) - 1)
     def _epilogue():
@@ -332,8 +337,7 @@ def _phvp_kernel(a_ref, h_ref, r_ref, v_ref, mk_ref, o_ref, *, n, lam):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += jnp.dot(a_ref[...].T, h_ref[...] * r_ref[...],
-                          preferred_element_type=o_ref.dtype)
+    o_ref[...] += _dot(a_ref[...].T, h_ref[...] * r_ref[...], o_ref.dtype)
 
     @pl.when(i == pl.num_programs(2) - 1)
     def _epilogue():

@@ -10,7 +10,8 @@
 Envelopes stream to stdout as JSON lines as verdicts complete (per-
 client submission order); rejected payloads become
 ``{"status": "rejected", ...}`` lines.  Service stats go to stderr.
-Exit status is non-zero iff any payload was rejected.
+Exit status is non-zero iff any payload was rejected or any envelope
+is a dead letter (``status="error"``).
 """
 from __future__ import annotations
 
@@ -19,6 +20,7 @@ import json
 import sys
 from typing import Optional, Sequence
 
+from ..compile_cache import enable_compile_cache
 from .service import CertificationService
 from .workload import Arrival, DEFAULT_STRUCTURES, synthetic_trace
 
@@ -67,6 +69,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     parser.add_argument("--cache-capacity", type=int, default=32)
     parser.add_argument("--max-depth", type=int, default=4096)
     args = parser.parse_args(argv)
+    enable_compile_cache()
 
     if args.demo is not None:
         per = max(1, -(-args.demo // len(DEFAULT_STRUCTURES)))
@@ -79,7 +82,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                                    max_wait=args.max_wait,
                                    cache_capacity=args.cache_capacity,
                                    max_depth=args.max_depth)
-    rejected = 0
+    rejected = dead = 0
 
     def on_reject(arrival, err):
         nonlocal rejected
@@ -92,7 +95,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     # stdout as their batches complete, not at end of trace.  Arrival
     # specs may be raw payloads (from --input); admission deserializes.
     def emit(envelopes):
+        nonlocal dead
         for env in envelopes:
+            dead += env.status != "ok"
             print(json.dumps(env.to_dict()), flush=True)
 
     last = 0.0
@@ -106,7 +111,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     emit(service.drain(last))
 
     print(f"[serve] {json.dumps(service.stats())}", file=sys.stderr)
-    return 1 if rejected else 0
+    return 1 if rejected or dead else 0
 
 
 if __name__ == "__main__":

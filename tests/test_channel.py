@@ -21,8 +21,8 @@ import jax.numpy as jnp
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core.channel import (CHANNELS, Channel, parse_channel,
-                                stochastic_round)
+from repro.core.channel import (CHANNELS, Channel, _hash_uniform,
+                                parse_channel, stochastic_round)
 from repro.core.engine import ENGINES, run_program
 from repro.core.runtime import ORACLE_BACKENDS, LocalDistERM
 from repro.experiments.instances import build_instance
@@ -112,6 +112,55 @@ def test_half_precision_roundtrip_and_idempotence(n, seed, scale):
         y = ch.apply(x)
         np.testing.assert_allclose(y, x, rtol=rel, atol=rel * scale)
         np.testing.assert_array_equal(ch.apply(y), y)   # idempotent
+
+
+def _f32(bits):
+    return np.asarray(bits, dtype=np.uint32).view(np.float32)
+
+
+def _same_bits(got, want):
+    got, want = np.asarray(got), np.asarray(want)
+    nan = np.isnan(want)
+    np.testing.assert_array_equal(np.isnan(got), nan)
+    np.testing.assert_array_equal(got.view(np.uint32)[~nan],
+                                  want.view(np.uint32)[~nan])
+
+
+# 0, -0, f32 and half subnormals, the half normal/subnormal edge, +-max
+# half (65504), the overflow edge (65519.996 rounds down, 65520 up),
+# +-inf and NaN, plus random bit patterns across every exponent.
+_HALF_EDGES = np.concatenate([
+    np.array([0.0, -0.0, 1e-45, -1e-45, 1e-39, 2.0 ** -25, 2.0 ** -24,
+              1.5 * 2.0 ** -24, 2.5 * 2.0 ** -24, 3 * 2.0 ** -25,
+              2.0 ** -14, 2.0 ** -14 * (1 - 2.0 ** -11), 6.1e-5, 1.0,
+              1.0 + 2.0 ** -11, 1.0 + 3 * 2.0 ** -11, 65504.0, -65504.0,
+              65519.996, 65520.0, -65520.0, 1e6, -3.4e38, np.inf, -np.inf,
+              np.nan], np.float32),
+    _f32(np.random.RandomState(0).randint(0, 2 ** 32, 200_000,
+                                          dtype=np.uint64)),
+])
+
+
+def test_fp16_transform_matches_the_half_cast_bit_for_bit():
+    """The fp16 wire rounds to the half grid with int32 ops (Mosaic has
+    no f16 vector cast); it must equal the plain cast it replaced."""
+    x = jnp.asarray(_HALF_EDGES)
+    _same_bits(jax.jit(parse_channel("fp16").apply)(x),
+               x.astype(jnp.float16).astype(jnp.float32))
+
+
+def test_int8_uniforms_match_the_unsigned_cast_bit_for_bit():
+    """The hash uniforms go through int32 before f32 (Mosaic converts
+    only signed ints); below 2^24 that is the same value as the old
+    uint32 -> f32 cast."""
+    x = jnp.asarray(_HALF_EDGES)
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    h = bits ^ jnp.uint32(0x9E3779B9)
+    h = (h ^ (h >> 16)) * jnp.uint32(0x45D9F3B)
+    h = (h ^ (h >> 16)) * jnp.uint32(0x45D9F3B)
+    h = h ^ (h >> 16)
+    want = (h >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    _same_bits(_hash_uniform(x), want)
 
 
 def test_stochastic_round_unbiased_under_uniform_offsets():

@@ -65,7 +65,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import json
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import CommLedger, make_random_erm
 from repro.core.comm import collective_bytes_from_lowered
@@ -76,9 +75,9 @@ out = {}
 
 # (1) toy module: one all_gather, known payload
 mesh = Mesh(np.array(jax.devices()), ("x",))
-gather = shard_map(lambda a: jax.lax.all_gather(a, "x"), mesh=mesh,
+gather = jax.shard_map(lambda a: jax.lax.all_gather(a, "x"), mesh=mesh,
                    in_specs=P("x"), out_specs=P(None, "x"),
-                   check_rep=False)
+                   check_vma=False)
 audit = collective_bytes_from_lowered(
     jax.jit(gather).lower(jnp.ones((4,), jnp.float32)))
 out["toy"] = {"counts": audit.count_by_op, "bytes": audit.bytes_by_op}

@@ -267,3 +267,34 @@ def test_sweep_records_engine_invariant():
             assert rec.pop("run_spec")["engine"] == eng
             ref.pop("run_spec")
             assert rec == ref, (eng, rec, ref)
+
+
+def test_hoisted_jit_passes_large_closures_as_arguments():
+    """A compiled run must not embed the cell's data as constants (at
+    deployment sizes that is gigabytes through lowering and compile):
+    ``hoisted_jit`` feeds large closed-over arrays in as arguments, and
+    still computes what ``jax.jit`` does."""
+    import warnings
+
+    import jax
+    from repro.core.engine import HOIST_BYTES, hoisted_jit
+
+    big = jnp.arange(HOIST_BYTES // 4 + 1, dtype=jnp.float32) / 7.0
+    small = jnp.float32(3.0)
+
+    def fn(x):
+        return jnp.sum(big * x) + small
+
+    before = jax.config.jax_captured_constants_warn_bytes
+    jax.config.update("jax_captured_constants_warn_bytes", HOIST_BYTES)
+    try:
+        with warnings.catch_warnings(record=True) as caught:
+            warnings.simplefilter("always")
+            got = hoisted_jit(fn)(jnp.float32(2.0))
+        assert not [w for w in caught if "constants were captured"
+                    in str(w.message)]
+        with pytest.warns(UserWarning, match="constants were captured"):
+            want = jax.jit(fn)(jnp.float32(2.0))
+    finally:
+        jax.config.update("jax_captured_constants_warn_bytes", before)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

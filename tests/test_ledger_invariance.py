@@ -213,11 +213,10 @@ def test_fused_conformance_matrix(algo_name, channel):
         move a single metered byte;
       * measured rounds-to-eps agree within the +/-1 threshold-crossing
         tolerance the sweep invariance test already grants;
-      * under the scan engine the fused iterates equal the kernel
-        iterates bit-for-bit (same ops, same order, one jit boundary);
-        under the python engine per-call jit boundaries already separate
-        einsum from kernel by an ulp, so fused gets the same float
-        tolerance those backends get against each other.
+      * the fused iterates agree with the kernel iterates to f32
+        rounding: the whole-round kernel and the composed kernels are
+        different programs, so their sums may round differently in the
+        last ulp (compiled Mosaic and interpret mode alike).
     """
     from repro import api
 
@@ -244,20 +243,18 @@ def test_fused_conformance_matrix(algo_name, channel):
         else:
             assert abs(got - ref_rounds) <= 1, (key, got, ref_rounds)
 
-    assert np.array_equal(np.asarray(runs[("fused", "scan")].w),
-                          np.asarray(runs[("kernel", "scan")].w))
-    fused_py = np.asarray(runs[("fused", "python")].w)
-    kernel_py = np.asarray(runs[("kernel", "python")].w)
-    if channel == "identity":
-        np.testing.assert_allclose(fused_py, kernel_py,
-                                   atol=1e-4, rtol=1e-4)
-    else:
-        # Quantized channels: a 1-ulp pre-quantization difference (the
-        # python engine's per-call jit boundaries) can flip a stochastic
-        # rounding decision, so iterates agree only to the accumulated
-        # quantization-noise envelope; convergence equivalence is pinned
-        # by the measured-rounds check above.
-        np.testing.assert_allclose(fused_py, kernel_py, atol=2e-2)
+    for eng in ENGINES:
+        fused = np.asarray(runs[("fused", eng)].w)
+        kernel = np.asarray(runs[("kernel", eng)].w)
+        if channel == "identity":
+            # 40 rounds of last-ulp differences on O(1) iterates
+            np.testing.assert_allclose(fused, kernel, atol=1e-4, rtol=1e-4)
+        else:
+            # Quantized channels: a 1-ulp pre-quantization difference can
+            # flip a stochastic rounding decision, so iterates agree only
+            # to the accumulated quantization-noise envelope; convergence
+            # equivalence is pinned by the measured-rounds check above.
+            np.testing.assert_allclose(fused, kernel, atol=2e-2)
 
 
 def test_faulted_ledger_bit_identical_across_backends_and_engines():

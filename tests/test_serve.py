@@ -5,6 +5,8 @@ time — so the soak trace produces the identical batch sequence, cache
 counters, and envelope stream on every run (CI replays it three times
 back-to-back to enforce exactly that).
 """
+import json
+
 import numpy as np
 import pytest
 
@@ -430,6 +432,21 @@ def test_retry_backoff_then_dead_letter_then_quarantine(monkeypatch):
     # a different spec is unaffected
     other = api.RunSpec(**dict(SMALL, rounds=4), engine="python")
     assert svc.submit(other, client_id="c", now=0.2) == "t000002"
+
+
+def test_cli_exits_nonzero_on_a_dead_letter(monkeypatch, tmp_path, capsys):
+    """An admitted spec that dead-letters fails the CLI run, exactly as a
+    rejected payload does: a smoke run must not exit 0 over lost work."""
+    from repro.serve.__main__ import main as serve_main
+    monkeypatch.setattr(api.ExecutionPlan, "execute",
+                        lambda self: (_ for _ in ()).throw(
+                            FloatingPointError("chaos: poisoned spec")))
+    path = tmp_path / "specs.jsonl"
+    spec = api.RunSpec(**SMALL, engine="python")
+    path.write_text(json.dumps(dict(client_id="c", spec=spec.to_dict())))
+    assert serve_main(["--input", str(path)]) == 1
+    (line,) = capsys.readouterr().out.splitlines()
+    assert json.loads(line)["status"] == "error"
 
 
 def test_python_engine_fallback_rescues_scan_failures(monkeypatch):
