@@ -31,7 +31,6 @@ from __future__ import annotations
 
 import argparse
 import contextlib
-import dataclasses
 import io
 import json
 import pathlib
@@ -46,6 +45,7 @@ sys.path.insert(0, str(ROOT / "src"))
 
 import numpy as np  # noqa: E402
 import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
 
 from repro import api  # noqa: E402
 from repro.compile_cache import enable_compile_cache  # noqa: E402
@@ -56,17 +56,41 @@ from repro.kernels import fused_round  # noqa: E402
 
 OUT = ROOT / "chiprun_out" / "chip_smoke"
 
-# (b): one A_j block of exactly one tile per machine
-TILE = dict(n=512, d=2048, m=4)
+# (b): one A_j block of exactly one tile per machine; lam = 1e-5 makes
+# kappa ~ 110, so eps = 1e-5 rel takes ~ 40 rounds, not 2
+TILE = dict(n=512, d=2048, m=4, lam=1e-5, ref_iters=4000)
+TILE_ROUNDS = 80
 TILE_CHANNELS = ("identity", "bf16", "int8", "sched:int8@0,fp16@5")
 # (c): LIBSVM epsilon's shape; lam ~ 4/n, the reference solve cut to
 # what f32 resolves at this conditioning (kappa ~ 15)
 EPSILON = dict(n=400_000, d=2_000, m=4, lam=1e-5, ref_iters=500)
 EPSILON_ROUNDS = 300
-EPS_REL = 1e-4
-# max|w - w_einsum| / max|w_einsum|: f32 rounding of two different
-# programs, amplified by a stochastic quantizer where the wire is lossy
-W_RTOL = {"lossless": 1e-2, "lossy": 5e-2}
+EPS_REL = 1e-5
+# The einsum reference runs at full f32 precision: by default XLA
+# multiplies f32 matrices on the TPU in one bf16 pass.
+REFERENCE_PRECISION = "highest"
+# Bounds against that reference.  Each sits between the reading of the
+# sound kernels and that of a control whose kernel dots run at Mosaic's
+# default precision, one bf16 pass; both readings, from a TPU v5e, are
+# in PERF.md.  max|w - w_einsum| / max|w_einsum| after PRECISION_ROUNDS
+# rounds (sound <= 5.7e-7, control >= 1.0e-3):
+PRECISION_ROUNDS = 2
+PRECISION_RTOL = 1e-5
+PRECISION_CHANNELS = ("identity", "bf16")
+# and over the whole solve, max|w - w_einsum| / max|w_einsum| and
+# max_k |gap_k - gap_einsum_k| / gap_0.  An int8 stage's dither hashes
+# the value's bits, so one ulp moves a coordinate by a quantization
+# step: on the int8 wire sound and control read alike, and its bounds
+# only hold the solve near einsum's.
+W_RTOL = {"identity": 1e-5, "bf16": 4e-4, "int8": 1e-2,
+          "sched:int8@0,fp16@5": 1.5e-4, "epsilon": 1e-5}
+GAP_RTOL = {"identity": 5e-7, "bf16": 2e-6, "int8": 1e-3,
+            "sched:int8@0,fp16@5": 3e-5, "epsilon": 1e-6}
+# The one cell whose committed count no longer holds: dgd at kappa = 64
+# crosses eps = 1e-6 on the f32 noise floor of its gap, so the JAX
+# release moves it (148 committed with jax 0.4.37, 145 on CPU and 146 on
+# a TPU v5e with jax 0.9.0).  It is held to the v5e reading.
+THM2_DRIFT = {("thm2_chain(d=96, kappa=64, lam=0.5, m=4, n=96)", "dgd"): 146}
 
 
 class Checks:
@@ -100,6 +124,13 @@ def _peak_bytes(device):
     return stats.get("peak_bytes_in_use")
 
 
+def _memory(label):
+    """Device 0's bytes in use and peak so far, after ``label``."""
+    stats = jax.devices()[0].memory_stats() or {}
+    print(f"  memory after {label}: in use {stats.get('bytes_in_use')}, "
+          f"peak {stats.get('peak_bytes_in_use')}", flush=True)
+
+
 def _device_events(trace_dir: pathlib.Path):
     """Names of the device-side events in a profiler trace (an XLA op's
     event is named by its HLO text, ``%<instruction> = ...``)."""
@@ -111,28 +142,67 @@ def _device_events(trace_dir: pathlib.Path):
                     yield from (ev.name for ev in line.events)
 
 
-def _compare(checks, label, got, ref, eps_abs, lossy):
-    """The conformance contract against the einsum reference."""
+def _gaps(pl, res):
+    """The run's gap series, or its final gap where the placement has
+    no in-run measurement (sharded)."""
+    if res.gaps is not None:
+        return np.asarray(res.gaps)
+    b = pl.bundle
+    with jax.default_matmul_precision(REFERENCE_PRECISION):
+        return np.asarray([float(b.objective(jnp.asarray(res.w)))
+                           - b.fstar])
+
+
+def _reference(spec, bundle):
+    """``spec`` run on the ``einsum`` backend at ``REFERENCE_PRECISION``."""
+    with jax.default_matmul_precision(REFERENCE_PRECISION):
+        return api.plan(spec.replace(backend="einsum"),
+                        bundle=bundle).execute()
+
+
+def _first_rounds(checks, label, spec, bundle):
+    """``spec``'s iterate after ``PRECISION_ROUNDS`` rounds against the
+    same rounds on ``einsum`` (local placement)."""
+    short = spec.replace(rounds=PRECISION_ROUNDS, eps=(), measure="none")
+    got = api.plan(short, bundle=bundle).execute()
+    ref = _reference(short.replace(placement="local"), bundle)
+    w, w_ref = np.asarray(got.w), np.asarray(ref.w)
+    rel = float(np.max(np.abs(w - w_ref)) / np.max(np.abs(w_ref)))
+    checks.require(rel <= PRECISION_RTOL,
+                   f"{label}: after {PRECISION_ROUNDS} rounds max|w - "
+                   f"w_einsum| / max|w_einsum| = {rel!r} <= "
+                   f"{PRECISION_RTOL}")
+
+
+def _compare(checks, label, pl, got, ref, tol):
+    """The conformance contract of ``pl``'s run against the einsum
+    reference; ``tol`` names the row of ``W_RTOL``/``GAP_RTOL``."""
     same = ((got.ledger.round_marks, got.ledger.typed_stream())
             == (ref.ledger.round_marks, ref.ledger.typed_stream()))
     checks.require(same, f"{label}: typed ledger stream and round marks "
                          f"identical to einsum "
                          f"({len(ref.ledger.records)} records)")
-    if eps_abs is None:
+    if got.gaps is None:
         checks.require(got.rounds == ref.rounds,
                        f"{label}: rounds {got.rounds} == einsum "
                        f"{ref.rounds}")
     else:
+        eps_abs = pl.eps_abs(EPS_REL)
         a, b = got.measured_rounds(eps_abs), ref.measured_rounds(eps_abs)
         checks.require(a is not None and b is not None and abs(a - b) <= 1,
-                       f"{label}: measured rounds {a} vs einsum {b} "
-                       f"(within +-1)")
+                       f"{label}: measured rounds to {EPS_REL:g} rel {a} "
+                       f"vs einsum {b} (within +-1)")
     w, w_ref = np.asarray(got.w), np.asarray(ref.w)
     rel = float(np.max(np.abs(w - w_ref)) / np.max(np.abs(w_ref)))
-    tol = W_RTOL["lossy" if lossy else "lossless"]
-    checks.require(bool(np.all(np.isfinite(w))) and rel <= tol,
+    checks.require(bool(np.all(np.isfinite(w))) and rel <= W_RTOL[tol],
                    f"{label}: max|w - w_einsum| / max|w_einsum| = {rel!r} "
-                   f"<= {tol}")
+                   f"<= {W_RTOL[tol]}")
+    g, g_ref = _gaps(pl, got), _gaps(pl, ref)
+    dev = float(np.max(np.abs(g - g_ref)) / pl.gap0())
+    checks.require(dev <= GAP_RTOL[tol],
+                   f"{label}: max|gap - gap_einsum| / gap_0 = {dev!r} <= "
+                   f"{GAP_RTOL[tol]} over {len(g)} gaps (final gap "
+                   f"{g[-1]!r}, einsum {g_ref[-1]!r})")
 
 
 # --------------------------------------------------------------------------
@@ -145,11 +215,7 @@ def _near(got, ref):
 
 def phase_certify(checks, clock):
     """(a) the thm2-small sweep through its CLI, against the committed
-    round counts.  Rounds-to-eps at eps = 1e-6 sits at the f32 noise
-    floor of the gap, so a slow cell (dgd, kappa = 64) moves by a few
-    rounds with the summation order of the JAX release that produced
-    it; such a cell passes within +-1 of the einsum reference run here
-    on the same chip instead."""
+    round counts (``THM2_DRIFT`` names the one exception)."""
     from repro.experiments import sweep
     out = OUT / "sweep"
     rc = sweep.main(["--preset", "thm2-small", "--out", str(out)])
@@ -158,27 +224,25 @@ def phase_certify(checks, clock):
     committed = json.loads(
         (ROOT / "docs" / "results" / "thm2-small.json").read_text())
     want = {key(r): r["measured_rounds"] for r in committed["records"]}
-    einsum = {key(dataclasses.asdict(r)): r.measured_rounds
-              for r in sweep.run_sweep(sweep.PRESETS["thm2-small"],
-                                       backend="einsum").records}
     records = json.loads((out / "thm2-small.json").read_text())["records"]
     checks.require(len(records) == len(want),
                    f"{len(records)} records (committed {len(want)})")
     for r in records:
-        got, ref, here = r["measured_rounds"], want.get(key(r)), \
-            einsum.get(key(r))
+        got, ref = r["measured_rounds"], want.get(key(r))
+        held = THM2_DRIFT.get((r["instance_label"], r["algorithm"]), ref)
         checks.require(
             r["certified"] is True and r["oracle_backend"] == "fused"
-            and (_near(got, ref) or _near(got, here)),
+            and _near(got, held),
             f"{r['instance_label']} {r['algorithm']}: certified="
             f"{r['certified']} backend={r['oracle_backend']} measured "
-            f"{got} (committed {ref}, einsum on this chip {here})")
+            f"{got} (committed {ref}" + (f", held to {held}"
+                                         if held != ref else "") + ")")
 
 
 def phase_round_kernel(checks, clock):
     """(b) the whole-round kernel at its full tile, per wire channel."""
     base = api.RunSpec(instance="logistic", instance_params=TILE,
-                       algorithm="dagd", rounds=60, eps=(EPS_REL,),
+                       algorithm="dagd", rounds=TILE_ROUNDS, eps=(EPS_REL,),
                        eps_mode="rel")
     bundle = api.plan(base).bundle
     checks.require(fused_round.round_step_fits(TILE["n"],
@@ -206,10 +270,10 @@ def phase_round_kernel(checks, clock):
                        f"{ch}: device trace holds {ran} "
                        f"{fused_round.KERNEL_NAME} events for "
                        f"{res.rounds} rounds")
-        ref = api.plan(spec.replace(backend="einsum"),
-                       bundle=bundle).execute()
-        _compare(checks, ch, res, ref, pl.eps_abs(EPS_REL),
-                 lossy=ch != "identity")
+        ref = _reference(spec, bundle)
+        _compare(checks, ch, pl, res, ref, ch)
+        if ch in PRECISION_CHANNELS:
+            _first_rounds(checks, ch, spec, bundle)
 
 
 def phase_service(checks, clock):
@@ -241,7 +305,8 @@ def phase_service(checks, clock):
 
 @contextlib.contextmanager
 def _setup_clock(seconds: dict):
-    """Time the instance builder's phases where they happen."""
+    """Time the instance builder's phases where they happen, and show
+    device memory after each."""
     def timed(label, fn):
         def run(*args, **kwargs):
             t = time.perf_counter()
@@ -249,6 +314,7 @@ def _setup_clock(seconds: dict):
             jax.block_until_ready(getattr(out, "A", out))
             seconds[label] = seconds.get(label, 0.0) \
                 + time.perf_counter() - t
+            _memory(label)
             return out
         return run
 
@@ -288,6 +354,8 @@ def phase_epsilon(checks, clock):
     pl, bundle = _build(spec, clock)
     checks.require(pl.backend == "fused",
                    f"auto resolved backend {pl.backend!r}")
+    api.prepare_cell(pl)                 # A_stk, the per-machine blocks
+    _memory("cell build")
     session = EngineSession()
     c0, t0 = clock.mark(), time.perf_counter()
     res = pl.execute(session)
@@ -295,6 +363,7 @@ def phase_epsilon(checks, clock):
     print(f"  cold run: {time.perf_counter() - t0:.2f} s, compile "
           f"{clock.seconds - c0[0]:.2f} s in {clock.count - c0[1]} "
           f"compiles")
+    _memory("cold run")
     c0, t0 = clock.mark(), time.perf_counter()
     res = pl.execute(session)
     jax.block_until_ready(res.w)
@@ -304,14 +373,15 @@ def phase_epsilon(checks, clock):
           f"{clock.count - c0[1]} compiles inside")
     dev = jax.devices()[0]
     print(f"  peak_bytes_in_use: {_peak_bytes(dev)}")
-    eps_abs = pl.eps_abs(EPS_REL)
-    print(f"  measured rounds to {EPS_REL:g} rel: "
-          f"{res.measured_rounds(eps_abs)}")
     # the reference stacks its own copy of A: free this cell's first
     pl.release()
     del session
-    ref = api.plan(spec.replace(backend="einsum"), bundle=bundle).execute()
-    _compare(checks, "epsilon fused", res, ref, eps_abs, lossy=False)
+    _memory("release")
+    ref = _reference(spec, bundle)
+    _memory("einsum reference")
+    _compare(checks, "epsilon fused", api.plan(spec, bundle=bundle), res,
+             ref, "epsilon")
+    _first_rounds(checks, "epsilon fused", spec, bundle)
 
 
 def phase_sharded(checks, clock):
@@ -335,11 +405,15 @@ def phase_sharded(checks, clock):
               f"{wall:.2f} s wall incl. trace, compile "
               f"{clock.seconds - c0[0]:.2f} s in {clock.count - c0[1]} "
               f"compiles")
-    ref = api.plan(spec.replace(placement="local", backend="einsum"),
-                   bundle=bundle).execute()
-    _compare(checks, "sharded vs local einsum", res, ref, None, lossy=False)
     for dev in jax.devices():
-        print(f"  {dev}: peak_bytes_in_use {_peak_bytes(dev)}")
+        print(f"  {dev}: peak_bytes_in_use {_peak_bytes(dev)} after the "
+              f"sharded solve")
+    ref = _reference(spec.replace(placement="local"), bundle)
+    _compare(checks, "sharded vs local einsum", pl, res, ref, "epsilon")
+    for dev in jax.devices():
+        print(f"  {dev}: peak_bytes_in_use {_peak_bytes(dev)} after the "
+              f"one-chip reference")
+    _first_rounds(checks, "sharded vs local einsum", spec, bundle)
 
 
 def _run_phase(name, fn, checks, clock):

@@ -60,7 +60,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.comm import CommLedger, inject_crash_recovery
-from ..core.engine import Segment
+from ..core.engine import Segment, trace_closure
 from .plan import ExecutionPlan, PlanError, RunResult
 
 
@@ -84,14 +84,7 @@ class _Converted:
 
 
 def _convert(fn: Callable, *example_args) -> _Converted:
-    closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(*example_args)
-    out_tree = jax.tree.structure(out_shape)
-
-    def pure(consts, *args):
-        flat, _ = jax.tree.flatten(args)
-        out = jax.core.eval_jaxpr(closed.jaxpr, consts, *flat)
-        return jax.tree.unflatten(out_tree, out)
-
+    closed, pure = trace_closure(fn, *example_args)
     return _Converted(pure=pure, consts=list(closed.consts),
                       structure=str(closed.jaxpr), schedule=([], 0, []),
                       closed=closed)

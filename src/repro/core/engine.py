@@ -281,6 +281,21 @@ def scheduled_channel(dist):
     return chan if getattr(chan, "scheduled", False) else None
 
 
+def trace_closure(fn: Callable, *example_args):
+    """Trace ``fn`` once at ``example_args`` and split it into the traced
+    ``ClosedJaxpr`` and ``pure(consts, *args)``, which evaluates ``fn``
+    with the arrays it closes over passed in as ``consts`` (in the
+    jaxpr's const order)."""
+    closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(*example_args)
+    out_tree = jax.tree.structure(out_shape)
+
+    def pure(consts, *args):
+        out = jax.core.eval_jaxpr(closed.jaxpr, consts, *jax.tree.leaves(args))
+        return jax.tree.unflatten(out_tree, out)
+
+    return closed, pure
+
+
 # Closed-over arrays at least this large enter a compiled program as
 # arguments (``hoisted_jit``); smaller ones stay embedded constants.
 HOIST_BYTES = 1 << 20
@@ -294,30 +309,31 @@ def hoisted_jit(fn: Callable) -> Callable:
     labels, the objective's data) in the program as a constant: at
     deployment sizes that copies gigabytes through lowering, compile and
     the compilation cache, and again into device memory.  Here ``fn`` is
-    traced once per argument signature and its consts of ``HOIST_BYTES``
-    or more are fed back as arguments of one jitted evaluator.  Smaller
-    consts (step sizes, small instances) stay constants, so the program
-    XLA compiles for them is the one ``jax.jit(fn)`` would."""
+    traced once per argument signature (``trace_closure``) and its consts
+    of ``HOIST_BYTES`` or more are fed back as arguments of one jitted
+    evaluator.  Smaller consts (step sizes, small instances) stay
+    constants: XLA folds them, so passing them in would change the f32
+    bits of every small run from what ``jax.jit(fn)`` computes."""
     traced = {}
 
     def call(*args):
         leaves, tree = jax.tree.flatten(args)
         key = (tree, tuple(jax.typeof(x) for x in leaves))
         if key not in traced:
-            closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(*args)
-            big = [i for i, c in enumerate(closed.consts)
+            closed, pure = trace_closure(fn, *args)
+            consts = list(closed.consts)
+            big = [i for i, c in enumerate(consts)
                    if getattr(c, "nbytes", 0) >= HOIST_BYTES]
 
-            def run(hoisted, *flat):
-                consts = list(closed.consts)
+            def run(hoisted, *args):
+                full = list(consts)
                 for i, c in zip(big, hoisted):
-                    consts[i] = c
-                return jax.core.eval_jaxpr(closed.jaxpr, consts, *flat)
+                    full[i] = c
+                return pure(full, *args)
 
-            traced[key] = (jax.jit(run), [closed.consts[i] for i in big],
-                           jax.tree.structure(out_shape))
-        run, hoisted, out_tree = traced[key]
-        return jax.tree.unflatten(out_tree, run(hoisted, *leaves))
+            traced[key] = (jax.jit(run), [consts[i] for i in big])
+        run, hoisted = traced[key]
+        return run(hoisted, *args)
 
     return call
 

@@ -23,6 +23,7 @@ import dataclasses
 from typing import Callable, Optional
 
 import numpy as np
+import scipy.linalg
 import jax
 import jax.numpy as jnp
 
@@ -123,13 +124,12 @@ class ERMProblem:
     def smoothness_bound(self) -> float:
         """L <= ell''_max * sigma_max(A)^2 / n + lam.
 
-        One scalar of set-up.  Off the CPU platform it is taken by the
-        host's LAPACK: the TPU's SVD takes minutes to compile at d in the
-        thousands, LAPACK seconds to run."""
-        if jax.default_backend() == "cpu":
-            smax = jnp.linalg.norm(self.A, ord=2)
-        else:
-            smax = np.linalg.norm(np.asarray(self.A), ord=2)
+        One scalar of set-up, taken by the host's LAPACK on every
+        platform: the TPU's SVD takes minutes to compile at d in the
+        thousands, LAPACK seconds to run.  SciPy's ``gesdd`` is the routine
+        JAX's CPU SVD calls, so this equals ``jnp.linalg.norm(A, ord=2)``
+        on the CPU bit for bit."""
+        smax = scipy.linalg.svdvals(np.asarray(self.A))[0]
         return float(self.loss.smoothness * smax ** 2 / self.n + self.lam)
 
     # ---- feature-partitioned oracles (machine-local pieces) ------------

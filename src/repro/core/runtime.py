@@ -300,7 +300,10 @@ class LocalDistERM:
                                       faults=faults)
         self.backend = resolve_oracle_backend(backend)
         self.backend_impl: OracleBackend = BACKEND_IMPLS[self.backend]
-        self.A_stk = part.pad_blocks(part.split_columns(prob.A))  # (m,n,dmax)
+        # (m, n, dmax) in one program: op by op, the column slices and
+        # their padded copies would each hold a full copy of A at once
+        self.A_stk = jax.jit(
+            lambda A: part.pad_blocks(part.split_columns(A)))(prob.A)
         self.mask = part.mask()                                   # (m,dmax)
         self.n = prob.n
         self.lam = prob.lam

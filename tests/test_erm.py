@@ -69,3 +69,14 @@ def test_smoothness_bound_is_upper_bound():
     H = np.asarray(prob.A.T @ prob.A) / prob.n + prob.lam * np.eye(20)
     lmax = float(np.linalg.eigvalsh(H).max())
     assert prob.smoothness_bound() >= lmax - 1e-6
+
+
+@pytest.mark.parametrize("n,d", [(256, 96), (96, 256), (160, 160)])
+def test_smoothness_bound_equals_jax_norm_on_cpu(n, d):
+    """The host-LAPACK bound is the float the JAX formula gives on the
+    CPU: step sizes, and with them every committed round count, depend
+    on its last bit."""
+    prob = make_random_erm(n=n, d=d, loss="logistic", lam=1e-2, seed=1)
+    smax = jnp.linalg.norm(prob.A, ord=2)
+    want = float(prob.loss.smoothness * smax ** 2 / prob.n + prob.lam)
+    assert prob.smoothness_bound() == want
