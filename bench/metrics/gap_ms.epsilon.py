@@ -1,0 +1,9 @@
+"""Device milliseconds a round of the in-scan gap measure: the ops whose
+HLO ``op_name`` holds the program's ``repro.gap`` scope, summed over the
+window's trace and divided by its rounds.  Read only from a trace of the
+whole window; nothing where no op carries a ``repro.`` scope."""
+from harness import program_trace
+
+
+def read(run):
+    return program_trace.scope_ms_per_round(run, "repro.gap")

@@ -38,7 +38,6 @@ import shutil
 import sys
 import time
 import traceback
-from unittest import mock
 
 ROOT = pathlib.Path(__file__).resolve().parent
 sys.path.insert(0, str(ROOT / "src"))
@@ -50,9 +49,8 @@ import jax.numpy as jnp  # noqa: E402
 from repro import api  # noqa: E402
 from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.core.engine import EngineSession  # noqa: E402
-from repro.core.erm import ERMProblem  # noqa: E402
-from repro.experiments import instances  # noqa: E402
 from repro.kernels import fused_round  # noqa: E402
+from repro.metrics import spans  # noqa: E402
 
 OUT = ROOT / "chiprun_out" / "chip_smoke"
 
@@ -103,20 +101,12 @@ class Checks:
             self.failed.append(what)
 
 
-class CompileClock:
-    """Seconds and count of XLA backend compiles, from JAX's own events."""
-
-    def __init__(self):
-        self.seconds, self.count = 0.0, 0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-            self.count += 1
-
-    def mark(self):
-        return self.seconds, self.count
+def _compiled():
+    """(seconds, count) of every backend compile so far, as the
+    program's compile counter (``repro.metrics.spans``) has them."""
+    every = spans.snapshot()["compiles"].values()
+    return (sum(c["seconds"] for c in every),
+            sum(c["count"] for c in every))
 
 
 def _peak_bytes(device):
@@ -213,7 +203,7 @@ def _near(got, ref):
     return got is not None and ref is not None and abs(got - ref) <= 1
 
 
-def phase_certify(checks, clock):
+def phase_certify(checks):
     """(a) the thm2-small sweep through its CLI, against the committed
     round counts (``THM2_DRIFT`` names the one exception)."""
     from repro.experiments import sweep
@@ -239,7 +229,7 @@ def phase_certify(checks, clock):
                                          if held != ref else "") + ")")
 
 
-def phase_round_kernel(checks, clock):
+def phase_round_kernel(checks):
     """(b) the whole-round kernel at its full tile, per wire channel."""
     base = api.RunSpec(instance="logistic", instance_params=TILE,
                        algorithm="dagd", rounds=TILE_ROUNDS, eps=(EPS_REL,),
@@ -276,7 +266,7 @@ def phase_round_kernel(checks, clock):
             _first_rounds(checks, ch, spec, bundle)
 
 
-def phase_service(checks, clock):
+def phase_service(checks):
     """(d) the service's synthetic demo through its CLI."""
     from repro.serve.__main__ import main as serve_main
     stdout, stderr = io.StringIO(), io.StringIO()
@@ -303,74 +293,51 @@ def phase_service(checks, clock):
     checks.require(backends == {"fused"}, f"backends {sorted(backends)}")
 
 
-@contextlib.contextmanager
-def _setup_clock(seconds: dict):
-    """Time the instance builder's phases where they happen, and show
-    device memory after each."""
-    def timed(label, fn):
-        def run(*args, **kwargs):
-            t = time.perf_counter()
-            out = fn(*args, **kwargs)
-            jax.block_until_ready(getattr(out, "A", out))
-            seconds[label] = seconds.get(label, 0.0) \
-                + time.perf_counter() - t
-            _memory(label)
-            return out
-        return run
-
-    with contextlib.ExitStack() as stack:
-        for owner, attr, label in (
-                (instances, "make_random_erm", "data generation"),
-                (ERMProblem, "smoothness_bound", "smoothness_bound"),
-                (instances, "_make_context", "per-block norms (host)"),
-                (instances, "_reference_solution", "reference solve")):
-            stack.enter_context(mock.patch.object(
-                owner, attr, timed(label, getattr(owner, attr))))
-        yield
-
-
-def _build(spec, clock):
-    """Plan ``spec`` and build its instance, printing set-up by phase."""
-    seconds = {}
-    c0, t0 = clock.mark(), time.perf_counter()
-    with _setup_clock(seconds):
-        pl = api.plan(spec)
-        bundle = pl.bundle
+def _build(spec):
+    """Plan ``spec`` and build its instance, printing the build's parts
+    and the compiles in each, from the program's spans."""
+    before, t0 = spans.snapshot(), time.perf_counter()
+    pl = api.plan(spec)
+    bundle = pl.bundle
     total = time.perf_counter() - t0
-    for label, s in seconds.items():
-        print(f"  set-up {label}: {s:.2f} s")
-    print(f"  set-up compile (inside the above): "
-          f"{clock.seconds - c0[0]:.2f} s in {clock.count - c0[1]} "
-          f"compiles; set-up total {total:.2f} s; reference solve "
+    moved = spans.since(before)
+    for name, part in moved["spans"].items():
+        print(f"  set-up {name}: {part['total_s']:.2f} s "
+              f"({part['count']} span(s), self {part['self_s']:.2f} s)")
+    for name, c in moved["compiles"].items():
+        print(f"  set-up compiles under {name or 'no span'}: {c['count']} "
+              f"({c['seconds']:.2f} s, {c['cache_hits']} from the cache)")
+    print(f"  set-up total {total:.2f} s; reference solve "
           f"ref_iters={spec.instance_params['ref_iters']}")
+    _memory("instance build")
     return pl, bundle
 
 
-def phase_epsilon(checks, clock):
+def phase_epsilon(checks):
     """(c) the epsilon-shaped dense logistic ERM on one chip."""
     spec = api.RunSpec(instance="logistic", instance_params=EPSILON,
                        algorithm="dagd", rounds=EPSILON_ROUNDS,
                        eps=(EPS_REL,), eps_mode="rel")
-    pl, bundle = _build(spec, clock)
+    pl, bundle = _build(spec)
     checks.require(pl.backend == "fused",
                    f"auto resolved backend {pl.backend!r}")
     api.prepare_cell(pl)                 # A_stk, the per-machine blocks
     _memory("cell build")
     session = EngineSession()
-    c0, t0 = clock.mark(), time.perf_counter()
+    c0, t0 = _compiled(), time.perf_counter()
     res = pl.execute(session)
     jax.block_until_ready(res.w)
+    c1 = _compiled()
     print(f"  cold run: {time.perf_counter() - t0:.2f} s, compile "
-          f"{clock.seconds - c0[0]:.2f} s in {clock.count - c0[1]} "
-          f"compiles")
+          f"{c1[0] - c0[0]:.2f} s in {c1[1] - c0[1]} compiles")
     _memory("cold run")
-    c0, t0 = clock.mark(), time.perf_counter()
+    c0, t0 = _compiled(), time.perf_counter()
     res = pl.execute(session)
     jax.block_until_ready(res.w)
     warm = time.perf_counter() - t0
     print(f"  warm window: {res.rounds} rounds in {warm:.3f} s = "
           f"{res.rounds / warm:.2f} rounds/s, "
-          f"{clock.count - c0[1]} compiles inside")
+          f"{_compiled()[1] - c0[1]} compiles inside")
     dev = jax.devices()[0]
     print(f"  peak_bytes_in_use: {_peak_bytes(dev)}")
     # the reference stacks its own copy of A: free this cell's first
@@ -384,7 +351,7 @@ def phase_epsilon(checks, clock):
     _first_rounds(checks, "epsilon fused", spec, bundle)
 
 
-def phase_sharded(checks, clock):
+def phase_sharded(checks):
     """The epsilon problem with machine j on chip j, against the same
     spec on one chip."""
     count = len(jax.devices())
@@ -393,18 +360,18 @@ def phase_sharded(checks, clock):
     spec = api.RunSpec(instance="logistic", instance_params=EPSILON,
                        algorithm="dagd", rounds=EPSILON_ROUNDS,
                        placement="sharded", measure="none")
-    pl, bundle = _build(spec, clock)
+    pl, bundle = _build(spec)
     checks.require(pl.backend == "fused",
                    f"auto resolved backend {pl.backend!r}")
     for attempt in ("cold", "again"):
-        c0, t0 = clock.mark(), time.perf_counter()
+        c0, t0 = _compiled(), time.perf_counter()
         res = pl.execute()
         jax.block_until_ready(res.w)
         wall = time.perf_counter() - t0
+        c1 = _compiled()
         print(f"  sharded run ({attempt}): {res.rounds} rounds in "
               f"{wall:.2f} s wall incl. trace, compile "
-              f"{clock.seconds - c0[0]:.2f} s in {clock.count - c0[1]} "
-              f"compiles")
+              f"{c1[0] - c0[0]:.2f} s in {c1[1] - c0[1]} compiles")
     for dev in jax.devices():
         print(f"  {dev}: peak_bytes_in_use {_peak_bytes(dev)} after the "
               f"sharded solve")
@@ -416,11 +383,11 @@ def phase_sharded(checks, clock):
     _first_rounds(checks, "sharded vs local einsum", spec, bundle)
 
 
-def _run_phase(name, fn, checks, clock):
+def _run_phase(name, fn, checks):
     print(f"== phase {name}", flush=True)
     t0 = time.perf_counter()
     try:
-        fn(checks, clock)
+        fn(checks)
     except Exception:   # report and go on: later phases still say things
         traceback.print_exc()
         checks.failed.append(f"phase {name} raised")
@@ -443,14 +410,14 @@ def main(argv=None) -> int:
     print(f"device: {dev.platform} {dev.device_kind} x "
           f"{len(jax.devices())}, jax {jax.__version__}, compile cache "
           f"{cache}", flush=True)
-    checks, clock = Checks(), CompileClock()
+    checks = Checks()
     phases = ([("sharded", phase_sharded)] if args.chips == 4 else
               [("a certify", phase_certify),
                ("b round kernel", phase_round_kernel),
                ("d service", phase_service),
                ("c epsilon", phase_epsilon)])
     for name, fn in phases:
-        _run_phase(name, fn, checks, clock)
+        _run_phase(name, fn, checks)
     device = dict(platform=dev.platform, kind=dev.device_kind,
                   count=len(jax.devices()))
     if checks.failed:

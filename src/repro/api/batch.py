@@ -60,7 +60,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.comm import CommLedger, inject_crash_recovery
-from ..core.engine import Segment, trace_closure
+from ..core.engine import GAP_SCOPE, Segment, trace_closure
+from ..metrics.spans import span
 from .plan import ExecutionPlan, PlanError, RunResult
 
 
@@ -144,10 +145,17 @@ class Cell:
 
 def prepare_cell(plan: ExecutionPlan) -> Optional[Cell]:
     """Trace a plan's cell into structure + consts; None if unbatchable."""
-    if plan.resolution_only or plan.placement != "local" \
-            or plan.engine != "scan":
+    if not plan.batchable:
         return None
-    dist, program, measure_fn = plan._cell()
+    with span("repro.prepare_cell"):
+        with span("repro.cell.dist"):
+            dist, program, measure_fn = plan._cell()
+        with span("repro.cell.trace"):
+            return _trace_cell(plan, dist, program, measure_fn)
+
+
+def _trace_cell(plan: ExecutionPlan, dist, program,
+                measure_fn) -> Cell:
     scheduled = getattr(getattr(dist.comm, "channel", None),
                         "scheduled", False)
     real = dist.comm.ledger
@@ -225,8 +233,20 @@ def execute_group(cells: List[Cell],
     trace + compile once per (structure, batch width).  Per-cell consts
     are stacked fresh per call (they carry the data); a cached runner is
     pure structure.  Safe to share only between batches with EQUAL group
-    keys — the key pins structure text and const shapes/dtypes."""
-    C = len(cells)
+    keys — the key pins structure text and const shapes/dtypes.
+
+    Spans (``repro.metrics.spans``): ``repro.execute_group``, with the
+    group key's hash and the width, holds once per segment
+    ``repro.runner`` (runner-cache lookup or build) and ``repro.run``
+    (dispatch until the results are ready; a runner's first call traces
+    and compiles it), then ``repro.ledger_replay``."""
+    with span("repro.execute_group", key=hash(cells[0].group_key()),
+              width=len(cells)):
+        return _execute_group(cells, runner_cache)
+
+
+def _execute_group(cells: List[Cell],
+                   runner_cache: Optional[dict]) -> List[RunResult]:
     progs = [c.program for c in cells]
     carry = jax.tree.map(lambda *xs: jnp.stack(xs),
                          *[p.init for p in progs])
@@ -254,29 +274,11 @@ def execute_group(cells: List[Cell],
             consts_cache[ckey] = _stack_consts(cells, lambda c: c.steps[s])
         consts = consts_cache[ckey]
         rkey = (conv0.structure, shared_xs, sched_chan is not None)
-        if rkey not in runners:
-            pure_step = conv0.pure
-            pure_meas = meas0.pure if meas0 else None
-
-            def runner_fn(consts, mconsts, carry, xs,
-                          _step=pure_step, _meas=pure_meas,
-                          _shared=shared_xs,
-                          _sched=sched_chan is not None):
-                # scheduled channels scan (round index, per-round input)
-                # pairs; the round index is identical across the batch,
-                # so it broadcasts (in_axes None) like shared xs
-                x_axes = ((None, None) if _shared else (None, 0)) \
-                    if _sched else (None if _shared else 0)
-
-                def body(c, x):
-                    c, w = jax.vmap(_step, in_axes=(0, 0, x_axes)
-                                    )(consts, c, x)
-                    out = jax.vmap(_meas)(mconsts, w) if _meas else None
-                    return c, out
-
-                return lax.scan(body, carry, xs)
-
-            runners[rkey] = jax.jit(runner_fn)
+        with span("repro.runner"):
+            if rkey not in runners:
+                runners[rkey] = _group_runner(
+                    conv0.pure, meas0.pure if meas0 else None, shared_xs,
+                    sched_chan is not None)
         xs = cell_xs[0] if shared_xs else np.stack(cell_xs, axis=1)
         xs_arg = jnp.asarray(xs)
         rounds_per_step = conv0.schedule[1]
@@ -285,7 +287,9 @@ def execute_group(cells: List[Cell],
                                          dtype=np.int32) * rounds_per_step
             xs_arg = (jnp.asarray(rid), xs_arg)
         round_base += rounds_per_step * seg0.count
-        carry, out = runners[rkey](consts, mconsts, carry, xs_arg)
+        with span("repro.run"):
+            carry, out = jax.block_until_ready(
+                runners[rkey](consts, mconsts, carry, xs_arg))
         if meas0 is not None:
             outs.append(out)                        # (count, C)
     gaps_all = np.asarray(jnp.concatenate(outs, axis=0)) if outs else None
@@ -295,16 +299,20 @@ def execute_group(cells: List[Cell],
     faults0 = getattr(cells[0].dist.comm, "faults", None)
     if faults0 is not None and not faults0.active:
         faults0 = None
+    ledgers = []
+    with span("repro.ledger_replay"):
+        for cell in cells:
+            ledger = CommLedger()
+            for s, seg in enumerate(cell.program.segments):
+                records, rounds_per_step, marks = cell.steps[s].schedule
+                ledger.replay_schedule(records, rounds_per_step, marks,
+                                       seg.count, channel=sched_chan,
+                                       faults=faults0)
+            if faults0 is not None:
+                inject_crash_recovery(ledger, faults0)
+            ledgers.append(ledger)
     results = []
-    for i, cell in enumerate(cells):
-        ledger = CommLedger()
-        for s, seg in enumerate(cell.program.segments):
-            records, rounds_per_step, marks = cell.steps[s].schedule
-            ledger.replay_schedule(records, rounds_per_step, marks,
-                                   seg.count, channel=sched_chan,
-                                   faults=faults0)
-        if faults0 is not None:
-            inject_crash_recovery(ledger, faults0)
+    for i, (cell, ledger) in enumerate(zip(cells, ledgers)):
         carry_i = jax.tree.map(lambda a: a[i], carry)
         w = cell.dist.gather_w(cell.program.final(carry_i))
         pl = cell.plan
@@ -316,6 +324,29 @@ def execute_group(cells: List[Cell],
             gaps=gaps_all[:, i] if gaps_all is not None else None,
             budget_ok=pl._budget_ok(ledger), batched=True))
     return results
+
+
+def _group_runner(pure_step, pure_meas, shared: bool, sched: bool):
+    """The jitted scan of one segment over a group: the step ``vmap``-ed
+    over the cells, then the gap measure (``repro.gap`` scope)."""
+
+    def runner_fn(consts, mconsts, carry, xs):
+        # scheduled channels scan (round index, per-round input) pairs;
+        # the round index is identical across the batch, so it
+        # broadcasts (in_axes None) like shared xs
+        x_axes = ((None, None) if shared else (None, 0)) \
+            if sched else (None if shared else 0)
+
+        def body(c, x):
+            c, w = jax.vmap(pure_step, in_axes=(0, 0, x_axes))(consts, c, x)
+            if pure_meas is None:
+                return c, None
+            with jax.named_scope(GAP_SCOPE):
+                return c, jax.vmap(pure_meas)(mconsts, w)
+
+        return lax.scan(body, carry, xs)
+
+    return jax.jit(runner_fn)
 
 
 # --------------------------------------------------------------------------

@@ -42,6 +42,7 @@ from ..experiments.instances import INSTANCE_BUILDERS, InstanceBundle, \
     build_instance
 from ..experiments.registry import ALGORITHM_REGISTRY, AlgorithmSpec, \
     get_algorithm
+from ..metrics.spans import span
 from . import _resolve
 from .spec import RunSpec
 
@@ -132,6 +133,13 @@ class ExecutionPlan:
     @property
     def resolution_only(self) -> bool:
         return self.spec.instance is None
+
+    @property
+    def batchable(self) -> bool:
+        """Whether ``repro.api.prepare_cell`` traces this plan into a
+        ``Cell``: a runnable plan on the local placement and scan engine."""
+        return (not self.resolution_only and self.placement == "local"
+                and self.engine == "scan")
 
     @property
     def bundle(self) -> InstanceBundle:
@@ -335,12 +343,13 @@ class ExecutionPlan:
         if self.resolution_only:
             raise PlanError("resolution-only plan; give the RunSpec an "
                             "instance and algorithm to execute it")
-        if self.placement == "sharded":
-            return self._execute_sharded()
-        dist, program, measure_fn = self._cell()
-        dist.comm.ledger = ledger = CommLedger()
-        res = run_program(dist, program, engine=self.engine,
-                          measure=measure_fn, session=session)
+        with span("repro.execute"):
+            if self.placement == "sharded":
+                return self._execute_sharded()
+            dist, program, measure_fn = self._cell()
+            dist.comm.ledger = ledger = CommLedger()
+            res = run_program(dist, program, engine=self.engine,
+                              measure=measure_fn, session=session)
         return RunResult(
             spec=self.spec, placement=self.placement, backend=self.backend,
             engine=self.engine, channel=self.channel,

@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..metrics.spans import span
 from .comm import CommLedger, inject_crash_recovery
 from .faults import FaultRecoveryError
 
@@ -67,6 +68,10 @@ from .faults import FaultRecoveryError
 # (repro.api.plan imports modules that import this one). tests/test_api.py
 # pins equality.
 ENGINES = ("python", "scan")
+
+# The gap measure's ops carry this ``jax.named_scope`` in their HLO
+# metadata (``op_name``), so a device trace can tell them from the round's.
+GAP_SCOPE = "repro.gap"
 
 
 def resolve_engine(engine: Optional[str] = None) -> str:
@@ -205,7 +210,8 @@ def _run_python(dist, program, measure, history) -> EngineResult:
                                                carry)
                         crash_at = None
                 if measure is not None:
-                    gaps.append(measure(w))
+                    with jax.named_scope(GAP_SCOPE):
+                        gaps.append(measure(w))
                 elif history:
                     iterates.append(w)
     finally:
@@ -349,7 +355,8 @@ def _build_runner(dist, step: Callable, measure, history, scheduled: bool):
             dist.comm.begin_round(rk)
         carry, w = step(dist, carry, x)
         if measure is not None:
-            return carry, measure(w)
+            with jax.named_scope(GAP_SCOPE):
+                return carry, measure(w)
         return carry, (w if collect_w else None)
 
     return hoisted_jit(lambda carry, xs: lax.scan(body, carry, xs))
@@ -362,19 +369,24 @@ def _run_scan(dist, program, measure, history,
     faults = _engine_faults(dist)
     carry = program.init
     outs, rounds = [], 0
+    # Spans, once per segment (``repro.metrics.spans``): ``repro.runner``
+    # (schedule capture and runner lookup or build), ``repro.run``
+    # (dispatch until the segment's results are ready; a runner's first
+    # call traces and compiles it) and ``repro.ledger_replay``.
     for seg in program.segments:
         xs = _segment_xs(seg)
-        sched_key = (seg.step, xs.dtype.str, xs.shape[1:])
-        if sched_key not in session.schedules:
-            session.schedules[sched_key] = _capture_schedule(
-                dist, seg, carry, xs)
-        records, rounds_per_step, marks = session.schedules[sched_key]
-        run_key = (seg.step, measure, history, chan is not None)
-        runner = session.runners.get(run_key)
-        if runner is None:
-            runner = _build_runner(dist, seg.step, measure, history,
-                                   chan is not None)
-            session.runners[run_key] = runner
+        with span("repro.runner"):
+            sched_key = (seg.step, xs.dtype.str, xs.shape[1:])
+            if sched_key not in session.schedules:
+                session.schedules[sched_key] = _capture_schedule(
+                    dist, seg, carry, xs)
+            records, rounds_per_step, marks = session.schedules[sched_key]
+            run_key = (seg.step, measure, history, chan is not None)
+            runner = session.runners.get(run_key)
+            if runner is None:
+                runner = _build_runner(dist, seg.step, measure, history,
+                                       chan is not None)
+                session.runners[run_key] = runner
         xs_arg = jnp.asarray(xs)
         if chan is not None:
             # Global round index per scan step, precomputed as scanned
@@ -390,15 +402,17 @@ def _run_scan(dist, program, measure, history,
         # the schedule replay below is the single source of truth).
         dist.comm.ledger = CommLedger()
         try:
-            carry, out = runner(carry, xs_arg)
+            with span("repro.run"):
+                carry, out = jax.block_until_ready(runner(carry, xs_arg))
         finally:
             dist.comm.ledger = ledger
             if chan is not None:
                 dist.comm.reset_round()
         if measure is not None or history:
             outs.append(out)
-        ledger.replay_schedule(records, rounds_per_step, marks, seg.count,
-                               channel=chan, faults=faults)
+        with span("repro.ledger_replay"):
+            ledger.replay_schedule(records, rounds_per_step, marks,
+                                   seg.count, channel=chan, faults=faults)
         rounds += seg.count
     if faults is not None:
         # splice the crash-replay traffic exactly where the live python

@@ -29,6 +29,7 @@ from repro.core import (ChainInstance, ERMProblem, make_random_erm,
 from repro.core.algorithms import soft_threshold
 from repro.core.engine import hoisted_jit
 from repro.core.partition import FeaturePartition, even_partition
+from repro.metrics.spans import span
 
 from .registry import AlgoContext
 
@@ -63,19 +64,42 @@ class InstanceBundle:
 # Shared construction helpers
 # --------------------------------------------------------------------------
 
+# The build's parts, each in a span of its own (``repro.metrics.spans``):
+# the data (timed until it is on the device), the smoothness bound L,
+# the per-block constants and the reference optimum f*.
+
 def _make_context(prob: ERMProblem, part: FeaturePartition, L: float,
                   prox: Optional[Callable] = None) -> AlgoContext:
     """Derive every constant the registered adapters may ask for, given
-    ``L = prob.smoothness_bound()`` (each builder computes it once)."""
-    sm = prob.loss.smoothness
-    A = np.asarray(prob.A)
-    block_L = np.array(
-        [sm * np.linalg.norm(A[:, off:off + b], 2) ** 2 / prob.n + prob.lam
-         for off, b in zip(part.offsets, part.block_sizes)]).reshape(-1, 1)
-    L_max = float(np.max(np.sum(A ** 2, axis=1)) * sm + prob.lam)
+    ``L = _smoothness(prob)`` (each builder computes it once)."""
+    with span("repro.instance.block_norms"):
+        sm = prob.loss.smoothness
+        A = np.asarray(prob.A)
+        block_L = np.array(
+            [sm * np.linalg.norm(A[:, off:off + b], 2) ** 2 / prob.n
+             + prob.lam
+             for off, b in zip(part.offsets, part.block_sizes)]
+        ).reshape(-1, 1)
+        L_max = float(np.max(np.sum(A ** 2, axis=1)) * sm + prob.lam)
     return AlgoContext(L=L, lam=prob.lam, L_max=L_max, block_L=block_L,
                        m=part.m, n=prob.n, d=prob.d,
                        loss_name=prob.loss.name, prox=prox)
+
+
+def _ready(prob: ERMProblem) -> ERMProblem:
+    jax.block_until_ready((prob.A, prob.y))
+    return prob
+
+
+def _smoothness(prob: ERMProblem) -> float:
+    with span("repro.instance.smoothness"):
+        return prob.smoothness_bound()
+
+
+def _fstar(objective: Callable, wstar) -> float:
+    """f* at a known minimizer (closed form or reference solve)."""
+    with span("repro.instance.reference_solve"):
+        return float(objective(wstar))
 
 
 def chain_erm(d: int, kappa: float, lam: float):
@@ -118,6 +142,12 @@ def _reference_solution(prob: ERMProblem, iters: int, L: float,
                         prox: Optional[Callable] = None) -> jnp.ndarray:
     """High-accuracy reference minimizer for workloads with no closed form:
     full-vector (non-distributed) FISTA / accelerated gradient, jitted."""
+    with span("repro.instance.reference_solve"):    # the dispatch; the
+        return _accelerated_solve(prob, iters, L, prox)  # wait is _fstar's
+
+
+def _accelerated_solve(prob: ERMProblem, iters: int, L: float,
+                       prox: Optional[Callable]) -> jnp.ndarray:
     lam = prob.lam
     grad = jax.grad(prob.value) if prox is None else prob.gradient
     px = prox if prox is not None else (lambda w, s: w)
@@ -156,13 +186,15 @@ def build_thm2_chain(d: int = 160, kappa: float = 64.0, lam: float = 0.5,
                      m: int = 4) -> InstanceBundle:
     """Theorem-2 hard instance: lam-strongly-convex chain with condition
     number kappa; exact minimizer w*(i) = q^i."""
-    ci, prob = chain_erm(d, kappa, lam)
+    with span("repro.instance.data"):
+        ci, prob = chain_erm(d, kappa, lam)
+        _ready(prob)
     part = even_partition(prob.d, m)
     wstar = jnp.asarray(ci.w_star())
-    fstar = float(prob.value(wstar))
+    fstar = _fstar(prob.value, wstar)
     return InstanceBundle(
         kind="thm2_chain", hard=True, prob=prob, part=part,
-        ctx=_make_context(prob, part, prob.smoothness_bound()),
+        ctx=_make_context(prob, part, _smoothness(prob)),
         objective=prob.value,
         fstar=fstar, wstar_norm=float(jnp.linalg.norm(wstar)),
         params=dict(d=d, kappa=kappa, lam=lam, m=m, n=prob.n))
@@ -171,12 +203,14 @@ def build_thm2_chain(d: int = 160, kappa: float = 64.0, lam: float = 0.5,
 def build_thm3_chain(d: int = 128, L: float = 1.0, m: int = 4
                      ) -> InstanceBundle:
     """Theorem-3 hard instance: smooth convex chain, lam = 0."""
-    prob, wstar = smooth_chain_erm(d, L)
+    with span("repro.instance.data"):
+        prob, wstar = smooth_chain_erm(d, L)
+        _ready(prob)
     part = even_partition(d, m)
-    fstar = float(prob.value(wstar))
+    fstar = _fstar(prob.value, wstar)
     return InstanceBundle(
         kind="thm3_chain", hard=True, prob=prob, part=part,
-        ctx=_make_context(prob, part, prob.smoothness_bound()),
+        ctx=_make_context(prob, part, _smoothness(prob)),
         objective=prob.value,
         fstar=fstar, wstar_norm=float(jnp.linalg.norm(wstar)),
         params=dict(d=d, L=L, m=m, n=prob.n))
@@ -188,11 +222,13 @@ def build_thm4_separable(n: int = 32, kappa: float = 64.0, lam: float = 0.5,
     function on d = n coordinates, so the ERM has n components and each
     stochastic step touches one (Definition 3.2's model). The certifying
     kappa is the ERM's own condition number L/lam."""
-    ci, prob = chain_erm(d=n, kappa=kappa, lam=lam)
+    with span("repro.instance.data"):
+        ci, prob = chain_erm(d=n, kappa=kappa, lam=lam)
+        _ready(prob)
     part = even_partition(prob.d, m)
     wstar = jnp.asarray(ci.w_star())
-    fstar = float(prob.value(wstar))
-    L = prob.smoothness_bound()
+    fstar = _fstar(prob.value, wstar)
+    L = _smoothness(prob)
     kappa_erm = L / prob.lam
     return InstanceBundle(
         kind="thm4_separable", hard=True, prob=prob, part=part,
@@ -210,26 +246,27 @@ def build_lasso(n: int = 128, d: int = 256, m: int = 4, tau: float = 2e-3,
                 ref_iters: int = 20000) -> InstanceBundle:
     """Sparse-recovery lasso: F(w) = 1/2n |Aw - y|^2 + tau |w|_1. The prox
     is block-local, so the round budget stays one R^n ReduceAll."""
-    rng = np.random.RandomState(seed)
-    A = rng.randn(n, d) / np.sqrt(d)
-    w_true = np.zeros(d)
-    idx = rng.choice(d, k_true, replace=False)
-    w_true[idx] = rng.randn(k_true) * 3
-    y = A @ w_true + 0.01 * rng.randn(n)
-    prob = ERMProblem(A=jnp.asarray(A), y=jnp.asarray(y),
-                      loss=squared_loss(), lam=0.0)
+    with span("repro.instance.data"):
+        rng = np.random.RandomState(seed)
+        A = rng.randn(n, d) / np.sqrt(d)
+        w_true = np.zeros(d)
+        idx = rng.choice(d, k_true, replace=False)
+        w_true[idx] = rng.randn(k_true) * 3
+        y = A @ w_true + 0.01 * rng.randn(n)
+        prob = _ready(ERMProblem(A=jnp.asarray(A), y=jnp.asarray(y),
+                                 loss=squared_loss(), lam=0.0))
     part = even_partition(d, m)
     prox = soft_threshold(tau)
 
     def objective(w):
         return prob.value(w) + tau * jnp.sum(jnp.abs(w))
 
-    L = prob.smoothness_bound()
+    L = _smoothness(prob)
     wref = _reference_solution(prob, ref_iters, L, prox=prox)
     return InstanceBundle(
         kind="lasso", hard=False, prob=prob, part=part,
         ctx=_make_context(prob, part, L, prox=prox), objective=objective,
-        fstar=float(objective(wref)),
+        fstar=_fstar(objective, wref),
         wstar_norm=float(jnp.linalg.norm(wref)),
         params=dict(n=n, d=d, m=m, tau=tau, L=L))
 
@@ -238,15 +275,17 @@ def build_logistic(n: int = 256, d: int = 96, m: int = 4, lam: float = 1e-2,
                    seed: int = 0, ref_iters: int = 20000) -> InstanceBundle:
     """Ridge-regularized logistic regression on synthetic separable-ish
     data — the paper's motivating GLM workload."""
-    prob = make_random_erm(n=n, d=d, loss="logistic", lam=lam, seed=seed)
+    with span("repro.instance.data"):
+        prob = _ready(make_random_erm(n=n, d=d, loss="logistic", lam=lam,
+                                      seed=seed))
     part = even_partition(d, m)
-    L = prob.smoothness_bound()
+    L = _smoothness(prob)
     wref = _reference_solution(prob, ref_iters, L)
     kappa = L / lam
     return InstanceBundle(
         kind="logistic", hard=False, prob=prob, part=part,
         ctx=_make_context(prob, part, L), objective=prob.value,
-        fstar=float(prob.value(wref)),
+        fstar=_fstar(prob.value, wref),
         wstar_norm=float(jnp.linalg.norm(wref)),
         params=dict(n=n, d=d, m=m, lam=lam, kappa=kappa))
 
@@ -255,11 +294,13 @@ def build_random_ridge(n: int = 256, d: int = 64, m: int = 8,
                        lam: float = 1e-2, seed: int = 1) -> InstanceBundle:
     """Random ridge ERM for fixed-round communication costing (no fstar:
     used by the comm-cost sweeps, which never measure rounds-to-eps)."""
-    prob = make_random_erm(n=n, d=d, loss="squared", lam=lam, seed=seed)
+    with span("repro.instance.data"):
+        prob = _ready(make_random_erm(n=n, d=d, loss="squared", lam=lam,
+                                      seed=seed))
     part = even_partition(d, m)
     return InstanceBundle(
         kind="random_ridge", hard=False, prob=prob, part=part,
-        ctx=_make_context(prob, part, prob.smoothness_bound()),
+        ctx=_make_context(prob, part, _smoothness(prob)),
         objective=prob.value,
         fstar=None, wstar_norm=None,
         params=dict(n=n, d=d, m=m, lam=lam))
@@ -281,4 +322,6 @@ def build_instance(kind: str, **params) -> InstanceBundle:
     except KeyError:
         raise KeyError(f"unknown instance kind {kind!r}; known: "
                        f"{sorted(INSTANCE_BUILDERS)}") from None
-    return dataclasses.replace(builder(**params), build_params=dict(params))
+    with span("repro.instance_build", kind=kind):
+        bundle = builder(**params)
+    return dataclasses.replace(bundle, build_params=dict(params))

@@ -38,6 +38,12 @@ BLOCK_N = 512
 BLOCK_D = 512
 BLOCK_B = 128
 
+# Padding to the block grid runs under this ``jax.named_scope``, so its
+# device ops carry it in their HLO ``op_name``.  Each kernel's
+# ``pallas_call`` is named for its entry point: the name is the kernel's
+# label in compiled programs and device traces.
+PAD_SCOPE = "repro.pad"
+
 
 def _matvec_kernel(a_ref, w_ref, o_ref):
     """Grid (n_blocks, b_blocks, d_blocks): o[i,b] += A[i,j] @ w[j,b];
@@ -76,6 +82,7 @@ def feature_matvec(A_j, w_j, *, block_n: int = BLOCK_N,
         out_shape=jax.ShapeDtypeStruct((A_p.shape[0], w_p.shape[1]),
                                        _acc_dtype(A_j.dtype)),
         interpret=_interp(interpret),
+        name="feature_matvec",
     )(A_p, w_p)
     out = out[:n, :b].astype(A_j.dtype)
     return out[:, 0] if squeeze else out
@@ -118,6 +125,7 @@ def feature_rmatvec(A_j, r, *, block_n: int = BLOCK_N,
         out_shape=jax.ShapeDtypeStruct((A_p.shape[1], r_p.shape[1]),
                                        _acc_dtype(A_j.dtype)),
         interpret=_interp(interpret),
+        name="feature_rmatvec",
     )(A_p, r_p)
     out = out[:dj, :b].astype(A_j.dtype)
     return out[:, 0] if squeeze else out
@@ -167,6 +175,7 @@ def feature_hvp(A_j, h, av, *, block_n: int = BLOCK_N,
         out_shape=jax.ShapeDtypeStruct((A_p.shape[1], r_p.shape[1]),
                                        _acc_dtype(A_j.dtype)),
         interpret=_interp(interpret),
+        name="feature_hvp",
     )(A_p, h_p.astype(A_j.dtype), r_p)
     out = out[:dj, :b].astype(A_j.dtype)
     return out[:, 0] if squeeze else out
@@ -192,7 +201,8 @@ def _pad2(x, r0: int, r1: int):
     p0 = (-x.shape[0]) % r0
     p1 = (-x.shape[1]) % r1
     if p0 or p1:
-        x = jnp.pad(x, ((0, p0), (0, p1)))
+        with jax.named_scope(PAD_SCOPE):
+            x = jnp.pad(x, ((0, p0), (0, p1)))
     return x
 
 

@@ -321,6 +321,7 @@ def fused_pgrad(A_j, r, w_j, mask_j, *, n: int, lam: float,
         out_shape=jax.ShapeDtypeStruct((A_p.shape[1], r_p.shape[1]),
                                        _acc_dtype(A_j.dtype)),
         interpret=_interp(interpret),
+        name="fused_pgrad",
     )(A_p, r_p, w_p, mk_p)
     out = out[:dj, :b].astype(A_j.dtype)
     return out[:, 0] if squeeze else out
@@ -380,6 +381,7 @@ def fused_phvp(A_j, h, av, v_j, mask_j, *, n: int, lam: float,
         out_shape=jax.ShapeDtypeStruct((A_p.shape[1], r_p.shape[1]),
                                        _acc_dtype(A_j.dtype)),
         interpret=_interp(interpret),
+        name="fused_phvp",
     )(A_p, h_p.astype(A_j.dtype), r_p, v_p, mk_p)
     out = out[:dj, :b].astype(A_j.dtype)
     return out[:, 0] if squeeze else out

@@ -9,7 +9,10 @@
 
 Envelopes stream to stdout as JSON lines as verdicts complete (per-
 client submission order); rejected payloads become
-``{"status": "rejected", ...}`` lines.  Service stats go to stderr.
+``{"status": "rejected", ...}`` lines.  Service stats go to stderr, and
+beside them the program's spans (``repro.metrics.spans``): where the
+host time of admission, execution and release went, span by span, and
+which span made each compile.
 Exit status is non-zero iff any payload was rejected or any envelope
 is a dead letter (``status="error"``).
 """
@@ -21,6 +24,7 @@ import sys
 from typing import Optional, Sequence
 
 from ..compile_cache import enable_compile_cache
+from ..metrics import spans
 from .service import CertificationService
 from .workload import Arrival, DEFAULT_STRUCTURES, synthetic_trace
 
@@ -111,6 +115,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     emit(service.drain(last))
 
     print(f"[serve] {json.dumps(service.stats())}", file=sys.stderr)
+    print(f"[spans] {json.dumps(spans.snapshot())}", file=sys.stderr)
     return 1 if rejected or dead else 0
 
 

@@ -24,6 +24,7 @@ import json
 from typing import Dict, Optional, Union
 
 from .. import api
+from ..metrics.spans import span
 
 
 class SpecError(ValueError):
@@ -116,17 +117,23 @@ class SubmissionQueue:
                 f"runs (max_depth={self.max_depth}); retry after "
                 f"{self.retry_after:g}s",
                 depth=self.outstanding, retry_after=self.retry_after)
-        try:
-            spec = parse_runspec(payload)
-            pl = api.plan(spec)
-            if pl.resolution_only:
-                raise SpecError(
-                    "resolution-only RunSpec (no instance/algorithm); "
-                    "nothing to certify")
-            cell = api.prepare_cell(pl)
-        except ValueError:
-            self.rejected += 1
-            raise
+        # the spans carry the ticket the spec gets if it is admitted
+        with span("repro.admit", ticket=f"t{self.admitted + 1:06d}"):
+            try:
+                with span("repro.parse"):
+                    spec = parse_runspec(payload)
+                with span("repro.plan"):
+                    pl = api.plan(spec)
+                if pl.resolution_only:
+                    raise SpecError(
+                        "resolution-only RunSpec (no instance/algorithm); "
+                        "nothing to certify")
+                if pl.batchable:    # build the instance here, in its own
+                    pl.bundle       # span, not inside the cell's
+                cell = api.prepare_cell(pl)
+            except ValueError:
+                self.rejected += 1
+                raise
         seq = self._client_seq.get(client_id, 0)
         self._client_seq[client_id] = seq + 1
         self.admitted += 1

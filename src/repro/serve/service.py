@@ -47,6 +47,7 @@ import dataclasses
 from typing import Dict, List, Optional, Set, Tuple
 
 from .. import api
+from ..metrics.spans import span
 from .cache import ProgramCache
 from .queue import (PendingRun, QuarantinedError, SubmissionQueue,
                     parse_runspec)
@@ -267,11 +268,13 @@ class CertificationService:
     def _complete(self, run: PendingRun, result: api.RunResult,
                   batch: Batch, cache_hit: bool,
                   now: float) -> List[ResultEnvelope]:
+        with span("repro.verdicts", ticket=run.ticket):
+            verdicts = self._verdicts(run.plan, result)
         env = ResultEnvelope(
             ticket=run.ticket, client_id=run.client_id, seq=run.seq,
             spec=run.spec, batched=batch.grouped, cache_hit=cache_hit,
             width=batch.width, arrival=run.arrival, completed=now,
-            verdicts=self._verdicts(run.plan, result), result=result)
+            verdicts=verdicts, result=result)
         return self._release(run, env)
 
     def _dead_letter(self, run: PendingRun, now: float,
@@ -286,19 +289,20 @@ class CertificationService:
 
     def _release(self, run: PendingRun,
                  env: ResultEnvelope) -> List[ResultEnvelope]:
-        run.plan.release()            # drop the cell's data copies
-        run.cell = None
-        self.queue.complete()
-        self.completed += 1
-        # reorder-buffer release
-        held = self._held.setdefault(run.client_id, {})
-        held[run.seq] = env
-        nxt = self._next_seq.get(run.client_id, 0)
-        out: List[ResultEnvelope] = []
-        while nxt in held:
-            out.append(held.pop(nxt))
-            nxt += 1
-        self._next_seq[run.client_id] = nxt
+        with span("repro.release", ticket=run.ticket):
+            run.plan.release()        # drop the cell's data copies
+            run.cell = None
+            self.queue.complete()
+            self.completed += 1
+            # reorder-buffer release
+            held = self._held.setdefault(run.client_id, {})
+            held[run.seq] = env
+            nxt = self._next_seq.get(run.client_id, 0)
+            out: List[ResultEnvelope] = []
+            while nxt in held:
+                out.append(held.pop(nxt))
+                nxt += 1
+            self._next_seq[run.client_id] = nxt
         return out
 
     @staticmethod

@@ -25,7 +25,8 @@ from repro.core.erm import make_random_erm
 from repro.core.partition import even_partition
 from repro.core.runtime import LocalDistERM
 from repro.kernels import fused_round
-from repro.kernels.feature_matvec import BLOCK_D, BLOCK_N, feature_matvec
+from repro.kernels.feature_matvec import BLOCK_D, BLOCK_N, feature_hvp, \
+    feature_matvec, feature_rmatvec
 
 
 @pytest.fixture(scope="module")
@@ -141,3 +142,41 @@ def test_composed_oracle_compiles_at_epsilon_block(one_chip, name):
     compiled = jax.jit(fn).lower(
         *[_shape(one_chip, s) for s in shapes]).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# Every composed kernel, at a block a little wider than one tile.
+NAMED_N, NAMED_DJ = 4096, 500
+NAMED = {
+    "feature_matvec": (lambda A, w: feature_matvec(A, w, interpret=False),
+                       [(NAMED_N, NAMED_DJ), (NAMED_DJ,)]),
+    "feature_rmatvec": (lambda A, r: feature_rmatvec(A, r, interpret=False),
+                        [(NAMED_N, NAMED_DJ), (NAMED_N,)]),
+    "feature_hvp": (lambda A, h, av: feature_hvp(A, h, av, interpret=False),
+                    [(NAMED_N, NAMED_DJ), (NAMED_N,), (NAMED_N,)]),
+    "fused_pgrad": (
+        functools.partial(fused_round.fused_pgrad, n=NAMED_N, lam=1e-5,
+                          interpret=False),
+        [(NAMED_N, NAMED_DJ), (NAMED_N,), (NAMED_DJ,), (NAMED_DJ,)]),
+    "fused_phvp": (
+        functools.partial(fused_round.fused_phvp, n=NAMED_N, lam=1e-5,
+                          interpret=False),
+        [(NAMED_N, NAMED_DJ), (NAMED_N,), (NAMED_N,), (NAMED_DJ,),
+         (NAMED_DJ,)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(NAMED))
+def test_composed_kernels_carry_stable_names(one_chip, name):
+    """Each composed kernel is named for its entry point: the lowered
+    module's custom call carries ``kernel_name``, and the compiled
+    instruction, whose name labels the kernel's events in a device trace,
+    holds it too (vmapped over machines inside a jit, as the oracles call
+    it).  The benchmark's roofline readers select on these names."""
+    fn, shapes = NAMED[name]
+    lowered = jax.jit(jax.vmap(jax.jit(fn))).lower(
+        *[_shape(one_chip, (4,) + s) for s in shapes])
+    assert f'kernel_name = "{name}"' in lowered.as_text()
+    calls = [line.split("=", 1)[0] for line in
+             lowered.compile().as_text().splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert calls and all(name in call for call in calls), calls
