@@ -7,10 +7,29 @@ GEMVs per round on each machine:
     g_j = A_j^T r        (d_j x n) @ (n)     -> the partial-gradient term
 
 On TPU these are tall-skinny matmuls; the kernels below tile them into
-MXU-aligned (multiples of 128) VMEM blocks with an accumulation grid.
+VMEM blocks (MXU-aligned, or a whole shorter dimension) with an
+accumulation grid.
 The contraction dimension is the innermost grid axis, so each output
 block stays resident in VMEM while partial products accumulate into it
 (revisiting semantics), and HBM traffic is one pass over A_j.
+
+A_j is read where it lies, never copied to the block grid: the grid is
+``cdiv(n, bn) x cdiv(d_j, bd)``.  A dimension no longer than its block
+takes the whole dimension as its block (legal at any size, and it never
+overhangs); a longer one that is not a multiple of its block ends in a
+block that overhangs the array.  Past the array's end a TPU reads
+unspecified values (NaN among them, and NaN x 0 is NaN), so on the last
+block along the contraction axis the overhang of the A tile is zeroed in
+VMEM; every other block runs the unmasked body, and a shape its blocks
+divide traces no mask at all.  Overhang along the other axis only feeds
+output rows past n (or d_j), which are sliced off.  Only the small
+vectors (w, r, h, masks) are padded to the grid.
+
+XLA may keep A_j with its rows as the minor dimension (it does for
+400,000 x 500 on a v5e, the layout with the least padding), while the
+kernels read row-major tiles: it then inserts one copy of A_j into that
+layout before the kernel.  A jitted loop that holds A_j fixed hoists the
+copy out of the loop, so it is paid once a solve, not once a round.
 
 Batched right-hand sides are supported (w: (d_j, B), r: (n, B)) because
 DISCO-F's CG and the benchmark harness evaluate multiple vectors at once;
@@ -25,6 +44,7 @@ block VMEM-resident instead of materializing h ⊙ av in HBM first.
 """
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -38,14 +58,15 @@ BLOCK_N = 512
 BLOCK_D = 512
 BLOCK_B = 128
 
-# Padding to the block grid runs under this ``jax.named_scope``, so its
-# device ops carry it in their HLO ``op_name``.  Each kernel's
-# ``pallas_call`` is named for its entry point: the name is the kernel's
-# label in compiled programs and device traces.
+# Padding of the vectors to the block grid (A itself is read in place)
+# runs under this ``jax.named_scope``, so its device ops carry it in
+# their HLO ``op_name``.  Each kernel's ``pallas_call`` is named for its
+# entry point: the name is the kernel's label in compiled programs and
+# device traces.
 PAD_SCOPE = "repro.pad"
 
 
-def _matvec_kernel(a_ref, w_ref, o_ref):
+def _matvec_kernel(a_ref, w_ref, o_ref, *, extent):
     """Grid (n_blocks, b_blocks, d_blocks): o[i,b] += A[i,j] @ w[j,b];
     the contraction axis j is innermost so o stays VMEM-resident."""
     j = pl.program_id(2)
@@ -54,7 +75,10 @@ def _matvec_kernel(a_ref, w_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += _dot(a_ref[...], w_ref[...], o_ref.dtype)
+    def acc(a):
+        o_ref[...] += _dot(a, w_ref[...], o_ref.dtype)
+
+    _with_a_tile(a_ref, acc, extent=extent, dim=1)
 
 
 def feature_matvec(A_j, w_j, *, block_n: int = BLOCK_N,
@@ -66,29 +90,28 @@ def feature_matvec(A_j, w_j, *, block_n: int = BLOCK_N,
         w_j = w_j[:, None]
     n, dj = A_j.shape
     b = w_j.shape[1]
-    bn, bd = min(block_n, _rup(n)), min(block_d, _rup(dj))
+    bn, bd = min(block_n, n), min(block_d, dj)
     bb = min(block_b, _rup(b))
-    A_p = _pad2(A_j, bn, bd)
     w_p = _pad2(w_j, bd, bb)
-    grid = (A_p.shape[0] // bn, w_p.shape[1] // bb, A_p.shape[1] // bd)
+    grid = (pl.cdiv(n, bn), w_p.shape[1] // bb, pl.cdiv(dj, bd))
     out = pl.pallas_call(
-        _matvec_kernel,
+        functools.partial(_matvec_kernel, extent=dj),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, bd), lambda i, k, j: (i, j)),
             pl.BlockSpec((bd, bb), lambda i, k, j: (j, k)),
         ],
         out_specs=pl.BlockSpec((bn, bb), lambda i, k, j: (i, k)),
-        out_shape=jax.ShapeDtypeStruct((A_p.shape[0], w_p.shape[1]),
+        out_shape=jax.ShapeDtypeStruct((_rup(n, bn), w_p.shape[1]),
                                        _acc_dtype(A_j.dtype)),
         interpret=_interp(interpret),
         name="feature_matvec",
-    )(A_p, w_p)
+    )(A_j, w_p)
     out = out[:n, :b].astype(A_j.dtype)
     return out[:, 0] if squeeze else out
 
 
-def _rmatvec_kernel(a_ref, r_ref, o_ref):
+def _rmatvec_kernel(a_ref, r_ref, o_ref, *, extent):
     """Grid (d_blocks, b_blocks, n_blocks): o[j,b] += A[i,j]^T @ r[i,b];
     the contraction axis i is innermost so o stays VMEM-resident."""
     i = pl.program_id(2)
@@ -97,7 +120,10 @@ def _rmatvec_kernel(a_ref, r_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += _dot(a_ref[...].T, r_ref[...], o_ref.dtype)
+    def acc(a):
+        o_ref[...] += _dot(a.T, r_ref[...], o_ref.dtype)
+
+    _with_a_tile(a_ref, acc, extent=extent, dim=0)
 
 
 def feature_rmatvec(A_j, r, *, block_n: int = BLOCK_N,
@@ -109,29 +135,28 @@ def feature_rmatvec(A_j, r, *, block_n: int = BLOCK_N,
         r = r[:, None]
     n, dj = A_j.shape
     b = r.shape[1]
-    bn, bd = min(block_n, _rup(n)), min(block_d, _rup(dj))
+    bn, bd = min(block_n, n), min(block_d, dj)
     bb = min(block_b, _rup(b))
-    A_p = _pad2(A_j, bn, bd)
     r_p = _pad2(r, bn, bb)
-    grid = (A_p.shape[1] // bd, r_p.shape[1] // bb, A_p.shape[0] // bn)
+    grid = (pl.cdiv(dj, bd), r_p.shape[1] // bb, pl.cdiv(n, bn))
     out = pl.pallas_call(
-        _rmatvec_kernel,
+        functools.partial(_rmatvec_kernel, extent=n),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, bd), lambda j, k, i: (i, j)),
             pl.BlockSpec((bn, bb), lambda j, k, i: (i, k)),
         ],
         out_specs=pl.BlockSpec((bd, bb), lambda j, k, i: (j, k)),
-        out_shape=jax.ShapeDtypeStruct((A_p.shape[1], r_p.shape[1]),
+        out_shape=jax.ShapeDtypeStruct((_rup(dj, bd), r_p.shape[1]),
                                        _acc_dtype(A_j.dtype)),
         interpret=_interp(interpret),
         name="feature_rmatvec",
-    )(A_p, r_p)
+    )(A_j, r_p)
     out = out[:dj, :b].astype(A_j.dtype)
     return out[:, 0] if squeeze else out
 
 
-def _hvp_kernel(a_ref, h_ref, r_ref, o_ref):
+def _hvp_kernel(a_ref, h_ref, r_ref, o_ref, *, extent):
     """Grid (d_blocks, b_blocks, n_blocks): o[j,b] += A[i,j]^T (h[i] ⊙
     r[i,b]); the Hadamard happens on the VMEM-resident r block, so the
     scaled residual never round-trips through HBM."""
@@ -141,7 +166,10 @@ def _hvp_kernel(a_ref, h_ref, r_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += _dot(a_ref[...].T, h_ref[...] * r_ref[...], o_ref.dtype)
+    def acc(a):
+        o_ref[...] += _dot(a.T, h_ref[...] * r_ref[...], o_ref.dtype)
+
+    _with_a_tile(a_ref, acc, extent=extent, dim=0)
 
 
 def feature_hvp(A_j, h, av, *, block_n: int = BLOCK_N,
@@ -157,14 +185,13 @@ def feature_hvp(A_j, h, av, *, block_n: int = BLOCK_N,
         av = av[:, None]
     n, dj = A_j.shape
     b = av.shape[1]
-    bn, bd = min(block_n, _rup(n)), min(block_d, _rup(dj))
+    bn, bd = min(block_n, n), min(block_d, dj)
     bb = min(block_b, _rup(b))
-    A_p = _pad2(A_j, bn, bd)
     h_p = _pad2(h[:, None], bn, 1)
     r_p = _pad2(av, bn, bb)
-    grid = (A_p.shape[1] // bd, r_p.shape[1] // bb, A_p.shape[0] // bn)
+    grid = (pl.cdiv(dj, bd), r_p.shape[1] // bb, pl.cdiv(n, bn))
     out = pl.pallas_call(
-        _hvp_kernel,
+        functools.partial(_hvp_kernel, extent=n),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, bd), lambda j, k, i: (i, j)),
@@ -172,11 +199,11 @@ def feature_hvp(A_j, h, av, *, block_n: int = BLOCK_N,
             pl.BlockSpec((bn, bb), lambda j, k, i: (i, k)),
         ],
         out_specs=pl.BlockSpec((bd, bb), lambda j, k, i: (j, k)),
-        out_shape=jax.ShapeDtypeStruct((A_p.shape[1], r_p.shape[1]),
+        out_shape=jax.ShapeDtypeStruct((_rup(dj, bd), r_p.shape[1]),
                                        _acc_dtype(A_j.dtype)),
         interpret=_interp(interpret),
         name="feature_hvp",
-    )(A_p, h_p.astype(A_j.dtype), r_p)
+    )(A_j, h_p.astype(A_j.dtype), r_p)
     out = out[:dj, :b].astype(A_j.dtype)
     return out[:, 0] if squeeze else out
 
@@ -191,6 +218,33 @@ def _dot(a, b, out_dtype):
     f32 = a.dtype == jnp.float32 and b.dtype == jnp.float32
     return jnp.dot(a, b, preferred_element_type=out_dtype,
                    precision=lax.Precision.HIGHEST if f32 else None)
+
+
+def _with_a_tile(a_ref, use, *, extent: int, dim: int):
+    """Call ``use(a)`` on this grid step's A tile.
+
+    ``dim`` is the tile's contraction dimension, which is grid axis 2 in
+    every composed kernel, and ``extent`` the array's size along it.
+    Where the block does not divide the extent, the last block overhangs
+    the array; on that block alone the tile is zeroed past the extent, so
+    the overhang adds exact zeros.  Every other block, and every block of
+    a shape its blocks divide, runs ``use(a_ref[...])`` unmasked."""
+    block = a_ref.shape[dim]
+    if extent % block == 0:
+        use(a_ref[...])
+        return
+    blk = pl.program_id(2)
+    last = blk == pl.num_programs(2) - 1
+
+    @pl.when(last)
+    def _edge():
+        a = a_ref[...]
+        idx = blk * block + lax.broadcasted_iota(jnp.int32, a.shape, dim)
+        use(jnp.where(idx < extent, a, jnp.zeros_like(a)))
+
+    @pl.when(jnp.logical_not(last))
+    def _interior():
+        use(a_ref[...])
 
 
 def _rup(x: int, to: int = 128) -> int:

@@ -31,6 +31,8 @@ kernels here collapse all of that:
   ``feature_rmatvec``/``feature_hvp`` with the gradient epilogue
   (``/n + lam v``, block mask) folded into the last contraction block —
   one A-read per oracle instead of an extra d-vector HBM round-trip.
+  Like those, they read A_j in place: a row block that overhangs the
+  array is zeroed past n in VMEM, and only the vectors are padded.
 
 Conformance contract: wherever ``round_step_fits`` and
 ``channel_stages`` admit a cell, the fused step's ledger stream and
@@ -57,7 +59,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .feature_matvec import (BLOCK_B, BLOCK_D, BLOCK_N, _acc_dtype,
-                             _dot, _interp, _pad2, _rup)
+                             _dot, _interp, _pad2, _rup, _with_a_tile)
 from ..core.channel import Channel, ScheduledChannel
 
 # The whole-round kernel keeps machine j's entire padded A_j block in
@@ -268,7 +270,7 @@ def make_round_step(A_stk, mask, y_data, loss, *, n: int, lam: float,
 # Epilogue-fused composed oracles (the fallback / DISCO-F CG variant)
 # --------------------------------------------------------------------------
 
-def _pgrad_kernel(a_ref, r_ref, w_ref, mk_ref, o_ref, *, n, lam):
+def _pgrad_kernel(a_ref, r_ref, w_ref, mk_ref, o_ref, *, n, lam, extent):
     """Grid (d_blocks, b_blocks, n_blocks): o[j,b] += A[i,j]^T @ r[i,b]
     with the gradient epilogue (o/n + lam w) * mask folded into the last
     contraction block, so the partial gradient never round-trips HBM
@@ -279,7 +281,10 @@ def _pgrad_kernel(a_ref, r_ref, w_ref, mk_ref, o_ref, *, n, lam):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += _dot(a_ref[...].T, r_ref[...], o_ref.dtype)
+    def acc(a):
+        o_ref[...] += _dot(a.T, r_ref[...], o_ref.dtype)
+
+    _with_a_tile(a_ref, acc, extent=extent, dim=0)
 
     @pl.when(i == pl.num_programs(2) - 1)
     def _epilogue():
@@ -301,15 +306,14 @@ def fused_pgrad(A_j, r, w_j, mask_j, *, n: int, lam: float,
         w_j = w_j[:, None]
     n_rows, dj = A_j.shape
     b = r.shape[1]
-    bn, bd = min(block_n, _rup(n_rows)), min(block_d, _rup(dj))
+    bn, bd = min(block_n, n_rows), min(block_d, dj)
     bb = min(block_b, _rup(b))
-    A_p = _pad2(A_j, bn, bd)
     r_p = _pad2(r, bn, bb)
     w_p = _pad2(w_j.astype(A_j.dtype), bd, bb)
     mk_p = _pad2(mask_j[:, None].astype(A_j.dtype), bd, 1)
-    grid = (A_p.shape[1] // bd, r_p.shape[1] // bb, A_p.shape[0] // bn)
+    grid = (pl.cdiv(dj, bd), r_p.shape[1] // bb, pl.cdiv(n_rows, bn))
     out = pl.pallas_call(
-        functools.partial(_pgrad_kernel, n=n, lam=lam),
+        functools.partial(_pgrad_kernel, n=n, lam=lam, extent=n_rows),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, bd), lambda j, k, i: (i, j)),
@@ -318,16 +322,17 @@ def fused_pgrad(A_j, r, w_j, mask_j, *, n: int, lam: float,
             pl.BlockSpec((bd, 1), lambda j, k, i: (j, 0)),
         ],
         out_specs=pl.BlockSpec((bd, bb), lambda j, k, i: (j, k)),
-        out_shape=jax.ShapeDtypeStruct((A_p.shape[1], r_p.shape[1]),
+        out_shape=jax.ShapeDtypeStruct((w_p.shape[0], r_p.shape[1]),
                                        _acc_dtype(A_j.dtype)),
         interpret=_interp(interpret),
         name="fused_pgrad",
-    )(A_p, r_p, w_p, mk_p)
+    )(A_j, r_p, w_p, mk_p)
     out = out[:dj, :b].astype(A_j.dtype)
     return out[:, 0] if squeeze else out
 
 
-def _phvp_kernel(a_ref, h_ref, r_ref, v_ref, mk_ref, o_ref, *, n, lam):
+def _phvp_kernel(a_ref, h_ref, r_ref, v_ref, mk_ref, o_ref, *, n, lam,
+                 extent):
     """Grid (d_blocks, b_blocks, n_blocks): o[j,b] += A[i,j]^T (h[i] ⊙
     r[i,b]) with the HVP epilogue (o/n + lam v) * mask folded into the
     last contraction block — DISCO-F's CG applies this every inner
@@ -338,7 +343,10 @@ def _phvp_kernel(a_ref, h_ref, r_ref, v_ref, mk_ref, o_ref, *, n, lam):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += _dot(a_ref[...].T, h_ref[...] * r_ref[...], o_ref.dtype)
+    def acc(a):
+        o_ref[...] += _dot(a.T, h_ref[...] * r_ref[...], o_ref.dtype)
+
+    _with_a_tile(a_ref, acc, extent=extent, dim=0)
 
     @pl.when(i == pl.num_programs(2) - 1)
     def _epilogue():
@@ -359,16 +367,15 @@ def fused_phvp(A_j, h, av, v_j, mask_j, *, n: int, lam: float,
         v_j = v_j[:, None]
     n_rows, dj = A_j.shape
     b = av.shape[1]
-    bn, bd = min(block_n, _rup(n_rows)), min(block_d, _rup(dj))
+    bn, bd = min(block_n, n_rows), min(block_d, dj)
     bb = min(block_b, _rup(b))
-    A_p = _pad2(A_j, bn, bd)
     h_p = _pad2(h[:, None], bn, 1)
     r_p = _pad2(av, bn, bb)
     v_p = _pad2(v_j.astype(A_j.dtype), bd, bb)
     mk_p = _pad2(mask_j[:, None].astype(A_j.dtype), bd, 1)
-    grid = (A_p.shape[1] // bd, r_p.shape[1] // bb, A_p.shape[0] // bn)
+    grid = (pl.cdiv(dj, bd), r_p.shape[1] // bb, pl.cdiv(n_rows, bn))
     out = pl.pallas_call(
-        functools.partial(_phvp_kernel, n=n, lam=lam),
+        functools.partial(_phvp_kernel, n=n, lam=lam, extent=n_rows),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, bd), lambda j, k, i: (i, j)),
@@ -378,10 +385,10 @@ def fused_phvp(A_j, h, av, v_j, mask_j, *, n: int, lam: float,
             pl.BlockSpec((bd, 1), lambda j, k, i: (j, 0)),
         ],
         out_specs=pl.BlockSpec((bd, bb), lambda j, k, i: (j, k)),
-        out_shape=jax.ShapeDtypeStruct((A_p.shape[1], r_p.shape[1]),
+        out_shape=jax.ShapeDtypeStruct((v_p.shape[0], r_p.shape[1]),
                                        _acc_dtype(A_j.dtype)),
         interpret=_interp(interpret),
         name="fused_phvp",
-    )(A_p, h_p.astype(A_j.dtype), r_p, v_p, mk_p)
+    )(A_j, h_p.astype(A_j.dtype), r_p, v_p, mk_p)
     out = out[:dj, :b].astype(A_j.dtype)
     return out[:, 0] if squeeze else out
