@@ -375,7 +375,9 @@ def phase_sharded(checks):
     for dev in jax.devices():
         print(f"  {dev}: peak_bytes_in_use {_peak_bytes(dev)} after the "
               f"sharded solve")
-    ref = _reference(spec.replace(placement="local"), bundle)
+    # the same recipe on one chip: the sharded build carries its bits
+    local = spec.replace(placement="local")
+    ref = _reference(local, api.plan(local).bundle)
     _compare(checks, "sharded vs local einsum", pl, res, ref, "epsilon")
     for dev in jax.devices():
         print(f"  {dev}: peak_bytes_in_use {_peak_bytes(dev)} after the "

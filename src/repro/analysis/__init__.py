@@ -156,23 +156,13 @@ def _audit_local(pl, cell: CellAudit, chan, coords,
 
 def _audit_sharded(pl, cell: CellAudit, chan, coords,
                    execute: bool) -> None:
-    from ..core.runtime import _run_sharded
-
-    b = pl.bundle
-    kwargs = pl.algo_kwargs()
-    closed, led, spans = _run_sharded(
-        b.prob, None, rounds=pl.spec.rounds, ledger=CommLedger(),
-        backend=pl.backend, engine="scan",
-        program_builder=lambda d_, r: pl.algo.program(d_, r, **kwargs),
-        channel=pl.wire_channel(), trace_only=True)
+    # the module execute() runs, in-scan measure included
+    program = pl._sharded_program(engine="scan")
+    closed = program.trace()
+    led, spans = program.ledger, program.spans
     executed_led: Optional[CommLedger] = None
     if execute:
-        _, executed_led = _run_sharded(
-            b.prob, None, rounds=pl.spec.rounds, ledger=CommLedger(),
-            backend=pl.backend, engine="scan",
-            program_builder=lambda d_, r: pl.algo.program(d_, r,
-                                                          **kwargs),
-            channel=pl.wire_channel())
+        _, _, executed_led = program(CommLedger())
         cell.executed = True
     fs, stats = verify_sharded_schedule(closed, led, spans, chan,
                                         executed_ledger=executed_led)

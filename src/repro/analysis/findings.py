@@ -35,6 +35,8 @@ CODES = (
     "class-oob",        # cross-machine combination outside a comm scope
     "class-unknown",    # propagation hit an unmodeled primitive (unsound
                         # to certify past it)
+    "class-measure",    # collective of the in-scan measure (repro.gap
+                        # scope): measurement, not metered (info)
     "thm4-payload",     # incremental inner round ships a non-scalar
     # compile-hazard / determinism lints
     "lint-rng",         # RNG primitive inside a step jaxpr

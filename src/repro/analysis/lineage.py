@@ -524,18 +524,32 @@ def certify_sharded_class(closed, algorithm: str = "",
                           channel: str = "") -> List[Finding]:
     """Under the sharded placement machines are mesh shards, so the
     class boundary is syntactic: every collective primitive must sit
-    inside a communicator scope."""
+    inside a communicator scope.  The one exception is the in-scan
+    measure of f(w_k) - f* (scope ``repro.gap``): its psums compute the
+    gap series, which leaves the program as the scan's per-round output
+    and never reaches the iterate, so they are measurement, not
+    communication; each is reported as a ``class-measure`` info."""
+    from ..core.engine import GAP_SCOPE
     out: List[Finding] = []
     for eqn, path in iter_eqns(closed.jaxpr):
-        if eqn.primitive.name in _COLLECTIVES \
-                and comm_token(eqn) is None:
+        if eqn.primitive.name not in _COLLECTIVES \
+                or comm_token(eqn) is not None:
+            continue
+        if GAP_SCOPE in str(eqn.source_info.name_stack).split("/"):
             out.append(Finding(
-                "class-oob", "error",
-                f"collective '{eqn.primitive.name}' outside a "
-                f"communicator scope — cross-machine information flow "
-                f"the ledger never priced", eqn=format_eqn(eqn),
-                path=path, algorithm=algorithm, placement="sharded",
-                channel=channel))
+                "class-measure", "info",
+                f"collective '{eqn.primitive.name}' of the in-scan "
+                f"measure ({GAP_SCOPE}): measurement, not metered",
+                eqn=format_eqn(eqn), path=path, algorithm=algorithm,
+                placement="sharded", channel=channel))
+            continue
+        out.append(Finding(
+            "class-oob", "error",
+            f"collective '{eqn.primitive.name}' outside a "
+            f"communicator scope — cross-machine information flow "
+            f"the ledger never priced", eqn=format_eqn(eqn),
+            path=path, algorithm=algorithm, placement="sharded",
+            channel=channel))
     return out
 
 

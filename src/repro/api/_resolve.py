@@ -37,7 +37,7 @@ the call-time indirection avoids.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import jax
 
@@ -105,9 +105,42 @@ def resolve_faults(faults: Optional[str] = None) -> str:
     return _axes.resolve(_axes.AXES_BY_NAME["faults"], faults)
 
 
-def resolve_placement(placement: Optional[str] = None) -> str:
-    """``None``/``"auto"`` -> ``local``.  The sharded placement is an
-    explicit opt-in: it needs a mesh and its ledger records at trace
-    time, so silently switching on device count would change metering
-    conventions under the caller."""
-    return _axes.resolve(_axes.AXES_BY_NAME["placement"], placement)
+def device_bytes_limit() -> Optional[int]:
+    """One device's memory as its runtime reports it (``bytes_limit``),
+    or None where it reports none (the CPU)."""
+    return (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+
+
+def dense_footprint(n: int, d: int, m: int) -> int:
+    """Bytes one device needs to run an n x d float32 instance of m
+    machines locally: A itself, plus the copy of every machine's block
+    in the row-major tiles of 128 lanes the oracle kernels read."""
+    d_j = -(-d // m)
+    return 4 * n * d + 4 * n * m * (-(-d_j // 128) * 128)
+
+
+def resolve_placement(placement: Optional[str] = None, *,
+                      shape: Optional[Tuple[int, int, int]] = None,
+                      caps: Optional[dict] = None) -> str:
+    """``None``/``"auto"`` -> ``local`` for every instance one device
+    can hold, and ``sharded`` for one it cannot: where the instance's
+    ``shape`` (n, d, m) gives a ``dense_footprint`` above one device's
+    memory and the host has at least m devices (the mesh is m of them,
+    one machine each).  The choice follows from what the process
+    observes, never from an option; both placements meter the same
+    ledger, record for record and mark for mark
+    (``tests/test_sharded_instance.py`` and the conformance suites pin
+    it), so switching changes where the data lies and nothing a
+    certificate reads."""
+    axis = _axes.AXES_BY_NAME["placement"]
+    resolved = _axes.resolve(axis, placement)
+    if placement not in axis.auto_values or shape is None:
+        return resolved
+    n, d, m = shape
+    caps = caps if caps is not None else capabilities()
+    if caps["devices"] < m:
+        return resolved
+    limit = device_bytes_limit()
+    if limit is not None and dense_footprint(n, d, m) > limit:
+        return "sharded"
+    return resolved

@@ -1,20 +1,23 @@
 """``plan(spec) -> ExecutionPlan`` — validate a run before paying for it.
 
 Planning is where every ``"auto"`` in a ``RunSpec`` becomes a concrete
-choice (one resolver, ``repro.api._resolve``, consulted at plan time)
-and where incompatible combinations are rejected eagerly: unknown
-algorithm or instance names, instance parameters the builder does not
-accept, eps thresholds without measurement, gap measurement under the
-sharded placement (whose driver has no measurement channel), hyper-
-parameter overrides the algorithm's program does not take.  A failed
-plan costs microseconds; a failed run costs a compile.
+choice (one resolver, ``repro.api._resolve``, consulted at plan time;
+``auto`` placement shards an instance one device cannot hold) and where
+incompatible combinations are rejected eagerly: unknown algorithm or
+instance names, instance parameters the builder does not accept, eps
+thresholds without measurement, gap measurement under the sharded
+placement outside the scan engine (the one that measures inside the
+``shard_map`` program), hyper-parameter overrides the algorithm's
+program does not take.  A failed plan costs microseconds; a failed run
+costs a compile.
 
 An ``ExecutionPlan`` then drives the existing machinery:
 
   * ``execute()`` — one metered run through ``LocalDistERM`` +
-    ``run_program`` (or ``shard_map`` via the ``core.runtime`` driver for
-    the sharded placement), returning a ``RunResult`` with the final
-    iterate, the per-round gap series, and a fresh ``CommLedger``.
+    ``run_program`` (or a ``core.runtime.ShardedProgram``, compiled once
+    per plan, for the sharded placement), returning a ``RunResult`` with
+    the final iterate, the per-round gap series, and a fresh
+    ``CommLedger``.
   * ``bound(eps_abs)`` — the closed-form theorem report certifying this
     (instance, algorithm) pair: Thm 2 (λ>0) / Thm 3 (λ=0) for the
     non-incremental family, Thm 4 for the incremental one.
@@ -32,14 +35,16 @@ import inspect
 from typing import List, Optional, Tuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh
 
 from ..core.bounds import (BoundReport, thm2_strongly_convex,
                            thm3_smooth_convex, thm4_incremental)
 from ..core.comm import CommLedger
 from ..core.engine import EngineSession, run_program
 from ..experiments.instances import INSTANCE_BUILDERS, InstanceBundle, \
-    build_instance
+    build_instance, builds_sharded, instance_shape
 from ..experiments.registry import ALGORITHM_REGISTRY, AlgorithmSpec, \
     get_algorithm
 from ..metrics.spans import span
@@ -126,6 +131,7 @@ class ExecutionPlan:
     faults: str = "none"              # canonical core.faults name
     _bundle: Optional[InstanceBundle] = None
     _cell_cache: Optional[tuple] = None
+    _sharded: Optional[object] = None     # core.runtime.ShardedProgram
     _gap0: Optional[float] = None
     _wire: Optional[str] = None       # gap: spec resolved to sched: (lazy)
 
@@ -148,8 +154,24 @@ class ExecutionPlan:
                             "to build")
         if self._bundle is None:
             self._bundle = build_instance(self.spec.instance,
+                                          mesh=self._mesh(),
                                           **self.spec.instance_params)
         return self._bundle
+
+    def _mesh(self) -> Optional[Mesh]:
+        """The machines' devices on one axis, where a sharded plan's
+        builder lays its data out itself (``builds_sharded``) and the
+        host has the m devices; else None, and the sharded driver shards
+        a one-device A over every device as it runs."""
+        if self.placement != "sharded" \
+                or not builds_sharded(self.spec.instance):
+            return None
+        m = instance_shape(self.spec.instance,
+                           self.spec.instance_params)[2]
+        devices = jax.devices()
+        if len(devices) < m:
+            return None
+        return Mesh(np.array(devices[:m]), ("model",))
 
     def algo_kwargs(self) -> dict:
         return dict(self.algo.make_kwargs(self.bundle.ctx),
@@ -300,10 +322,14 @@ class ExecutionPlan:
         (``core.comm.collective_bytes_from_lowered``): the module must
         carry at least the collective traffic the trace-once ledger
         metered, or the wire meter is lying about the compiled program.
-        Returns the ``CollectiveAudit``; ``plan(spec,
-        verify=("hlo-bytes",))`` is the raising front door.  Lowering
-        always happens through the scan driver (the python driver has no
-        whole-program module to audit)."""
+        The module is the one ``execute()`` runs, lowered through the
+        scan driver (the python driver has no whole-program module to
+        audit): with gap measurement it also holds the measure's psums
+        of f(w_k) under the ``repro.gap`` scope, which are measurement,
+        not communication, and are counted apart
+        (``CollectiveAudit.measure_bytes_by_op``).  Returns the
+        ``CollectiveAudit``; ``plan(spec, verify=("hlo-bytes",))`` is the
+        raising front door."""
         if self.placement != "sharded":
             raise PlanError(
                 "verify analysis 'hlo-bytes' audits the compiled XLA "
@@ -312,23 +338,18 @@ class ExecutionPlan:
                 "machines on one device, so its module has none) — use "
                 "placement='sharded', or verify='static' for local cells")
         from ..core.comm import collective_bytes_from_lowered
-        from ..core.runtime import _run_sharded
-        b = self.bundle
-        kwargs = self.algo_kwargs()
-        lowered, led, _ = _run_sharded(
-            b.prob, None, rounds=self.spec.rounds, ledger=CommLedger(),
-            backend=self.backend, engine="scan",
-            program_builder=lambda d_, r: self.algo.program(d_, r,
-                                                            **kwargs),
-            channel=self.wire_channel(), lower_only=True)
-        audit = collective_bytes_from_lowered(lowered)
-        traced = sum(r.bytes for r in led.records)
-        if led.records and audit.total_bytes < traced:
+        from ..core.engine import GAP_SCOPE
+        program = self._sharded_program(engine="scan")
+        audit = collective_bytes_from_lowered(program.lower(),
+                                              measure_scope=GAP_SCOPE)
+        traced = sum(r.bytes for r in program.ledger.records)
+        if program.ledger.records and audit.wire_bytes < traced:
             raise PlanError(
                 f"hlo-bytes audit rejected "
                 f"{self.spec.algorithm}/{self.channel}: the lowered "
-                f"module carries {audit.total_bytes} collective bytes "
-                f"but the trace-once ledger metered {traced}")
+                f"module carries {audit.wire_bytes} collective bytes "
+                f"outside the measure but the trace-once ledger "
+                f"metered {traced}")
         return audit
 
     def release(self) -> None:
@@ -337,6 +358,7 @@ class ExecutionPlan:
         cell's records so peak memory stays one grid point, not the whole
         grid; the plan can still re-execute (everything rebuilds)."""
         self._cell_cache = None
+        self._sharded = None
         self._bundle = None
 
     def execute(self, session: Optional[EngineSession] = None) -> RunResult:
@@ -358,29 +380,51 @@ class ExecutionPlan:
             ledger=ledger, gaps=res.gaps, budget_ok=self._budget_ok(ledger))
 
     def _execute_sharded(self) -> RunResult:
-        from ..core.runtime import _run_sharded
-        b = self.bundle
-        kwargs = self.algo_kwargs()
-        ledger = CommLedger()
-        if self.engine == "python":
-            w, led = _run_sharded(
-                b.prob, lambda d_, r: self.algo.fn(d_, r, **kwargs),
-                rounds=self.spec.rounds, ledger=ledger,
-                backend=self.backend, engine="python",
-                channel=self.wire_channel())
-        else:
-            w, led = _run_sharded(
-                b.prob, None, rounds=self.spec.rounds, ledger=ledger,
-                backend=self.backend, engine="scan",
-                program_builder=lambda d_, r: self.algo.program(d_, r,
-                                                                **kwargs),
-                channel=self.wire_channel())
+        if self._sharded is None:
+            self._sharded = self._sharded_program()
+        w, gaps, led = self._sharded(CommLedger())
         return RunResult(
             spec=self.spec, placement=self.placement, backend=self.backend,
             engine=self.engine, channel=self.channel,
             wire_channel=self.wire_channel(),
             w=w, rounds=led.rounds, ledger=led,
-            gaps=None, budget_ok=self._budget_ok(led))
+            gaps=None if gaps is None else np.asarray(gaps),
+            budget_ok=self._budget_ok(led))
+
+    def _sharded_program(self, engine: Optional[str] = None):
+        """The plan's ``ShardedProgram`` (under ``engine``, by default
+        the plan's), with the in-scan measure f(w_k) - f* evaluated from
+        each machine's block."""
+        from ..core.runtime import ShardedProgram
+        engine = self.engine if engine is None else engine
+        b = self.bundle
+        kwargs = self.algo_kwargs()
+        measure = None
+        if self.measure == "gap":
+            if b.fstar is None:
+                raise PlanError(f"instance {b.kind!r} has no fstar; "
+                                f"run with measure='none'")
+            if b.objective != b.prob.value:
+                raise PlanError(
+                    f"instance {b.kind!r} adds a regularizer to the ERM "
+                    f"objective; the sharded gap measurement evaluates the "
+                    f"ERM objective from the blocks — measure it with "
+                    f"placement='local'")
+            fstar = jnp.float32(b.fstar)
+
+            def measure(dist, w_loc):
+                return dist.objective(w_loc) - fstar
+
+        if engine == "python":
+            return ShardedProgram(
+                b.prob, self.spec.rounds, backend=self.backend,
+                engine="python", channel=self.wire_channel(),
+                algorithm_body=lambda d_, r: self.algo.fn(d_, r, **kwargs))
+        return ShardedProgram(
+            b.prob, self.spec.rounds, backend=self.backend, engine="scan",
+            channel=self.wire_channel(), measure=measure,
+            program_builder=lambda d_, r: self.algo.program(d_, r,
+                                                            **kwargs))
 
 
 # --------------------------------------------------------------------------
@@ -392,11 +436,12 @@ def _validate_instance(spec: RunSpec) -> None:
         raise PlanError(f"unknown instance {spec.instance!r}; known: "
                         f"{sorted(INSTANCE_BUILDERS)}")
     sig = inspect.signature(INSTANCE_BUILDERS[spec.instance])
-    unknown = set(spec.instance_params) - set(sig.parameters)
+    accepted = set(sig.parameters) - {"mesh"}     # a placement, not a
+    unknown = set(spec.instance_params) - accepted    # parameter
     if unknown:
         raise PlanError(
             f"instance {spec.instance!r} does not accept parameter(s) "
-            f"{sorted(unknown)}; accepted: {sorted(sig.parameters)}")
+            f"{sorted(unknown)}; accepted: {sorted(accepted)}")
 
 
 def _validate_algorithm(spec: RunSpec) -> AlgorithmSpec:
@@ -466,8 +511,11 @@ def plan(spec: RunSpec,
         metered (``ExecutionPlan.audit_hlo_bytes``)."""
     analyses = _verify_analyses(verify)
     caps = _resolve.capabilities()
+    shape = (instance_shape(spec.instance, spec.instance_params)
+             if spec.instance is not None else None)
     try:
-        placement = _resolve.resolve_placement(spec.placement)
+        placement = _resolve.resolve_placement(spec.placement, shape=shape,
+                                               caps=caps)
         backend = _resolve.resolve_oracle_backend(spec.backend, caps=caps)
         engine = _resolve.resolve_engine(spec.engine)
         channel = _resolve.resolve_channel(spec.channel)
@@ -513,14 +561,14 @@ def plan(spec: RunSpec,
         raise PlanError(
             "gap-adaptive channels need the local placement (the "
             "schedule is resolved from an identity probe's measured gap "
-            "series, and the sharded driver has no measurement channel); "
-            "pin an explicit sched: channel for sharded runs")
+            "series, run locally); pin an explicit sched: channel for "
+            "sharded runs")
     if placement == "sharded":
-        if measure == "gap":
+        if measure == "gap" and engine != "scan":
             raise PlanError(
-                "gap measurement is not supported under the sharded "
-                "placement (the shard_map driver has no measurement "
-                "channel); use placement='local' for certification cells")
+                "gap measurement under the sharded placement runs inside "
+                "the scan engine's shard_map program; use engine='scan' "
+                "(or placement='local')")
         if algo.local_only_kwargs:
             raise PlanError(
                 f"algorithm {algo.name!r} derives machine-stacked hyper-"

@@ -806,14 +806,36 @@ def _group_size(line: str) -> int:
 class CollectiveAudit:
     bytes_by_op: Dict[str, int]
     count_by_op: Dict[str, int]
+    # the part of bytes_by_op whose ops carry the measure's scope in
+    # their op_name: measurement, not communication
+    measure_bytes_by_op: Dict[str, int] = dataclasses.field(
+        default_factory=dict)
 
     @property
     def total_bytes(self) -> int:
         return sum(self.bytes_by_op.values())
 
+    @property
+    def wire_bytes(self) -> int:
+        """Collective bytes outside the measure's scope."""
+        return self.total_bytes - sum(self.measure_bytes_by_op.values())
 
-def collective_bytes_from_hlo(hlo_text: str) -> CollectiveAudit:
-    """Sum payload bytes of collective ops in an HLO module text.
+
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+
+
+def _in_scope(hlo_line: str, scope: str) -> bool:
+    """Whether an HLO op's ``op_name`` metadata holds ``scope``."""
+    m = _OP_NAME_RE.search(hlo_line)
+    return m is not None and scope in m.group(1).split("/")
+
+
+def collective_bytes_from_hlo(hlo_text: str,
+                              measure_scope: Optional[str] = None
+                              ) -> CollectiveAudit:
+    """Sum payload bytes of collective ops in an HLO module text; those
+    whose ``op_name`` holds the scope ``measure_scope`` are also summed
+    apart, as measurement.
 
     Methodology (documented for the roofline):
       * all-reduce / all-to-all / collective-permute: result bytes
@@ -826,6 +848,7 @@ def collective_bytes_from_hlo(hlo_text: str) -> CollectiveAudit:
     """
     bytes_by_op: Dict[str, int] = {}
     count_by_op: Dict[str, int] = {}
+    measure: Dict[str, int] = {}
     for line in hlo_text.splitlines():
         stripped = line.strip()
         if "=" not in stripped:
@@ -849,12 +872,17 @@ def collective_bytes_from_hlo(hlo_text: str) -> CollectiveAudit:
             nbytes *= _group_size(stripped)
         bytes_by_op[opname] = bytes_by_op.get(opname, 0) + nbytes
         count_by_op[opname] = count_by_op.get(opname, 0) + 1
-    return CollectiveAudit(bytes_by_op, count_by_op)
+        if measure_scope is not None and _in_scope(stripped,
+                                                   measure_scope):
+            measure[opname] = measure.get(opname, 0) + nbytes
+    return CollectiveAudit(bytes_by_op, count_by_op, measure)
 
 
-def collective_bytes_from_lowered(lowered) -> CollectiveAudit:
-    """Audit a ``jax.stages.Lowered`` computation (e.g. the sharded
-    driver's ``lower_only=True`` product): compile it and sum the
+def collective_bytes_from_lowered(lowered,
+                                  measure_scope: Optional[str] = None
+                                  ) -> CollectiveAudit:
+    """Audit a ``jax.stages.Lowered`` computation (e.g.
+    ``ShardedProgram.lower()``'s product): compile it and sum the
     collective payloads of the optimized HLO module.  Compilation beats
     auditing the pre-optimization text — it is what actually runs, after
     fusion, async splitting, and collective combining."""
@@ -864,4 +892,4 @@ def collective_bytes_from_lowered(lowered) -> CollectiveAudit:
         # some backends cannot render compiled HLO; the pre-optimization
         # lowering still names every collective
         text = lowered.as_text(dialect="hlo")
-    return collective_bytes_from_hlo(text)
+    return collective_bytes_from_hlo(text, measure_scope)

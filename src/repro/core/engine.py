@@ -60,6 +60,7 @@ from jax import lax
 
 from ..metrics.spans import span
 from .comm import CommLedger, inject_crash_recovery
+from .erm import spans_devices
 from .faults import FaultRecoveryError
 
 
@@ -316,8 +317,9 @@ def hoisted_jit(fn: Callable) -> Callable:
     deployment sizes that copies gigabytes through lowering, compile and
     the compilation cache, and again into device memory.  Here ``fn`` is
     traced once per argument signature (``trace_closure``) and its consts
-    of ``HOIST_BYTES`` or more are fed back as arguments of one jitted
-    evaluator.  Smaller consts (step sizes, small instances) stay
+    of ``HOIST_BYTES`` or more, and those laid out over several devices
+    (a constant would lose the layout), are fed back as arguments of one
+    jitted evaluator.  Smaller consts (step sizes, small instances) stay
     constants: XLA folds them, so passing them in would change the f32
     bits of every small run from what ``jax.jit(fn)`` computes."""
     traced = {}
@@ -329,7 +331,8 @@ def hoisted_jit(fn: Callable) -> Callable:
             closed, pure = trace_closure(fn, *args)
             consts = list(closed.consts)
             big = [i for i, c in enumerate(consts)
-                   if getattr(c, "nbytes", 0) >= HOIST_BYTES]
+                   if getattr(c, "nbytes", 0) >= HOIST_BYTES
+                   or spans_devices(c)]
 
             def run(hoisted, *args):
                 full = list(consts)

@@ -26,6 +26,8 @@ import numpy as np
 import scipy.linalg
 import jax
 import jax.numpy as jnp
+from jax import lax
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 @dataclasses.dataclass(frozen=True)
@@ -128,9 +130,14 @@ class ERMProblem:
         platform: the TPU's SVD takes minutes to compile at d in the
         thousands, LAPACK seconds to run.  SciPy's ``gesdd`` is the routine
         JAX's CPU SVD calls, so this equals ``jnp.linalg.norm(A, ord=2)``
-        on the CPU bit for bit."""
-        smax = scipy.linalg.svdvals(np.asarray(self.A))[0]
-        return float(self.loss.smoothness * smax ** 2 / self.n + self.lam)
+        on the CPU bit for bit.  An A that spans several devices never
+        comes to the host: sigma_max^2 is the largest eigenvalue of its
+        Gram matrix (``gram``)."""
+        if spans_devices(self.A):
+            smax_sq = float(np.linalg.eigvalsh(gram(self.A))[-1])
+        else:
+            smax_sq = scipy.linalg.svdvals(np.asarray(self.A))[0] ** 2
+        return float(self.loss.smoothness * smax_sq / self.n + self.lam)
 
     # ---- feature-partitioned oracles (machine-local pieces) ------------
     # These are the per-machine computations; the single ReduceAll that
@@ -149,24 +156,112 @@ class ERMProblem:
         return A_j.T @ (h * av) / self.n + self.lam * v_j
 
 
-def make_random_erm(n: int, d: int, loss: str = "squared", lam: float = 1e-2,
-                    seed: int = 0, cond: Optional[float] = None) -> ERMProblem:
-    """Synthetic ERM instance. If ``cond`` is set, shape A's spectrum to
-    roughly that condition number (for controlled kappa experiments)."""
+def spans_devices(x) -> bool:
+    """Whether the array ``x`` is laid out over more than one device."""
+    sharding = getattr(x, "sharding", None)
+    return sharding is not None and len(sharding.device_set) > 1
+
+
+# Rows of a sharded A that ``gram`` multiplies, or that the sharded draws
+# gather onto every device, at once.
+CHUNK_ROWS = 1 << 16
+
+
+def gram(A) -> np.ndarray:
+    """A^T A in float64 on the host, for an A that may span devices.
+
+    Each chunk of ``CHUNK_ROWS`` rows is multiplied on the devices where
+    it lies, at ``Precision.HIGHEST`` (d x d float32 partial sums); the
+    host adds the chunks in float64.  Only d x d values leave the
+    devices, never A."""
+    n, d = A.shape
+    rows = CHUNK_ROWS
+
+    def product(B):
+        return lax.dot_general(B, B, (((0,), (0,)), ((), ())),
+                               precision=lax.Precision.HIGHEST)
+
+    chunk = jax.jit(lambda A, s: product(
+        lax.dynamic_slice_in_dim(A, s, rows, axis=0)))
+    G = np.zeros((d, d), np.float64)
+    full = n // rows
+    for k in range(full):
+        G += np.asarray(chunk(A, k * rows), np.float64)
+    if n > full * rows:
+        G += np.asarray(jax.jit(product)(A[full * rows:]), np.float64)
+    return G
+
+
+def _sharded_draws(ka, kw, kn, n: int, d: int, mesh: Mesh):
+    """The recipe's random draws and response z = A w_true with A laid
+    out column-sharded over ``mesh`` (one axis) and the vectors
+    replicated, bit for bit the draws of the one-device recipe.  The
+    threefry generator is partitionable, so a draw's bits do not depend
+    on its layout; the elementwise steps run op by op as the recipe's
+    do; z is the recipe's dot, taken on chunks of ``CHUNK_ROWS`` whole
+    rows gathered onto every device in turn (each row's sum runs over
+    all d features, as on one device, and no device holds more than A's
+    block and one chunk)."""
+    axis, = mesh.axis_names
+    every = NamedSharding(mesh, P())
+
+    def draw(key, shape, spec):
+        return jax.jit(lambda k: jax.random.normal(k, shape),
+                       out_shardings=NamedSharding(mesh, spec))(key)
+
+    A = draw(ka, (n, d), P(None, axis)) / jnp.sqrt(d)
+    w_true = draw(kw, (d,), P())
+    rows = min(CHUNK_ROWS, n)
+    take = jax.jit(lambda A, s: lax.dynamic_slice_in_dim(A, s, rows),
+                   out_shardings=every)
+    dot = jax.jit(lambda B, w: B @ w)
+    z = [dot(take(A, s), w_true) for s in range(0, n - rows + 1, rows)]
+    if n % rows:
+        tail = jax.jit(lambda A: A[n - n % rows:], out_shardings=every)
+        z.append(dot(tail(A), w_true))
+    noise = draw(kn, (n,), P())
+    return A, w_true, jnp.concatenate(z), noise
+
+
+def random_erm_data(n: int, d: int, loss: str = "squared", seed: int = 0,
+                    cond: Optional[float] = None,
+                    mesh: Optional[Mesh] = None):
+    """(A, y, w_true) of ``make_random_erm``'s recipe.  With ``mesh``
+    (a one-axis mesh of the machines' devices) A is built column-sharded
+    over it and y, w_true replicated, never whole on one device; the
+    bits are the one-device recipe's."""
     key = jax.random.PRNGKey(seed)
     ka, kw, kn = jax.random.split(key, 3)
-    A = jax.random.normal(ka, (n, d)) / jnp.sqrt(d)
-    if cond is not None:
-        u, s, vt = jnp.linalg.svd(A, full_matrices=False)
-        k = s.shape[0]
-        s_new = jnp.geomspace(1.0, 1.0 / jnp.sqrt(cond), k)
-        A = (u * s_new) @ vt
-    w_true = jax.random.normal(kw, (d,))
-    z = A @ w_true
-    lf = LOSSES[loss]()
-    if loss == "squared":
-        y = z + 0.01 * jax.random.normal(kn, (n,))
+    if mesh is not None:
+        if cond is not None:
+            raise ValueError("cond shapes A's spectrum by an SVD of the "
+                             "whole A; it is not available with mesh")
+        A, w_true, z, noise = _sharded_draws(ka, kw, kn, n, d, mesh)
     else:
-        y = jnp.sign(z + 0.01 * jax.random.normal(kn, (n,)))
+        A = jax.random.normal(ka, (n, d)) / jnp.sqrt(d)
+        if cond is not None:
+            u, s, vt = jnp.linalg.svd(A, full_matrices=False)
+            k = s.shape[0]
+            s_new = jnp.geomspace(1.0, 1.0 / jnp.sqrt(cond), k)
+            A = (u * s_new) @ vt
+        w_true = jax.random.normal(kw, (d,))
+        z = A @ w_true
+        noise = jax.random.normal(kn, (n,))
+    if loss == "squared":
+        y = z + 0.01 * noise
+    else:
+        y = jnp.sign(z + 0.01 * noise)
         y = jnp.where(y == 0, 1.0, y)
-    return ERMProblem(A=A, y=y, loss=lf, lam=lam)
+    return A, y, w_true
+
+
+def make_random_erm(n: int, d: int, loss: str = "squared", lam: float = 1e-2,
+                    seed: int = 0, cond: Optional[float] = None,
+                    mesh: Optional[Mesh] = None) -> ERMProblem:
+    """Synthetic ERM instance. If ``cond`` is set, shape A's spectrum to
+    roughly that condition number (for controlled kappa experiments).
+    With ``mesh``, A is built column-sharded over it
+    (``random_erm_data``)."""
+    A, y, _ = random_erm_data(n, d, loss=loss, seed=seed, cond=cond,
+                              mesh=mesh)
+    return ERMProblem(A=A, y=y, loss=LOSSES[loss](), lam=lam)

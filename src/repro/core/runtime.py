@@ -15,9 +15,10 @@ Backends:
   * ``LocalDistERM`` — m simulated machines; per-machine blocks stacked on a
     leading axis (m, ...). Reference semantics, used by tests/benchmarks.
   * ``ShardedDistERM`` — identical math with machine j = slice j of a mesh
-    axis; constructed *inside* a ``shard_map`` body. ``_run_sharded``
-    places column-sharded data on a real mesh and drives any algorithm
-    through it (front-ended by ``repro.api``'s sharded placement).
+    axis; constructed *inside* a ``shard_map`` body. ``ShardedProgram``
+    takes column-sharded data where it lies on a real mesh (or shards a
+    one-device A), compiles any algorithm through it once and runs it
+    (front-ended by ``repro.api``'s sharded placement).
 
 The two backends are required to produce bit-comparable iterates (up to
 reduction order), which ``tests/test_runtime_parity.py`` asserts.
@@ -53,11 +54,11 @@ the whole-round kernel engages, and iterates equal to f32 rounding).
 
 A third orthogonal axis is the **round engine** (``core.engine``): whether
 an algorithm's rounds run as a per-call Python loop (``"python"``) or as
-one ``lax.scan``-compiled XLA program (``"scan"``).  ``_run_sharded``
+one ``lax.scan``-compiled XLA program (``"scan"``).  ``ShardedProgram``
 accepts a step-form ``RoundProgram`` builder to compile the whole
-multi-round run inside the ``shard_map`` body; the ledger is expanded
-from the trace-once schedule to the same per-call stream the python loop
-produces.
+multi-round run, in-scan gap measure included, inside the ``shard_map``
+body; the ledger is expanded from the trace-once schedule to the same
+per-call stream the python loop produces.
 
 All three axes are front-ended by ``repro.api``: a ``RunSpec`` names
 placement/backend/engine declaratively, ``plan`` resolves the ``auto``
@@ -75,12 +76,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .comm import CommLedger, LocalCommunicator, ShardMapCommunicator
 from .erm import ERMProblem, GLMLoss
 from .partition import FeaturePartition
 from ..kernels import ops as kops
+from ..metrics.spans import span
 
 
 # --------------------------------------------------------------------------
@@ -243,14 +245,24 @@ class FusedBackend(KernelBackend):
     gradient epilogue folded into the contraction's last block — one
     A-read per oracle; this is what DISCO-F's CG hits every inner
     iteration, where the round's scalar reduces make a whole-round
-    rotation impossible).  Sharded placement inherits the kernel
-    oracles unchanged: inside ``shard_map`` the fused backend is the
-    kernel backend.  ``round_step``
+    rotation impossible), on the stacked blocks and inside
+    ``shard_map`` alike (a shard has no padded coordinates: its mask is
+    all ones).  ``round_step``
     builds the one-kernel-per-machine round of
     ``kernels.fused_round.make_round_step`` when the cell qualifies.
     """
 
     name = "fused"
+
+    def pgrad_shard(self, dist, w_loc, lgrad):
+        return kops.fused_pgrad(dist.A_loc, lgrad, w_loc,
+                                jnp.ones_like(w_loc), n=dist.n,
+                                lam=dist.lam)
+
+    def phvp_shard(self, dist, v_loc, h, av):
+        return kops.fused_phvp(dist.A_loc, h, av, v_loc,
+                               jnp.ones_like(v_loc), n=dist.n,
+                               lam=dist.lam)
 
     def pgrad_local(self, dist, w_stk, lgrad):
         return jax.vmap(
@@ -447,6 +459,15 @@ class ShardedDistERM:
         self._round_cache.clear()
         self.comm.end_round()
 
+    def objective(self, w_loc):
+        """f(w) from this machine's block, for the in-scan measure: its
+        two psums are measurement, not communication, so nothing is
+        metered (the local placement's measure reads the whole A)."""
+        z = lax.psum(self.A_loc @ w_loc, self.comm.axis)
+        sq = lax.psum(jnp.vdot(w_loc, w_loc), self.comm.axis)
+        return jnp.sum(self.loss.value(z, self.y)) / self.n \
+            + 0.5 * self.lam * sq
+
     # ---- incremental-family oracles --------------------------------------
     def sample_row(self, i: int):
         return self.A_loc[i, :]
@@ -479,85 +500,119 @@ def run_sharded(*args, **kwargs):
         "repro.core.runtime._run_sharded")
 
 
-def _run_sharded(prob: ERMProblem, algorithm_body: Optional[Callable],
-                 rounds: int,
-                 mesh: Optional[Mesh] = None, axis: str = "model",
-                 ledger: Optional[CommLedger] = None,
-                 backend: Optional[str] = None,
-                 engine: str = "python",
-                 program_builder: Optional[Callable] = None,
-                 channel=None, trace_only: bool = False,
-                 lower_only: bool = False):
-    """Run an algorithm under shard_map with the data matrix column-sharded
-    over ``axis``.  (Machinery behind ``repro.api``'s sharded placement;
-    the retired public ``run_sharded`` wrapper raises, naming this
-    driver and the ``RunSpec`` path.)
+def _mesh_and_data(prob: ERMProblem, mesh: Optional[Mesh], axis: str):
+    """(mesh, axis, A, pad): the mesh the run shards over and A laid out
+    column-sharded on it.  An A already column-sharded over a one-axis
+    mesh is taken as it lies (padded only where the mesh does not
+    divide d); any other A is padded as needed and sharded over
+    ``mesh``, by default every device."""
+    sharding = getattr(prob.A, "sharding", None)
+    if mesh is None and isinstance(sharding, NamedSharding) \
+            and len(sharding.mesh.axis_names) == 1 \
+            and tuple(sharding.spec) == (None, sharding.mesh.axis_names[0]):
+        mesh, axis = sharding.mesh, sharding.mesh.axis_names[0]
+    if mesh is None:
+        mesh = Mesh(np.array(jax.devices()), (axis,))
+    m = mesh.shape[axis]
+    pad = (-prob.d) % m
+    A = prob.A
+    if pad:
+        A = jax.jit(lambda A: jnp.pad(A, ((0, 0), (0, pad))),
+                    out_shardings=NamedSharding(mesh, P(None, axis)))(A)
+    return mesh, axis, A, pad
+
+
+class ShardedProgram:
+    """An algorithm run under ``shard_map`` with the data matrix
+    column-sharded over ``axis``, traced and compiled once.  (Machinery
+    behind ``repro.api``'s sharded placement; ``_run_sharded`` runs one
+    once.)
 
     Two driving modes, selected by ``engine``:
 
-    * ``"python"`` (default) — ``algorithm_body(dist, rounds) -> w_loc``
-      is traced as-is: the historical per-round Python loop unrolled into
-      the jitted body. Ledger counts are trace-time (ops per traced
-      call), i.e. the full per-round stream.
+    * ``"python"`` — ``algorithm_body(dist, rounds) -> w_loc`` is traced
+      as-is: the historical per-round Python loop unrolled into the
+      jitted body. Ledger counts are trace-time (ops per traced call),
+      i.e. the full per-round stream.
     * ``"scan"`` — ``program_builder(dist, rounds) -> RoundProgram``
       (step-form, see ``core.engine``) is compiled segment-by-segment
       with ``lax.scan`` inside the shard_map body, so the traced program
       is one scan per segment regardless of the round budget. Each
-      segment's step traces ONCE; afterwards the ledger is expanded from
-      the captured per-step schedule to the identical per-round stream
-      the python mode records.
+      segment's step traces ONCE; the ledger is expanded from the
+      captured per-step schedule to the identical per-round stream the
+      python mode records.  ``measure(dist, w_loc) -> scalar`` (scan
+      only) is evaluated after every round under the ``repro.gap``
+      scope, as the local engine's in-scan measure is, and the run
+      returns its (K,) series.
 
     ``backend`` picks the oracle compute path (see
-    ``resolve_oracle_backend``). Returns the assembled global w (d,) and
-    the per-round ledger.
+    ``resolve_oracle_backend``).  Calling the program runs it and meters
+    the per-round stream into a ledger; its rounds are numbered from 0.
     """
-    from .engine import resolve_engine
 
-    engine = resolve_engine(engine)
-    if engine == "scan" and program_builder is None:
-        raise ValueError("engine='scan' requires a program_builder "
-                         "(step-form RoundProgram factory)")
-    if engine == "python" and algorithm_body is None:
-        raise ValueError("engine='python' requires an algorithm_body")
+    def __init__(self, prob: ERMProblem, rounds: int, *,
+                 algorithm_body: Optional[Callable] = None,
+                 program_builder: Optional[Callable] = None,
+                 mesh: Optional[Mesh] = None, axis: str = "model",
+                 backend: Optional[str] = None, engine: str = "python",
+                 channel=None, measure: Optional[Callable] = None):
+        from .channel import parse_channel
+        from .engine import resolve_engine
 
-    if mesh is None:
-        devs = np.array(jax.devices())
-        mesh = Mesh(devs, (axis,))
-    m = mesh.shape[axis]
-    d = prob.d
-    if d % m:
-        pad = m - d % m
-        A = jnp.pad(prob.A, ((0, 0), (0, pad)))
-    else:
-        pad = 0
-        A = prob.A
-    led = ledger if ledger is not None else CommLedger()
-    backend = resolve_oracle_backend(backend)
-    from .channel import parse_channel
-    chan = parse_channel(channel)
-    scheduled = getattr(chan, "scheduled", False)
-    pre_records, pre_rounds = len(led.records), led.rounds
-    spans = []   # (start, end, rounds_traced, count) per scanned segment
-    # run-time global round base of the NEXT scanned segment (python int:
-    # each segment's rounds-per-step is concrete at trace time)
-    run_base = [pre_rounds]
+        engine = resolve_engine(engine)
+        if engine == "scan" and program_builder is None:
+            raise ValueError("engine='scan' requires a program_builder "
+                             "(step-form RoundProgram factory)")
+        if engine == "python" and algorithm_body is None:
+            raise ValueError("engine='python' requires an algorithm_body")
+        if measure is not None and engine != "scan":
+            raise ValueError("an in-run measure needs engine='scan'")
+        self.prob, self.rounds = prob, rounds
+        self.algorithm_body, self.program_builder = (algorithm_body,
+                                                     program_builder)
+        self.engine, self.measure = engine, measure
+        self.mesh, self.axis, self.A, self.pad = _mesh_and_data(prob, mesh,
+                                                                axis)
+        self.backend = resolve_oracle_backend(backend)
+        self.chan = parse_channel(channel)
+        self.scheduled = getattr(self.chan, "scheduled", False)
+        # pallas_call has no shard_map varying-axes rule, and lax.scan
+        # carries mixing replicated (z, scalars) with sharded (w-block)
+        # values defeat the varying-axes typer; both paths opt out of the
+        # (purely diagnostic) check.
+        self._check_vma = (self.backend not in ("kernel", "fused")
+                           and engine != "scan")
+        self.ledger = CommLedger()      # the trace-time records
+        self.spans = []     # (start, end, rounds_traced, count) per segment
+        self._compiled = None
 
-    def body(A_loc, y):
-        dist = ShardedDistERM(A_loc, y, prob.loss, prob.lam, prob.n,
-                              axis=axis, ledger=led, backend=backend,
-                              channel=chan)
-        if engine == "python":
-            return algorithm_body(dist, rounds)
-        program = program_builder(dist, rounds)
-        carry = program.init
+    def _body(self, A_loc, y):
+        led = self.ledger
+        dist = ShardedDistERM(A_loc, y, self.prob.loss, self.prob.lam,
+                              self.prob.n, axis=self.axis, ledger=led,
+                              backend=self.backend, channel=self.chan)
+        if self.engine == "python":
+            return self.algorithm_body(dist, self.rounds)
+        from .engine import GAP_SCOPE
+        program = self.program_builder(dist, self.rounds)
+        measure = self.measure
+        carry, gaps = program.init, []
+        # run-time global round base of the next scanned segment (python
+        # int: each segment's rounds-per-step is concrete at trace time)
+        run_base = 0
         for seg in program.segments:
             xs = (jnp.asarray(seg.xs) if seg.xs is not None
                   else jnp.arange(seg.count, dtype=jnp.int32))
             start, r0 = len(led.records), led.rounds
 
+            def measured(c, w):
+                if measure is None:
+                    return c, None
+                with jax.named_scope(GAP_SCOPE):
+                    return c, measure(dist, w)
+
             def scan_body(c, x, _step=seg.step):
-                c, _ = _step(dist, c, x)
-                return c, None
+                return measured(*_step(dist, c, x))
 
             def sched_body(cr, x, _step=seg.step):
                 # scheduled channel: thread the global round index as a
@@ -567,57 +622,77 @@ def _run_sharded(prob: ERMProblem, algorithm_body: Optional[Callable],
                 c, rk = cr
                 dist.comm.begin_round(rk)
                 r_in = led.rounds
-                c, _ = _step(dist, c, x)
+                c, w = _step(dist, c, x)
                 dist.comm.reset_round()
-                return (c, rk + (led.rounds - r_in)), None
+                c, out = measured(c, w)
+                return (c, rk + (led.rounds - r_in)), out
 
-            if scheduled:
-                (carry, _), _ = lax.scan(
-                    sched_body, (carry, jnp.int32(run_base[0])), xs)
+            if self.scheduled:
+                (carry, _), out = lax.scan(
+                    sched_body, (carry, jnp.int32(run_base)), xs)
             else:
-                carry, _ = lax.scan(scan_body, carry, xs)
+                carry, out = lax.scan(scan_body, carry, xs)
+            gaps.append(out)
             r_traced = led.rounds - r0
-            run_base[0] += r_traced * seg.count
-            spans.append((start, len(led.records), r_traced, seg.count))
-        return program.final(carry)
+            run_base += r_traced * seg.count
+            self.spans.append((start, len(led.records), r_traced,
+                               seg.count))
+        w = program.final(carry)
+        return w if measure is None else (w, jnp.concatenate(gaps))
 
-    # pallas_call has no shard_map varying-axes rule, and lax.scan carries
-    # mixing replicated (z, scalars) with sharded (w-block) values defeat
-    # the varying-axes typer; both paths opt out of the (purely
-    # diagnostic) check.
-    fn = jax.shard_map(body, mesh=mesh,
-                       in_specs=(P(None, axis), P(None)),
-                       out_specs=P(axis),
-                       check_vma=(backend not in ("kernel", "fused")
-                                  and engine != "scan"))
-    if trace_only:
-        # repro.analysis hook: trace the sharded program without running
-        # it and hand back the jaxpr, the raw trace-time ledger (records
-        # metered once per scanned segment, NOT expanded), and the spans
-        # the expansion below would have consumed — the static verifier
-        # performs its own expansion and proves it equal to the ledger
-        # this function produces when actually run.
-        closed = jax.make_jaxpr(fn)(A, prob.y)
-        return closed, led, spans
-    if lower_only:
-        # HLO audit hook: the lowered (compilable, unexecuted) sharded
-        # computation, for collective_bytes_from_hlo cross-checks of the
-        # collectives XLA actually emits against the metered ledger.
-        return jax.jit(fn).lower(A, prob.y), led, spans
-    w = jax.jit(fn)(A, prob.y)
-    if spans:
-        # Expand the trace-once schedule: each segment's single traced
-        # step stream repeats `count` times, reproducing the per-round
-        # stream — round-boundary marks included — the python mode
-        # records bit-identically.  Marks are record positions into the
-        # trace-time stream; each region's marks are rebased onto the
-        # expanded stream as the region is copied.
-        records, marks = led.records, led.round_marks
-        expanded = list(records[:pre_records])
-        new_marks = [m for m in marks if m <= pre_records]
-        rounds_total = pre_rounds
-        prev_end = pre_records
-        for start, end, r_traced, count in spans:
+    def _fresh(self) -> Callable:
+        """A new ``shard_map`` function over ``_body``, with a fresh
+        trace-time ledger and spans: JAX serves a function it has traced
+        before from its cache, and would meter nothing."""
+        self.ledger, self.spans = CommLedger(), []
+        out_specs = (P(self.axis) if self.measure is None
+                     else (P(self.axis), P()))
+        return jax.shard_map(
+            lambda A_loc, y: self._body(A_loc, y), mesh=self.mesh,
+            in_specs=(P(None, self.axis), P(None)), out_specs=out_specs,
+            check_vma=self._check_vma)
+
+    def trace(self):
+        """The sharded program's jaxpr, without running it; the
+        trace-time ledger and spans are ``self.ledger`` and
+        ``self.spans`` (records metered once per scanned segment, NOT
+        expanded)."""
+        return jax.make_jaxpr(self._fresh())(self.A, self.prob.y)
+
+    def lower(self):
+        """The lowered (compilable, unexecuted) sharded computation,
+        with its trace-time ledger and spans as ``trace`` leaves them."""
+        return jax.jit(self._fresh()).lower(self.A, self.prob.y)
+
+    def __call__(self, ledger: Optional[CommLedger] = None):
+        """Run the compiled program (tracing and compiling it on the
+        first call); meter its per-round stream into ``ledger``.
+        Returns (w, gaps, ledger): the assembled global w (d,) and the
+        measure's (K,) series, or None without a measure."""
+        with span("repro.runner"):
+            if self._compiled is None:
+                self._compiled = self.lower().compile()
+        with span("repro.run"):
+            out = jax.block_until_ready(self._compiled(self.A, self.prob.y))
+        w, gaps = (out, None) if self.measure is None else out
+        led = ledger if ledger is not None else CommLedger()
+        with span("repro.ledger_replay"):
+            self._meter(led)
+        return (w[:self.prob.d] if self.pad else w), gaps, led
+
+    def _meter(self, led: CommLedger) -> None:
+        """Append the run's per-round stream to ``led``.  Scanned
+        segments expand their trace-once schedule: each segment's single
+        traced step stream repeats ``count`` times, reproducing the
+        per-round stream — round-boundary marks included — the python
+        mode records bit-identically.  Marks are record positions into
+        the trace-time stream; each region's marks are rebased onto the
+        expanded stream as the region is copied."""
+        records, marks = self.ledger.records, self.ledger.round_marks
+        expanded = []
+        new_marks = [m for m in marks if m == 0]
+        rnd, prev_end = 0, 0
+        for start, end, r_traced, count in self.spans:
             # records (and any marks) traced outside the scans, if ever
             new_marks.extend(len(expanded) + (m - prev_end)
                              for m in marks if prev_end < m <= start)
@@ -626,22 +701,42 @@ def _run_sharded(prob: ERMProblem, algorithm_body: Optional[Callable],
             span_marks = [m - start for m in marks if start < m <= end]
             for _ in range(count):
                 base = len(expanded)
-                if scheduled:
+                if self.scheduled:
                     # trace-time prices are provisional (the round index
                     # was a tracer): re-price each repeat from its
                     # global round base, as the scan-engine replay does.
                     from .comm import repriced_records
                     expanded.extend(repriced_records(
-                        span_records, span_marks, rounds_total, chan))
+                        span_records, span_marks, rnd, self.chan))
                 else:
                     expanded.extend(span_records)
                 new_marks.extend(base + m for m in span_marks)
-                rounds_total += r_traced
+                rnd += r_traced
             prev_end = end
         new_marks.extend(len(expanded) + (m - prev_end)
                          for m in marks if m > prev_end)
         expanded.extend(records[prev_end:])
-        led.records[:] = expanded
-        led.round_marks[:] = new_marks
-        led.rounds = rounds_total
-    return (w[:d] if pad else w), led
+        base = len(led.records)
+        led.records.extend(expanded)
+        led.round_marks.extend(base + m for m in new_marks)
+        led.rounds += self.ledger.rounds + sum(
+            r_traced * (count - 1) for _, _, r_traced, count in self.spans)
+
+
+def _run_sharded(prob: ERMProblem, algorithm_body: Optional[Callable],
+                 rounds: int,
+                 mesh: Optional[Mesh] = None, axis: str = "model",
+                 ledger: Optional[CommLedger] = None,
+                 backend: Optional[str] = None,
+                 engine: str = "python",
+                 program_builder: Optional[Callable] = None,
+                 channel=None):
+    """Run an algorithm once under shard_map (``ShardedProgram``).
+    Returns the assembled global w (d,) and the per-round ledger
+    (``ledger``, or a fresh one)."""
+    program = ShardedProgram(prob, rounds, algorithm_body=algorithm_body,
+                             program_builder=program_builder, mesh=mesh,
+                             axis=axis, backend=backend, engine=engine,
+                             channel=channel)
+    w, _, led = program(ledger)
+    return w, led

@@ -16,16 +16,20 @@ legitimate — the overlay is reported as context, not as a certificate.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import inspect
 import math
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import Mesh
 
 from repro.core import (ChainInstance, ERMProblem, make_random_erm,
                         squared_loss)
+from repro.core.erm import gram, spans_devices
 from repro.core.algorithms import soft_threshold
 from repro.core.engine import hoisted_jit
 from repro.core.partition import FeaturePartition, even_partition
@@ -70,20 +74,43 @@ class InstanceBundle:
 
 def _make_context(prob: ERMProblem, part: FeaturePartition, L: float,
                   prox: Optional[Callable] = None) -> AlgoContext:
-    """Derive every constant the registered adapters may ask for, given
-    ``L = _smoothness(prob)`` (each builder computes it once)."""
+    """Every constant the registered adapters may ask for, given ``L =
+    _smoothness(prob)`` (each builder computes it once).  The per-block
+    bounds and the per-component bound are computed when an adapter
+    first reads them (only ``bcd`` and ``dsvrg`` do)."""
+    return AlgoContext(L=L, lam=prob.lam, m=part.m, n=prob.n, d=prob.d,
+                       loss_name=prob.loss.name, prox=prox,
+                       L_max_fn=functools.partial(_component_L_max, prob),
+                       block_L_fn=functools.partial(_block_L, prob, part))
+
+
+def _component_L_max(prob: ERMProblem) -> float:
+    """max_i ell''_max |a_i|^2 + lam, the incremental family's bound."""
     with span("repro.instance.block_norms"):
         sm = prob.loss.smoothness
+        if spans_devices(prob.A):
+            # each device sums its columns' squares; one R^n reduce
+            rows = jax.jit(lambda A: jnp.max(jnp.sum(A ** 2, axis=1)))
+            return float(rows(prob.A)) * sm + prob.lam
         A = np.asarray(prob.A)
-        block_L = np.array(
-            [sm * np.linalg.norm(A[:, off:off + b], 2) ** 2 / prob.n
-             + prob.lam
-             for off, b in zip(part.offsets, part.block_sizes)]
-        ).reshape(-1, 1)
-        L_max = float(np.max(np.sum(A ** 2, axis=1)) * sm + prob.lam)
-    return AlgoContext(L=L, lam=prob.lam, L_max=L_max, block_L=block_L,
-                       m=part.m, n=prob.n, d=prob.d,
-                       loss_name=prob.loss.name, prox=prox)
+        return float(np.max(np.sum(A ** 2, axis=1)) * sm + prob.lam)
+
+
+def _block_L(prob: ERMProblem, part: FeaturePartition) -> np.ndarray:
+    """(m, 1) per-block bounds ell''_max sigma_max(A_j)^2 / n + lam."""
+    with span("repro.instance.block_norms"):
+        sm = prob.loss.smoothness
+        blocks = zip(part.offsets, part.block_sizes)
+        if spans_devices(prob.A):
+            # sigma_max(A_j)^2 is the top eigenvalue of A^T A's block jj
+            G = gram(prob.A)
+            sq = [np.linalg.eigvalsh(G[o:o + b, o:o + b])[-1]
+                  for o, b in blocks]
+        else:
+            A = np.asarray(prob.A)
+            sq = [np.linalg.norm(A[:, o:o + b], 2) ** 2 for o, b in blocks]
+        return np.array([sm * s / prob.n + prob.lam
+                         for s in sq]).reshape(-1, 1)
 
 
 def _ready(prob: ERMProblem) -> ERMProblem:
@@ -272,12 +299,16 @@ def build_lasso(n: int = 128, d: int = 256, m: int = 4, tau: float = 2e-3,
 
 
 def build_logistic(n: int = 256, d: int = 96, m: int = 4, lam: float = 1e-2,
-                   seed: int = 0, ref_iters: int = 20000) -> InstanceBundle:
+                   seed: int = 0, ref_iters: int = 20000,
+                   mesh: Optional[Mesh] = None) -> InstanceBundle:
     """Ridge-regularized logistic regression on synthetic separable-ish
-    data — the paper's motivating GLM workload."""
+    data — the paper's motivating GLM workload.  With ``mesh`` (the
+    machines' devices on one axis) A is built column-sharded over it,
+    each machine's columns on its own device, and every constant and the
+    reference solve are computed where the blocks lie."""
     with span("repro.instance.data"):
         prob = _ready(make_random_erm(n=n, d=d, loss="logistic", lam=lam,
-                                      seed=seed))
+                                      seed=seed, mesh=mesh))
     part = even_partition(d, m)
     L = _smoothness(prob)
     wref = _reference_solution(prob, ref_iters, L)
@@ -316,12 +347,52 @@ INSTANCE_BUILDERS: Dict[str, Callable[..., InstanceBundle]] = {
 }
 
 
-def build_instance(kind: str, **params) -> InstanceBundle:
+# (n, d, m) of each builder's instance, from the builder's parameters
+# (defaults applied): the shape a placement is chosen from before
+# anything is built.  The chains are square: d x d (n x n for thm4).
+INSTANCE_SHAPES: Dict[str, Callable[..., Tuple[int, int, int]]] = {
+    "thm2_chain": lambda d, m, **_: (d, d, m),
+    "thm3_chain": lambda d, m, **_: (d, d, m),
+    "thm4_separable": lambda n, m, **_: (n, n, m),
+    "lasso": lambda n, d, m, **_: (n, d, m),
+    "logistic": lambda n, d, m, **_: (n, d, m),
+    "random_ridge": lambda n, d, m, **_: (n, d, m),
+}
+
+
+def instance_shape(kind: str, params: dict
+                   ) -> Optional[Tuple[int, int, int]]:
+    """(n, d, m) of the instance ``kind`` builds from ``params``; None
+    for an unknown kind or parameters its builder does not take (plan
+    validation names those)."""
+    if kind not in INSTANCE_BUILDERS:
+        return None
+    try:
+        bound = inspect.signature(INSTANCE_BUILDERS[kind]).bind(**params)
+    except TypeError:
+        return None
+    bound.apply_defaults()
+    return tuple(int(x) for x in INSTANCE_SHAPES[kind](**bound.arguments))
+
+
+def build_instance(kind: str, mesh: Optional[Mesh] = None,
+                   **params) -> InstanceBundle:
+    """Build instance ``kind`` from ``params``.  ``mesh`` places the
+    data column-sharded over the machines' devices, for the builders
+    that take one (``builds_sharded``); it is a placement, not a
+    parameter of the problem, so ``build_params`` leaves it out."""
     try:
         builder = INSTANCE_BUILDERS[kind]
     except KeyError:
         raise KeyError(f"unknown instance kind {kind!r}; known: "
                        f"{sorted(INSTANCE_BUILDERS)}") from None
+    kwargs = params if mesh is None else dict(params, mesh=mesh)
     with span("repro.instance_build", kind=kind):
-        bundle = builder(**params)
+        bundle = builder(**kwargs)
     return dataclasses.replace(bundle, build_params=dict(params))
+
+
+def builds_sharded(kind: str) -> bool:
+    """Whether the builder of ``kind`` can build its data column-sharded
+    over a mesh."""
+    return "mesh" in inspect.signature(INSTANCE_BUILDERS[kind]).parameters

@@ -28,6 +28,7 @@ correct theorem bound.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
@@ -45,17 +46,29 @@ FAMILY_I = "I^{lam,L}"
 class AlgoContext:
     """Everything an adapter may need to instantiate an algorithm on a
     concrete (problem, partition) pair. Built once per instance by
-    ``instances.build_instance``."""
+    ``instances.build_instance``.  ``L_max`` and ``block_L`` are each
+    computed on first read, from the functions the builder gives: only
+    ``dsvrg`` and ``bcd`` read them."""
 
     L: float                      # global smoothness bound of f
     lam: float                    # ridge / strong-convexity modulus
-    L_max: float                  # max per-component smoothness (Thm 4)
-    block_L: np.ndarray           # (m, 1) per-block Lipschitz bounds (BCD)
     m: int
     n: int
     d: int
     loss_name: str
     prox: Optional[Callable] = None   # separable prox for composite runs
+    L_max_fn: Optional[Callable[[], float]] = None
+    block_L_fn: Optional[Callable[[], np.ndarray]] = None
+
+    @functools.cached_property
+    def L_max(self) -> float:
+        """Max per-component smoothness (Thm 4)."""
+        return self.L_max_fn()
+
+    @functools.cached_property
+    def block_L(self) -> np.ndarray:
+        """(m, 1) per-block Lipschitz bounds (BCD)."""
+        return self.block_L_fn()
 
 
 def _identity_prox(w, step):

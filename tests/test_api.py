@@ -95,7 +95,7 @@ def test_core_resolvers_delegate_to_api():
     (dict(TINY, rounds=0), "rounds"),
     (dict(TINY, algorithm="bcd", placement="sharded", eps=(),
           measure="none"), "machine-stacked"),
-    (dict(TINY, placement="sharded"), "gap measurement"),
+    (dict(TINY, placement="sharded", engine="python"), "gap measurement"),
     (dict(TINY, algo_kwargs=dict(zz=1)), "hyper-parameter"),
     (dict(TINY, algo_kwargs=dict(rounds=5)), "hyper-parameter"),
     (dict(TINY, backend="blas"), "oracle backend"),

@@ -68,7 +68,7 @@ import jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import CommLedger, make_random_erm
 from repro.core.comm import collective_bytes_from_lowered
-from repro.core.runtime import _run_sharded
+from repro.core.runtime import ShardedProgram
 from repro.core.algorithms import PROGRAMS
 
 out = {}
@@ -86,11 +86,12 @@ out["toy"] = {"counts": audit.count_by_op, "bytes": audit.bytes_by_op}
 # module must carry every collective the trace-once ledger metered
 prob = make_random_erm(n=16, d=8, loss="squared", lam=0.05, seed=1)
 L = prob.smoothness_bound()
-lowered, led, spans = _run_sharded(
-    prob, None, rounds=5, ledger=CommLedger(), engine="scan",
+program = ShardedProgram(
+    prob, 5, engine="scan",
     program_builder=lambda d_, r: PROGRAMS["dgd"](d_, r, L=L,
                                                   lam=prob.lam),
-    channel="identity", lower_only=True)
+    channel="identity")
+lowered, led = program.lower(), program.ledger
 audit = collective_bytes_from_lowered(lowered)
 out["dgd"] = {
     "counts": audit.count_by_op,
@@ -105,7 +106,7 @@ print(json.dumps(out))
 def test_audit_on_real_module():
     """The parser finds the collectives of real lowered modules: a toy
     shard_map all_gather with a known payload, and the sharded driver's
-    ``lower_only`` product, whose compiled HLO must carry at least the
+    lowered ``ShardedProgram``, whose compiled HLO must carry at least the
     collective traffic the trace-once ledger metered."""
     import json
     import os
