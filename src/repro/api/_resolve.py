@@ -7,7 +7,7 @@ Every run in the repo is positioned on five orthogonal axes:
     = mesh slice j inside ``shard_map``);
   * **oracle backend** — how the per-machine work inside
     ``response``/``pgrad``/``phvp`` is computed: ``einsum`` (plain jnp
-    contractions), ``kernel`` (the MXU-tiled Pallas kernels) or
+    contractions), ``kernel`` (the Pallas GEMV kernels) or
     ``fused`` (the kernels plus the whole-round fused step of
     ``kernels/fused_round.py`` where a cell supports it);
   * **round engine** — how rounds are driven: ``python`` (per-call loop)

@@ -30,7 +30,7 @@ run by ``repro.api._resolve``, never re-dispatched per call):
 
   * ``"einsum"`` — plain ``jnp`` contractions (XLA decides the schedule);
     the CPU default and the reference semantics.
-  * ``"kernel"`` — the MXU-tiled Pallas kernels in ``repro.kernels``
+  * ``"kernel"`` — the Pallas GEMV kernels in ``repro.kernels``
     (``feature_matvec``/``feature_rmatvec``/``feature_hvp``), ``vmap``-ed
     over the stacked machine axis in local mode and applied directly to
     the local shard inside ``shard_map``.
@@ -208,7 +208,8 @@ class EinsumBackend(OracleBackend):
 
 
 class KernelBackend(OracleBackend):
-    """The MXU-tiled Pallas GEMV kernels, composed with jnp epilogues."""
+    """The Pallas GEMV kernels (a VPU body at one right-hand side, MXU
+    tiles at several), composed with jnp epilogues."""
 
     name = "kernel"
 

@@ -31,8 +31,9 @@ kernels here collapse all of that:
   ``feature_rmatvec``/``feature_hvp`` with the gradient epilogue
   (``/n + lam v``, block mask) folded into the last contraction block —
   one A-read per oracle instead of an extra d-vector HBM round-trip.
-  Like those, they read A_j in place: a row block that overhangs the
-  array is zeroed past n in VMEM, and only the vectors are padded.
+  Like those, they read A_j in place, and take the VPU body at one
+  right-hand side and the MXU body at several (``feature_matvec``'s
+  module docstring).
 
 Conformance contract: wherever ``round_step_fits`` and
 ``channel_stages`` admit a cell, the fused step's ledger stream and
@@ -59,7 +60,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .feature_matvec import (BLOCK_B, BLOCK_D, BLOCK_N, _acc_dtype,
-                             _dot, _interp, _pad2, _rup, _with_a_tile)
+                             _dot, _f32, _gemv_lanes, _interp, _pad2, _rup,
+                             _Tiles, _with_a_tile)
 from ..core.channel import Channel, ScheduledChannel
 
 # The whole-round kernel keeps machine j's entire padded A_j block in
@@ -270,7 +272,8 @@ def make_round_step(A_stk, mask, y_data, loss, *, n: int, lam: float,
 # Epilogue-fused composed oracles (the fallback / DISCO-F CG variant)
 # --------------------------------------------------------------------------
 
-def _pgrad_kernel(a_ref, r_ref, w_ref, mk_ref, o_ref, *, n, lam, extent):
+def _pgrad_kernel(a_ref, r_ref, w_ref, mk_ref, o_ref, *, n, lam, extent,
+                  gemv):
     """Grid (d_blocks, b_blocks, n_blocks): o[j,b] += A[i,j]^T @ r[i,b]
     with the gradient epilogue (o/n + lam w) * mask folded into the last
     contraction block, so the partial gradient never round-trips HBM
@@ -281,10 +284,14 @@ def _pgrad_kernel(a_ref, r_ref, w_ref, mk_ref, o_ref, *, n, lam, extent):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    def acc(a):
-        o_ref[...] += _dot(a.T, r_ref[...], o_ref.dtype)
+    if gemv:
+        _gemv_lanes(a_ref, lambda s: _f32(r_ref[:, s]), o_ref,
+                    extent=extent)
+    else:
+        def acc(a):
+            o_ref[...] += _dot(a.T, r_ref[...], o_ref.dtype)
 
-    _with_a_tile(a_ref, acc, extent=extent, dim=0)
+        _with_a_tile(a_ref, acc, extent=extent, dim=0)
 
     @pl.when(i == pl.num_programs(2) - 1)
     def _epilogue():
@@ -292,7 +299,7 @@ def _pgrad_kernel(a_ref, r_ref, w_ref, mk_ref, o_ref, *, n, lam, extent):
 
 
 def fused_pgrad(A_j, r, w_j, mask_j, *, n: int, lam: float,
-                block_n: int = BLOCK_N, block_d: int = BLOCK_D,
+                block_n: int | None = None, block_d: int = BLOCK_D,
                 block_b: int = BLOCK_B, interpret: bool | None = None):
     """g_j = (A_j^T r / n + lam w_j) * mask_j in one accumulation pass.
 
@@ -306,33 +313,20 @@ def fused_pgrad(A_j, r, w_j, mask_j, *, n: int, lam: float,
         w_j = w_j[:, None]
     n_rows, dj = A_j.shape
     b = r.shape[1]
-    bn, bd = min(block_n, n_rows), min(block_d, dj)
-    bb = min(block_b, _rup(b))
-    r_p = _pad2(r, bn, bb)
-    w_p = _pad2(w_j.astype(A_j.dtype), bd, bb)
-    mk_p = _pad2(mask_j[:, None].astype(A_j.dtype), bd, 1)
-    grid = (pl.cdiv(dj, bd), r_p.shape[1] // bb, pl.cdiv(n_rows, bn))
-    out = pl.pallas_call(
-        functools.partial(_pgrad_kernel, n=n, lam=lam, extent=n_rows),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bn, bd), lambda j, k, i: (i, j)),
-            pl.BlockSpec((bn, bb), lambda j, k, i: (i, k)),
-            pl.BlockSpec((bd, bb), lambda j, k, i: (j, k)),
-            pl.BlockSpec((bd, 1), lambda j, k, i: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((bd, bb), lambda j, k, i: (j, k)),
-        out_shape=jax.ShapeDtypeStruct((w_p.shape[0], r_p.shape[1]),
-                                       _acc_dtype(A_j.dtype)),
-        interpret=_interp(interpret),
-        name="fused_pgrad",
-    )(A_j, r_p, w_p, mk_p)
+    t = _Tiles(A_j, b, "n", block_n, block_d, block_b)
+    out = t.call(
+        functools.partial(_pgrad_kernel, n=n, lam=lam, extent=n_rows,
+                          gemv=t.gemv),
+        [t.a(A_j), t.nvec(r), t.dvec(w_j.astype(A_j.dtype)),
+         t.dvec(mask_j[:, None].astype(A_j.dtype), cols=1)],
+        t.dvec_spec(), t.dvec_shape(_acc_dtype(A_j.dtype)), "fused_pgrad",
+        interpret)
     out = out[:dj, :b].astype(A_j.dtype)
     return out[:, 0] if squeeze else out
 
 
 def _phvp_kernel(a_ref, h_ref, r_ref, v_ref, mk_ref, o_ref, *, n, lam,
-                 extent):
+                 extent, gemv):
     """Grid (d_blocks, b_blocks, n_blocks): o[j,b] += A[i,j]^T (h[i] ⊙
     r[i,b]) with the HVP epilogue (o/n + lam v) * mask folded into the
     last contraction block — DISCO-F's CG applies this every inner
@@ -343,10 +337,14 @@ def _phvp_kernel(a_ref, h_ref, r_ref, v_ref, mk_ref, o_ref, *, n, lam,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    def acc(a):
-        o_ref[...] += _dot(a.T, h_ref[...] * r_ref[...], o_ref.dtype)
+    if gemv:
+        _gemv_lanes(a_ref, lambda s: _f32(h_ref[:, s]) * _f32(r_ref[:, s]),
+                    o_ref, extent=extent)
+    else:
+        def acc(a):
+            o_ref[...] += _dot(a.T, h_ref[...] * r_ref[...], o_ref.dtype)
 
-    _with_a_tile(a_ref, acc, extent=extent, dim=0)
+        _with_a_tile(a_ref, acc, extent=extent, dim=0)
 
     @pl.when(i == pl.num_programs(2) - 1)
     def _epilogue():
@@ -354,7 +352,7 @@ def _phvp_kernel(a_ref, h_ref, r_ref, v_ref, mk_ref, o_ref, *, n, lam,
 
 
 def fused_phvp(A_j, h, av, v_j, mask_j, *, n: int, lam: float,
-               block_n: int = BLOCK_N, block_d: int = BLOCK_D,
+               block_n: int | None = None, block_d: int = BLOCK_D,
                block_b: int = BLOCK_B, interpret: bool | None = None):
     """u_j = (A_j^T (h ⊙ av) / n + lam v_j) * mask_j in one fused pass.
 
@@ -367,28 +365,14 @@ def fused_phvp(A_j, h, av, v_j, mask_j, *, n: int, lam: float,
         v_j = v_j[:, None]
     n_rows, dj = A_j.shape
     b = av.shape[1]
-    bn, bd = min(block_n, n_rows), min(block_d, dj)
-    bb = min(block_b, _rup(b))
-    h_p = _pad2(h[:, None], bn, 1)
-    r_p = _pad2(av, bn, bb)
-    v_p = _pad2(v_j.astype(A_j.dtype), bd, bb)
-    mk_p = _pad2(mask_j[:, None].astype(A_j.dtype), bd, 1)
-    grid = (pl.cdiv(dj, bd), r_p.shape[1] // bb, pl.cdiv(n_rows, bn))
-    out = pl.pallas_call(
-        functools.partial(_phvp_kernel, n=n, lam=lam, extent=n_rows),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bn, bd), lambda j, k, i: (i, j)),
-            pl.BlockSpec((bn, 1), lambda j, k, i: (i, 0)),
-            pl.BlockSpec((bn, bb), lambda j, k, i: (i, k)),
-            pl.BlockSpec((bd, bb), lambda j, k, i: (j, k)),
-            pl.BlockSpec((bd, 1), lambda j, k, i: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((bd, bb), lambda j, k, i: (j, k)),
-        out_shape=jax.ShapeDtypeStruct((v_p.shape[0], r_p.shape[1]),
-                                       _acc_dtype(A_j.dtype)),
-        interpret=_interp(interpret),
-        name="fused_phvp",
-    )(A_j, h_p.astype(A_j.dtype), r_p, v_p, mk_p)
+    t = _Tiles(A_j, b, "n", block_n, block_d, block_b)
+    out = t.call(
+        functools.partial(_phvp_kernel, n=n, lam=lam, extent=n_rows,
+                          gemv=t.gemv),
+        [t.a(A_j), t.nvec(h[:, None].astype(A_j.dtype), cols=1),
+         t.nvec(av), t.dvec(v_j.astype(A_j.dtype)),
+         t.dvec(mask_j[:, None].astype(A_j.dtype), cols=1)],
+        t.dvec_spec(), t.dvec_shape(_acc_dtype(A_j.dtype)), "fused_phvp",
+        interpret)
     out = out[:dj, :b].astype(A_j.dtype)
     return out[:, 0] if squeeze else out

@@ -123,79 +123,194 @@ def _eqns(jaxpr):
                     yield from _eqns(sub)
 
 
-def test_epsilon_block_is_read_without_a_pad():
+# The B = 1 body's A_j^T tile at the epsilon block: all 500 rows of
+# A_j^T (rounded up to 504, whole 8-row tiles) by the 128-lane columns of
+# n that fit 8 MiB, and the MXU body's A_j tile: 512 rows by all 500
+# columns.
+EPS_N, EPS_DJ = 400_000, 500
+GEMV_EPS_TILE, GEMV_EPS_STEPS = [504, 4096], 98
+
+
+@pytest.mark.parametrize("b", [1, 3])
+def test_epsilon_block_is_read_without_a_pad(b):
     """At the epsilon block (400,000 x 500, not a multiple of the 512
-    grid) no pad as large as A_j is traced: A is read in place, and only
-    the vectors are padded to the grid."""
-    n, dj = 400_000, 500
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
-    traced = {
-        "feature_matvec": jax.make_jaxpr(
-            functools.partial(feature_matvec, interpret=False))(
-                f32(n, dj), f32(dj)),
-        "fused_pgrad": jax.make_jaxpr(
-            functools.partial(fused_round.fused_pgrad, n=n, lam=1e-5,
-                              interpret=False))(
-                f32(n, dj), f32(n), f32(dj), f32(dj)),
-    }
-    for name, jaxpr in traced.items():
+    grid) no pad as large as A_j is traced: A is read in place.  The MXU
+    body (B = 3) pads only the vectors to its grid; the B = 1 body pads
+    nothing, and no R^n vector takes a 128-lane panel."""
+    n, dj = EPS_N, EPS_DJ
+    for name in ("feature_matvec", "fused_pgrad"):
+        jaxpr = _composed_jaxpr(name, n, dj, b)
         pads = [eqn.outvars[0].aval.shape for eqn in _eqns(jaxpr.jaxpr)
                 if eqn.primitive.name == "pad"]
-        assert pads, name          # the vectors are still padded
-        assert all(np.prod(s) < n * dj for s in pads), (name, pads)
+        if b > 1:
+            assert pads, name          # the vectors are still padded
+            assert all(np.prod(s) < n * dj for s in pads), (name, pads)
+            continue
+        assert not pads, (name, pads)
+        panels = [v.aval.shape for eqn in _eqns(jaxpr.jaxpr)
+                  for v in eqn.outvars
+                  if len(v.aval.shape) == 2 and max(v.aval.shape) >= n
+                  and min(v.aval.shape) > 1]
+        assert panels == [(dj, n)], (name, panels)   # A_j^T itself
 
 
-def _composed_call(name, n, dj):
-    """The one ``pallas_call`` equation of kernel ``name`` on an (n, dj)
-    A_j at the default blocks, traced for the chip."""
+def _composed_jaxpr(name, n, dj, b=1):
+    """Kernel ``name`` on an (n, dj) A_j at the default blocks, traced for
+    the chip, with 1-D vectors (B = 1) or width-``b`` panels."""
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    panel = () if b == 1 else (b,)
     kw = dict(interpret=False)
     pg = dict(n=n, lam=LAM, **kw)
     fn, args = {
         "feature_matvec": (functools.partial(feature_matvec, **kw),
-                           (f32(n, dj), f32(dj))),
+                           (f32(n, dj), f32(dj, *panel))),
         "feature_rmatvec": (functools.partial(feature_rmatvec, **kw),
-                            (f32(n, dj), f32(n))),
+                            (f32(n, dj), f32(n, *panel))),
         "feature_hvp": (functools.partial(feature_hvp, **kw),
-                        (f32(n, dj), f32(n), f32(n))),
+                        (f32(n, dj), f32(n), f32(n, *panel))),
         "fused_pgrad": (functools.partial(fused_round.fused_pgrad, **pg),
-                        (f32(n, dj), f32(n), f32(dj), f32(dj))),
+                        (f32(n, dj), f32(n, *panel), f32(dj, *panel),
+                         f32(dj))),
         "fused_phvp": (functools.partial(fused_round.fused_phvp, **pg),
-                       (f32(n, dj), f32(n), f32(n), f32(dj), f32(dj))),
+                       (f32(n, dj), f32(n), f32(n, *panel),
+                        f32(dj, *panel), f32(dj))),
     }[name]
-    calls = [eqn for eqn in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+    return jax.make_jaxpr(fn)(*args)
+
+
+def _composed_call(name, n, dj, b=1):
+    """The one ``pallas_call`` equation of ``_composed_jaxpr``."""
+    calls = [eqn for eqn in _eqns(_composed_jaxpr(name, n, dj, b).jaxpr)
              if eqn.primitive.name == "pallas_call"]
     assert len(calls) == 1
     return calls[0]
 
 
+NAMES = ["feature_matvec", "feature_rmatvec", "feature_hvp", "fused_pgrad",
+         "fused_phvp"]
+
+
+@pytest.mark.parametrize("b", [1, 3], ids=["b1", "b3"])
 @pytest.mark.parametrize("n,dj", [(1024, 512), (1100, 300), (1100, 600),
                                   (400, 300)])
-@pytest.mark.parametrize("name", ["feature_matvec", "feature_rmatvec",
-                                  "feature_hvp", "fused_pgrad",
-                                  "fused_phvp"])
-def test_only_ragged_shapes_trace_a_mask(name, n, dj):
+@pytest.mark.parametrize("name", NAMES)
+def test_only_ragged_shapes_trace_a_mask(name, n, dj, b):
     """A kernel traces the edge mask (an iota in its body) only where its
-    contraction axis (d_j for ``feature_matvec``, n for the rest) is
-    longer than its 512-wide block and not a multiple of it; a shorter
-    axis is one block spanning the whole axis, and needs none."""
+    contraction axis (d_j for ``feature_matvec``, n for the rest) ends in
+    a cut block.  The MXU body (B = 3) masks where the axis is longer
+    than its 512-wide block and not a multiple of it; a shorter axis is
+    one block spanning the whole axis, and needs none.  The B = 1 body
+    skips whole row chunks and lane groups past the end, so it masks
+    where the axis is not a multiple of one chunk: 8 rows of A_j^T (d_j)
+    or 128 lanes (n)."""
     extent = dj if name == "feature_matvec" else n
-    masked = extent > 512 and extent % 512 != 0
-    body = list(_eqns(_composed_call(name, n, dj).params["jaxpr"]))
+    if b > 1:
+        masked = extent > 512 and extent % 512 != 0
+    else:
+        masked = extent % (8 if name == "feature_matvec" else 128) != 0
+    body = list(_eqns(_composed_call(name, n, dj, b).params["jaxpr"]))
     assert any(e.primitive.name == "iota" for e in body) == masked
 
 
-@pytest.mark.parametrize("name", ["feature_matvec", "feature_rmatvec",
-                                  "feature_hvp", "fused_pgrad",
-                                  "fused_phvp"])
-def test_epsilon_block_spans_its_columns(name):
-    """At the epsilon block (400,000 x 500) the A tile is 512 rows by all
-    500 columns: no block overhangs the lane axis, and the grid runs
-    cdiv(400,000, 512) = 782 row blocks."""
-    grid = _composed_call(name, 400_000, 500).params["grid_mapping"]
+@pytest.mark.parametrize("b", [1, 3])
+@pytest.mark.parametrize("name", NAMES)
+def test_epsilon_block_spans_its_columns(name, b):
+    """At the epsilon block (400,000 x 500) the MXU body's A tile (B = 3)
+    is 512 rows by all 500 columns: no block overhangs the lane axis, and
+    the grid runs cdiv(400,000, 512) = 782 row blocks.  The B = 1 body's
+    tile of A_j^T is all 500 rows (as 504) by 4,096 columns of n: 98 grid
+    steps over n, one over d_j and one over the right-hand side."""
+    grid = _composed_call(name, EPS_N, EPS_DJ, b).params["grid_mapping"]
     a_block = grid.block_mappings[0].block_shape
-    assert [getattr(b, "block_size", b) for b in a_block] == [512, 500]
-    assert 782 in grid.grid and 1 in grid.grid
+    got = [getattr(x, "block_size", x) for x in a_block]
+    if b > 1:
+        assert got == [512, 500]
+        assert 782 in grid.grid and 1 in grid.grid
+    else:
+        assert got == GEMV_EPS_TILE
+        assert sorted(grid.grid) == [1, 1, GEMV_EPS_STEPS]
+
+
+def _gemv_case(name, A, vecs, **kw):
+    """Kernel ``name`` vmapped over the machine axis of A as the local
+    oracles call it, and its float64 product."""
+    f64 = lambda x: np.asarray(x, np.float64)                  # noqa: E731
+    A64 = f64(A)
+    w, r, h, mk = (f64(vecs[k]) for k in ("w", "r", "h", "mask"))
+    at_r = np.einsum("mnd,n->md", A64, r)
+    at_hr = np.einsum("mnd,n->md", A64, h * r)
+    pg = dict(n=N_DIV, lam=LAM, **kw)
+    if name == "feature_matvec":
+        fn = jax.vmap(functools.partial(feature_matvec, **kw))
+        return fn(A, vecs["w"]), np.einsum("mnd,md->mn", A64, w)
+    if name == "feature_rmatvec":
+        fn = jax.vmap(functools.partial(feature_rmatvec, **kw),
+                      in_axes=(0, None))
+        return fn(A, vecs["r"]), at_r
+    if name == "feature_hvp":
+        fn = jax.vmap(functools.partial(feature_hvp, **kw),
+                      in_axes=(0, None, None))
+        return fn(A, vecs["h"], vecs["r"]), at_hr
+    if name == "fused_pgrad":
+        fn = jax.vmap(functools.partial(fused_round.fused_pgrad, **pg),
+                      in_axes=(0, None, 0, 0))
+        return (fn(A, vecs["r"], vecs["w"], vecs["mask"]),
+                (at_r / N_DIV + LAM * w) * mk)
+    fn = jax.vmap(functools.partial(fused_round.fused_phvp, **pg),
+                  in_axes=(0, None, None, 0, 0))
+    return (fn(A, vecs["h"], vecs["r"], vecs["w"], vecs["mask"]),
+            (at_hr / N_DIV + LAM * w) * mk)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("n,dj", [(1100, 129), (2100, 289), (2500, 500)])
+@pytest.mark.parametrize("name", NAMES)
+def test_gemv_body_matches_float64(name, n, dj, dtype):
+    """The B = 1 body at its default tiles, vmapped over 4 machines as the
+    local oracles call it, against a float64 product of the same inputs:
+    d_j not a multiple of 8 (a masked last row chunk) and n not a
+    multiple of 128 (a masked last lane group) in one tile that overhangs
+    A_j^T, where reads give NaN.  (Several steps along either axis, at
+    small blocks: ``test_composed_kernel_reads_ragged_a_in_place``.)"""
+    ks = jax.random.split(jax.random.PRNGKey(n + dj), 5)
+    m = 4
+    A = jax.random.normal(ks[0], (m, n, dj)).astype(dtype)
+    vecs = dict(w=jax.random.normal(ks[1], (m, dj)).astype(dtype),
+                r=jax.random.normal(ks[2], (n,)).astype(dtype),
+                h=jax.random.uniform(ks[3], (n,)).astype(dtype),
+                mask=(jax.random.uniform(ks[4], (m, dj)) > 0.2
+                      ).astype(dtype))
+    got, want = _gemv_case(name, A, vecs, interpret=NAN_PAST_END)
+    assert got.shape == want.shape and got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float64), want,
+                               **TOL[dtype])
+
+
+@pytest.mark.parametrize("name", NAMES)
+def test_gemv_and_mxu_bodies_agree(name):
+    """Each column of a B = 3 panel (the MXU body) equals the B = 1 body
+    on that column alone, to f32 rounding: one algorithm at two widths."""
+    n, dj, b = 1100, 300, 3
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    A = jax.random.normal(ks[0], (n, dj))
+    w = jax.random.normal(ks[1], (dj, b))
+    r = jax.random.normal(ks[2], (n, b))
+    h = jax.random.uniform(ks[3], (n,))
+    mk = (jax.random.uniform(ks[4], (dj,)) > 0.2).astype(jnp.float32)
+    pg = functools.partial(fused_round.fused_pgrad, n=N_DIV, lam=LAM)
+    ph = functools.partial(fused_round.fused_phvp, n=N_DIV, lam=LAM)
+    fn = {"feature_matvec": lambda w, r: feature_matvec(A, w),
+          "feature_rmatvec": lambda w, r: feature_rmatvec(A, r),
+          "feature_hvp": lambda w, r: feature_hvp(A, h, r),
+          "fused_pgrad": lambda w, r: pg(A, r, w, mk),
+          "fused_phvp": lambda w, r: ph(A, h, r, w, mk)}[name]
+    panel = np.asarray(fn(w, r))
+    for col in range(b):
+        one = np.asarray(fn(w[:, col:col + 1], r[:, col:col + 1]))
+        assert one.shape == panel[:, :1].shape
+        np.testing.assert_allclose(
+            one[:, 0], panel[:, col], rtol=1e-5,
+            atol=1e-5 * np.abs(panel[:, col]).max())
 
 
 def test_batched_rhs_matches_loop():

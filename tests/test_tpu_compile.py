@@ -144,6 +144,46 @@ def test_composed_oracle_compiles_at_epsilon_block(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# The B = 1 (VPU) body of every composed kernel at a deployment's block:
+# epsilon's 400,000 x 500 and PASCAL ocr's 3,500,000 x 289 shard.  A
+# 2-D A_j is kept with n as its minor dimension, which is A_j^T in
+# row-major tiles, so the body reads it with no copy.
+GEMV_BLOCKS = {"epsilon": (EPS_N, EPS_DJ), "ocr": (3_500_000, 289)}
+
+
+def _gemv_call(name, n, dj):
+    pg = dict(n=n, lam=1e-5, interpret=False)
+    return {
+        "feature_matvec": (lambda A, w: feature_matvec(A, w, interpret=False),
+                           [(n, dj), (dj,)]),
+        "feature_rmatvec": (lambda A, r: feature_rmatvec(A, r,
+                                                         interpret=False),
+                            [(n, dj), (n,)]),
+        "feature_hvp": (lambda A, h, av: feature_hvp(A, h, av,
+                                                     interpret=False),
+                        [(n, dj), (n,), (n,)]),
+        "fused_pgrad": (functools.partial(fused_round.fused_pgrad, **pg),
+                        [(n, dj), (n,), (dj,), (dj,)]),
+        "fused_phvp": (functools.partial(fused_round.fused_phvp, **pg),
+                       [(n, dj), (n,), (n,), (dj,), (dj,)]),
+    }[name]
+
+
+@pytest.mark.parametrize("block", sorted(GEMV_BLOCKS))
+@pytest.mark.parametrize("name", ["feature_matvec", "feature_rmatvec",
+                                  "feature_hvp", "fused_pgrad",
+                                  "fused_phvp"])
+def test_gemv_body_compiles_at_deployment_blocks(one_chip, name, block):
+    n, dj = GEMV_BLOCKS[block]
+    fn, shapes = _gemv_call(name, n, dj)
+    compiled = jax.jit(fn).lower(
+        *[_shape(one_chip, s) for s in shapes]).compile()
+    text = compiled.as_text()
+    assert name in text and "tpu_custom_call" in text
+    # no copy of A_j, and no 128-lane panel of an R^n vector
+    assert compiled.memory_analysis().temp_size_in_bytes < n * 4 * 8
+
+
 # Every composed kernel, at a block a little wider than one tile.
 NAMED_N, NAMED_DJ = 4096, 500
 NAMED = {
