@@ -19,9 +19,9 @@ from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 import jax.extend.core as jex
-import jax.numpy as jnp
 
-from ..core.comm import CommLedger, parse_comm_scope
+from ..core.comm import parse_comm_scope
+from ..core.engine import Capture, trace_segments
 
 # --------------------------------------------------------------------------
 # Generic jaxpr traversal
@@ -146,75 +146,34 @@ def extract_messages(jaxpr) -> Tuple[List[StaticMessage], List[str]]:
 
 
 # --------------------------------------------------------------------------
-# Step tracing (shared by plan audits and mutation fixtures)
+# Step tracing (plan audits and mutation fixtures)
 # --------------------------------------------------------------------------
 
 @dataclasses.dataclass
-class TracedStep:
-    """One traced segment step: the jaxpr, its hoisted consts, and the
-    schedule the trace captured into the scratch ledger."""
+class TracedStep(Capture):
+    """One traced segment step (jaxpr, consts, captured schedule) and
+    the program segments it drives."""
 
-    closed: Any                              # ClosedJaxpr
-    consts: List[Any]
-    structure: str
-    records: List[Any]                       # captured CommRecords
-    rounds_per_step: int
-    marks: List[int]                         # record-stream round marks
-    segments: List[int]                      # program segment indices
-    counts: List[int]                        # scan length per segment
+    segments: List[int] = dataclasses.field(default_factory=list)
+    counts: List[int] = dataclasses.field(default_factory=list)
 
 
 def trace_steps(dist, program) -> List[TracedStep]:
     """Trace every distinct segment step of a local ``RoundProgram``
-    into (jaxpr, consts, captured schedule) — the same ``make_jaxpr``
-    split ``repro.api.batch.prepare_cell`` performs, shared here so
-    mutation fixtures (raw ``dist`` + program, no ``ExecutionPlan``)
-    go through the identical trace path the batch engine uses."""
-    from ..api.batch import _convert, _segment_xs
-
-    scheduled = getattr(getattr(dist.comm, "channel", None),
-                        "scheduled", False)
-    real = dist.comm.ledger
-    dist.comm.ledger = scratch = CommLedger()
-    dist.comm._tracing = True
-    out: List[TracedStep] = []
-    try:
-        carry = program.init
-        by_step: Dict[tuple, TracedStep] = {}
-        for s, seg in enumerate(program.segments):
-            xs = _segment_xs(seg)
-            key = (id(seg.step), xs.dtype.str, xs.shape[1:])
-            if key not in by_step:
-                n0, r0 = len(scratch.records), scratch.rounds
-                m0 = len(scratch.round_marks)
-                if scheduled:
-                    def traced(c, rx, _step=seg.step):
-                        rk, x = rx
-                        dist.comm.begin_round(rk)
-                        try:
-                            return _step(dist, c, x)
-                        finally:
-                            dist.comm.reset_round()
-                    conv = _convert(traced, carry,
-                                    (jnp.int32(0), jnp.asarray(xs[0])))
-                else:
-                    conv = _convert(lambda c, x: seg.step(dist, c, x),
-                                    carry, jnp.asarray(xs[0]))
-                ts = TracedStep(
-                    closed=conv.closed, consts=list(conv.consts),
-                    structure=conv.structure,
-                    records=list(scratch.records[n0:]),
-                    rounds_per_step=scratch.rounds - r0,
-                    marks=[m - n0 for m in scratch.round_marks[m0:]],
-                    segments=[], counts=[])
-                by_step[key] = ts
-                out.append(ts)
-            by_step[key].segments.append(s)
-            by_step[key].counts.append(int(seg.count))
-    finally:
-        dist.comm.ledger = real
-        dist.comm._tracing = False
-    return out
+    into (jaxpr, consts, captured schedule) through
+    ``core.engine.trace_segments``, the trace the batch engine groups
+    cells by, so mutation fixtures (raw ``dist`` + program, no
+    ``ExecutionPlan``) audit exactly what the engines run."""
+    by_cap: Dict[int, TracedStep] = {}
+    for s, (seg, cap) in enumerate(zip(program.segments,
+                                       trace_segments(dist, program))):
+        ts = by_cap.get(id(cap))
+        if ts is None:
+            ts = by_cap[id(cap)] = TracedStep(cap.closed, cap.pure,
+                                              *cap.schedule)
+        ts.segments.append(s)
+        ts.counts.append(int(seg.count))
+    return list(by_cap.values())
 
 
 __all__ = [

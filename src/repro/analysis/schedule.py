@@ -28,6 +28,7 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from ..core.comm import CommLedger, sanitize_scope_tag
+from ..core.runtime import ScanSpan
 from .extract import StaticMessage, TracedStep, extract_messages
 from .findings import Finding
 
@@ -183,7 +184,7 @@ def static_expand_local(steps: List[TracedStep], program,
         offs = round_offsets(len(ts.records), ts.marks)
         rels = [msg.rnd - msgs[0].rnd + offs[0] for msg in msgs] \
             if msgs else []
-        rps = ts.rounds_per_step
+        rps = ts.rounds
         for k in range(int(seg.count)):
             for msg, rel in zip(msgs, rels):
                 rnd = base + k * rps + rel
@@ -205,7 +206,7 @@ def replay_expand_local(steps: List[TracedStep], program,
     led = CommLedger()
     for s, seg in enumerate(program.segments):
         ts = _step_for_segment(steps, s)
-        led.replay_schedule(ts.records, ts.rounds_per_step, ts.marks,
+        led.replay_schedule(ts.records, ts.rounds, ts.marks,
                             int(seg.count), channel=sched_chan,
                             faults=None)
     return led
@@ -276,7 +277,7 @@ def verify_local_schedule(steps: List[TracedStep], program, chan,
 
 def static_expand_sharded(msgs: List[StaticMessage],
                           trace_marks: Sequence[int],
-                          spans: Sequence[Tuple[int, int, int, int]],
+                          spans: Sequence[ScanSpan],
                           chan) -> Tuple[List[tuple], int]:
     """Expand the trace-time static schedule the way the sharded driver
     expands its ledger: records outside scan spans copy once; each
@@ -296,7 +297,7 @@ def static_expand_sharded(msgs: List[StaticMessage],
     stream: List[tuple] = []
     rounds_total = 0
     prev_end = 0
-    for start, end, r_traced, count in spans:
+    for start, end, r_traced, count, _ in spans:
         for i in range(prev_end, start):
             emit(stream, msgs[i],
                  rounds_total + offs[i] - offs[prev_end])
@@ -316,7 +317,7 @@ def static_expand_sharded(msgs: List[StaticMessage],
 
 
 def verify_sharded_schedule(closed, led: CommLedger,
-                            spans: Sequence[Tuple[int, int, int, int]],
+                            spans: Sequence[ScanSpan],
                             chan,
                             executed_ledger: Optional[CommLedger] = None,
                             ) -> Tuple[List[Finding], Dict[str, int]]:
@@ -327,9 +328,9 @@ def verify_sharded_schedule(closed, led: CommLedger,
     span_starts: Optional[Dict[int, int]] = None
     if scheduled:
         span_starts = {}
-        for start, end, _, _ in spans:
-            for i in range(start, end):
-                span_starts[i] = start
+        for sp in spans:
+            for i in range(sp.start, sp.end):
+                span_starts[i] = sp.start
     findings = _check_messages(
         msgs, problems, led.records, led.round_marks,
         placement="sharded", where="sharded trace",

@@ -9,9 +9,9 @@ thm2-style sweep compiles a handful of XLA programs instead of one per
 cell.
 
 **How a cell becomes batchable.**  A cell's step function closes over
-its own data (``A_stk``, masks, hyper-parameter scalars).  For each
-distinct step we trace it once with ``jax.make_jaxpr`` and split the
-result into
+its own data (``A_stk``, masks, hyper-parameter scalars).  Each
+distinct step is traced once (``core.engine.trace_segments``, the
+capture every trace-once engine shares) and the result split into
 
   * the *structure* — the jaxpr with its constants abstracted out, and
   * the *consts* — the closed-over arrays, in trace order.
@@ -52,7 +52,7 @@ same pieces.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence
 
 import numpy as np
 import jax
@@ -60,41 +60,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.comm import CommLedger, inject_crash_recovery
-from ..core.engine import GAP_SCOPE, Segment, trace_closure
+from ..core.engine import (GAP_SCOPE, Capture, _segment_xs, active_faults,
+                           capture, scan_input, scheduled_channel,
+                           trace_segments)
 from ..metrics.spans import span
 from .plan import ExecutionPlan, PlanError, RunResult
-
-
-# --------------------------------------------------------------------------
-# Structure/consts splitting
-# --------------------------------------------------------------------------
-
-@dataclasses.dataclass
-class _Converted:
-    """One closure, split into pure structure + hoisted consts."""
-
-    pure: Callable                    # pure(consts, *args) -> outputs
-    consts: List[jnp.ndarray]
-    structure: str                    # jaxpr text, consts abstracted
-    schedule: Tuple[list, int, list]  # (ledger records, rounds,
-                                      #  round-boundary marks) per call
-    closed: object = None             # the traced ClosedJaxpr itself —
-                                      # repro.analysis walks its
-                                      # equations (structure text is for
-                                      # grouping, not for analysis)
-
-
-def _convert(fn: Callable, *example_args) -> _Converted:
-    closed, pure = trace_closure(fn, *example_args)
-    return _Converted(pure=pure, consts=list(closed.consts),
-                      structure=str(closed.jaxpr), schedule=([], 0, []),
-                      closed=closed)
-
-
-def _segment_xs(seg: Segment) -> np.ndarray:
-    if seg.xs is not None:
-        return np.asarray(seg.xs)
-    return np.arange(seg.count, dtype=np.int32)
 
 
 @dataclasses.dataclass
@@ -105,8 +75,8 @@ class Cell:
     plan: ExecutionPlan
     dist: object
     program: object
-    steps: List[_Converted]           # one per segment (shared by identity)
-    meas: Optional[_Converted]
+    steps: List[Capture]              # one per segment (shared by identity)
+    meas: Optional[Capture]
 
     def group_key(self) -> tuple:
         """The grouping axis: cells batch iff their keys are equal.
@@ -156,55 +126,14 @@ def prepare_cell(plan: ExecutionPlan) -> Optional[Cell]:
 
 def _trace_cell(plan: ExecutionPlan, dist, program,
                 measure_fn) -> Cell:
-    scheduled = getattr(getattr(dist.comm, "channel", None),
-                        "scheduled", False)
-    real = dist.comm.ledger
-    dist.comm.ledger = scratch = CommLedger()
-    dist.comm._tracing = True   # captured schedules stay fault-free; the
-    try:                        # per-cell ledger replay injects faults
-        carry = program.init
-        by_step = {}
-        steps = []
-        for seg in program.segments:
-            xs = _segment_xs(seg)
-            key = (id(seg.step), xs.dtype.str, xs.shape[1:])
-            if key not in by_step:
-                n0, r0 = len(scratch.records), scratch.rounds
-                m0 = len(scratch.round_marks)
-                if scheduled:
-                    # scheduled channel: the round index rides along as
-                    # part of xs so the compiled group runner can switch
-                    # stages mid-scan; trace with a symbolic index (the
-                    # example int32 is abstracted by make_jaxpr) and pin
-                    # it for the step's channel transforms.
-                    def traced(c, rx, _step=seg.step):
-                        rk, x = rx
-                        dist.comm.begin_round(rk)
-                        try:
-                            return _step(dist, c, x)
-                        finally:
-                            dist.comm.reset_round()
-                    conv = _convert(traced, carry,
-                                    (jnp.int32(0), jnp.asarray(xs[0])))
-                else:
-                    conv = _convert(lambda c, x: seg.step(dist, c, x),
-                                    carry, jnp.asarray(xs[0]))
-                conv.schedule = (scratch.records[n0:], scratch.rounds - r0,
-                                 [m - n0 for m in scratch.round_marks[m0:]])
-                by_step[key] = conv
-            steps.append(by_step[key])
-        meas = None
-        if measure_fn is not None:
-            n0 = len(scratch.records)
-            # every registered program emits the round iterate in stacked
-            # block form (m, d_max) — the same shape zeros_like_w builds
-            meas = _convert(measure_fn, dist.zeros_like_w())
-            if len(scratch.records) != n0:
-                raise PlanError("measure performed metered communication; "
-                                "measurement must stay oracle-free")
-    finally:
-        dist.comm.ledger = real
-        dist.comm._tracing = False
+    steps, meas = trace_segments(dist, program), None
+    if measure_fn is not None:
+        # every registered program emits the round iterate in stacked
+        # block form (m, d_max) — the same shape zeros_like_w builds
+        meas = capture(dist, measure_fn, dist.zeros_like_w())
+        if meas.records or meas.rounds:
+            raise PlanError("measure performed metered communication; "
+                            "measurement must stay oracle-free")
     return Cell(plan=plan, dist=dist, program=program, steps=steps,
                 meas=meas)
 
@@ -252,8 +181,7 @@ def _execute_group(cells: List[Cell],
                          *[p.init for p in progs])
     meas0 = cells[0].meas
     # all cells in a group share the wire channel (group_key pins it)
-    chan0 = getattr(cells[0].dist.comm, "channel", None)
-    sched_chan = chan0 if getattr(chan0, "scheduled", False) else None
+    sched_chan = scheduled_channel(cells[0].dist)
     runners = runner_cache if runner_cache is not None else {}
     consts_cache, outs = {}, []
     mconsts = _stack_consts(cells, lambda c: c.meas) if meas0 else []
@@ -280,12 +208,9 @@ def _execute_group(cells: List[Cell],
                     conv0.pure, meas0.pure if meas0 else None, shared_xs,
                     sched_chan is not None)
         xs = cell_xs[0] if shared_xs else np.stack(cell_xs, axis=1)
-        xs_arg = jnp.asarray(xs)
         rounds_per_step = conv0.schedule[1]
-        if sched_chan is not None:
-            rid = round_base + np.arange(seg0.count,
-                                         dtype=np.int32) * rounds_per_step
-            xs_arg = (jnp.asarray(rid), xs_arg)
+        xs_arg = scan_input(jnp.asarray(xs), seg0.count, round_base,
+                            rounds_per_step, sched_chan is not None)
         round_base += rounds_per_step * seg0.count
         with span("repro.run"):
             carry, out = jax.block_until_ready(
@@ -296,9 +221,7 @@ def _execute_group(cells: List[Cell],
 
     # all cells in a group share the fault schedule (group_key pins it);
     # each cell's replay draws its own fault stream into its own ledger
-    faults0 = getattr(cells[0].dist.comm, "faults", None)
-    if faults0 is not None and not faults0.active:
-        faults0 = None
+    faults0 = active_faults(cells[0].dist)
     ledgers = []
     with span("repro.ledger_replay"):
         for cell in cells:

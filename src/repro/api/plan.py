@@ -527,8 +527,7 @@ def plan(spec: RunSpec,
         raise PlanError(
             "fault injection needs the local placement (the "
             "detect/retransmit recovery dance runs on concrete host "
-            "arrays; the shard_map driver meters at trace time); run "
-            "faulted specs with placement='local'")
+            "arrays); run faulted specs with placement='local'")
 
     if spec.instance is None and spec.algorithm is None:
         # resolution-only: the axes are the whole request (dry-run tools)

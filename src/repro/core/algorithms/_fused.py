@@ -1,8 +1,10 @@
-"""Whole-round fused program rotation for linear first-order rounds.
+"""The round program of linear first-order algorithms, from their update.
 
 Every first-order algorithm in the family F^{lam,L} runs the same round:
 reduce the response, take the masked partial gradient, apply a
-block-local update.  When the dist's oracle backend offers the
+block-local update.  ``dgd``, ``dagd`` and ``prox_dagd`` define only
+that update; ``linear_round_program`` builds the round from it, composed
+from the oracles or fused.  When the dist's oracle backend offers the
 whole-round ``round_step`` capability (the ``fused`` backend,
 ``kernels/fused_round.py``), that round can run as ONE Pallas kernel per
 machine — but only after a rotation: the composed step computes this
@@ -35,37 +37,45 @@ import jax.numpy as jnp
 from ..engine import RoundProgram, Segment
 
 
-def fused_linear_program(dist, rounds: int, update,
+def linear_round_program(dist, rounds: int, update, *,
                          xs: Optional[np.ndarray] = None,
-                         name: str = "") -> Optional[RoundProgram]:
-    """The fused RoundProgram for a response->pgrad->update round, or
-    ``None`` when the dist's backend (or this cell's channel/shape)
-    cannot rotate it — callers fall back to their composed program.
+                         name: str = "") -> RoundProgram:
+    """The RoundProgram of a response->pgrad->update round: the fused
+    one-kernel program where the dist's backend can rotate the round,
+    else the composed one, both built from the same ``update``.
 
     ``update(x, y, g, coeff) -> (x_new, y_new)`` is the algorithm's
-    block-local update (elementwise over the coordinate blocks; it is
-    traced into the kernel body).  ``xs`` optionally supplies the
-    per-round ``coeff`` input (e.g. FISTA momentum coefficients).
+    block-local update (elementwise over the coordinate blocks; the
+    fused program traces it into the kernel body).  The round reduces
+    the response at ``y`` and emits ``x_new`` as its iterate.  ``xs``
+    optionally supplies the per-round ``coeff`` input (e.g. FISTA
+    momentum coefficients).
     """
-    maker = getattr(dist, "fused_round_step", None)
-    if maker is None:
-        return None      # sharded placement (or a non-protocol dist)
-    step_fn = maker(update)
-    if step_fn is None:
-        return None      # backend or cell does not support the rotation
     zero = dist.zeros_like_w()
-    zloc0 = jnp.zeros((dist.part.m, dist.n))
     no_coeff = jnp.float32(0.0)
+    maker = getattr(dist, "fused_round_step", None)  # local placement only
+    step_fn = maker(update) if maker is not None else None
+    if step_fn is None:
+        def step(dist, carry, x):
+            x_c, y_c = carry
+            z = dist.response(y_c)
+            g = dist.pgrad(y_c, z)
+            x_n, y_n = update(x_c, y_c, g, x if xs is not None else no_coeff)
+            dist.end_round()
+            return (x_n, y_n), x_n
 
-    def step(dist, carry, x):
-        x_c, y_c, zloc = carry
-        z = dist.reduce_response(zloc)
-        coeff = x if xs is not None else no_coeff
-        rnd = dist.comm._round_index()
-        x_n, y_n, zloc_n = step_fn(z, x_c, y_c, coeff, rnd)
-        dist.end_round()
-        return (x_n, y_n, zloc_n), x_n
+        init = (zero, zero)
+    else:
+        def step(dist, carry, x):
+            x_c, y_c, zloc = carry
+            z = dist.reduce_response(zloc)
+            coeff = x if xs is not None else no_coeff
+            rnd = dist.comm._round_index()
+            x_n, y_n, zloc_n = step_fn(z, x_c, y_c, coeff, rnd)
+            dist.end_round()
+            return (x_n, y_n, zloc_n), x_n
 
-    return RoundProgram(init=(zero, zero, zloc0),
+        init = (zero, zero, jnp.zeros((dist.part.m, dist.n)))
+    return RoundProgram(init=init,
                         segments=[Segment(step, rounds, xs=xs, name=name)],
                         final=lambda c: c[0])

@@ -22,8 +22,8 @@ import math
 import numpy as np
 import jax.numpy as jnp
 
-from ..engine import RoundProgram, Segment, run_program
-from ._fused import fused_linear_program
+from ..engine import RoundProgram, run_program
+from ._fused import linear_round_program
 
 
 def fista_momentum_schedule(rounds: int) -> np.ndarray:
@@ -46,7 +46,6 @@ def dagd_program(dist, rounds: int, L: float, lam: float = 0.0
     # only in their hyper-parameters (a python-float literal would bake a
     # per-cell constant into the traced program).
     inv_L = jnp.float32(1.0 / L)
-    zero = dist.zeros_like_w()
 
     if lam > 0:
         kappa = L / lam
@@ -58,48 +57,16 @@ def dagd_program(dist, rounds: int, L: float, lam: float = 0.0
             y_new = x_new + beta * (x_new - x)
             return x_new, y_new
 
-        fused = fused_linear_program(dist, rounds, update, name="agd")
-        if fused is not None:
-            return fused
-
-        def step(dist, carry, _):
-            x, y = carry
-            z = dist.response(y)
-            g = dist.pgrad(y, z)
-            x_new = y - inv_L * g
-            y_new = x_new + beta * (x_new - x)
-            dist.end_round()
-            return (x_new, y_new), x_new
-
-        return RoundProgram(init=(zero, zero),
-                            segments=[Segment(step, rounds, name="agd")],
-                            final=lambda c: c[0])
+        return linear_round_program(dist, rounds, update, name="agd")
 
     def update(x, y, g, coeff):
         x_new = y - inv_L * g
         y_new = x_new + coeff * (x_new - x)
         return x_new, y_new
 
-    fused = fused_linear_program(dist, rounds, update,
-                                 xs=fista_momentum_schedule(rounds),
-                                 name="fista")
-    if fused is not None:
-        return fused
-
-    def step(dist, carry, coeff):
-        x, y = carry
-        z = dist.response(y)
-        g = dist.pgrad(y, z)
-        x_new = y - inv_L * g
-        y_new = x_new + coeff * (x_new - x)
-        dist.end_round()
-        return (x_new, y_new), x_new
-
-    return RoundProgram(
-        init=(zero, zero),
-        segments=[Segment(step, rounds, xs=fista_momentum_schedule(rounds),
-                          name="fista")],
-        final=lambda c: c[0])
+    return linear_round_program(dist, rounds, update,
+                                xs=fista_momentum_schedule(rounds),
+                                name="fista")
 
 
 def dagd(dist, rounds: int, L: float, lam: float = 0.0,

@@ -13,8 +13,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from ..engine import RoundProgram, Segment, run_program
-from ._fused import fused_linear_program
+from ..engine import RoundProgram, run_program
+from ._fused import linear_round_program
 
 
 def dgd_program(dist, rounds: int, L: float, lam: float = 0.0
@@ -28,20 +28,7 @@ def dgd_program(dist, rounds: int, L: float, lam: float = 0.0
         w_new = y - eta * g
         return w_new, w_new
 
-    fused = fused_linear_program(dist, rounds, update, name="gd")
-    if fused is not None:
-        return fused
-
-    def step(dist, w, _):
-        z = dist.response(w)
-        g = dist.pgrad(w, z)
-        w_new = w - eta * g
-        dist.end_round()
-        return w_new, w_new
-
-    return RoundProgram(init=dist.zeros_like_w(),
-                        segments=[Segment(step, rounds, name="gd")],
-                        final=lambda w: w)
+    return linear_round_program(dist, rounds, update, name="gd")
 
 
 def dgd(dist, rounds: int, L: float, lam: float = 0.0,

@@ -16,8 +16,8 @@ from typing import Callable
 
 import jax.numpy as jnp
 
-from ..engine import RoundProgram, Segment, run_program
-from ._fused import fused_linear_program
+from ..engine import RoundProgram, run_program
+from ._fused import linear_round_program
 from .dagd import fista_momentum_schedule
 
 
@@ -43,7 +43,6 @@ def prox_dagd_program(dist, rounds: int, L: float, prox: Callable,
     # exactly once, as it always has.
     inv_L = 1.0 / L
     step_L = jnp.float32(inv_L)
-    zero = dist.zeros_like_w()
 
     if lam > 0:
         kappa = L / lam
@@ -55,48 +54,16 @@ def prox_dagd_program(dist, rounds: int, L: float, prox: Callable,
             y_new = x_new + beta * (x_new - x)
             return x_new, y_new
 
-        fused = fused_linear_program(dist, rounds, update, name="apg")
-        if fused is not None:
-            return fused
-
-        def step(dist, carry, _):
-            x, y = carry
-            z = dist.response(y)
-            g = dist.pgrad(y, z)
-            x_new = prox(y - step_L * g, inv_L)  # block-local prox
-            y_new = x_new + beta * (x_new - x)
-            dist.end_round()
-            return (x_new, y_new), x_new
-
-        return RoundProgram(init=(zero, zero),
-                            segments=[Segment(step, rounds, name="apg")],
-                            final=lambda c: c[0])
+        return linear_round_program(dist, rounds, update, name="apg")
 
     def update(x, y, g, coeff):
         x_new = prox(y - step_L * g, inv_L)      # block-local prox
         y_new = x_new + coeff * (x_new - x)
         return x_new, y_new
 
-    fused = fused_linear_program(dist, rounds, update,
-                                 xs=fista_momentum_schedule(rounds),
-                                 name="fista")
-    if fused is not None:
-        return fused
-
-    def step(dist, carry, coeff):
-        x, y = carry
-        z = dist.response(y)
-        g = dist.pgrad(y, z)
-        x_new = prox(y - step_L * g, inv_L)      # block-local prox
-        y_new = x_new + coeff * (x_new - x)
-        dist.end_round()
-        return (x_new, y_new), x_new
-
-    return RoundProgram(
-        init=(zero, zero),
-        segments=[Segment(step, rounds, xs=fista_momentum_schedule(rounds),
-                          name="fista")],
-        final=lambda c: c[0])
+    return linear_round_program(dist, rounds, update,
+                                xs=fista_momentum_schedule(rounds),
+                                name="fista")
 
 
 def prox_dagd(dist, rounds: int, L: float, prox: Callable,

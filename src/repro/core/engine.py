@@ -32,13 +32,22 @@ Two engines execute a program:
 
 **Trace-once ledger schedule.**  The ``CommLedger`` meters the paper's
 communication model, and certifications must be bit-invariant to the
-execution engine.  The scan engine therefore captures each step's op
-stream once (an abstract ``jax.eval_shape`` trace against a scratch
-ledger), silences the ledger during the compiled run, and replays the
-captured schedule ``count`` times into the real ledger.  Because the
-python engine runs the *same* step functions, the replayed stream is
-bit-identical to the per-call stream — ``tests/test_ledger_invariance``
-pins this.
+execution engine.  The scan engines therefore capture each segment's
+step once (``trace_segment``: one ``make_jaxpr`` trace against a scratch
+ledger), silence the ledger during the compiled run, and replay the
+captured schedule ``count`` times into the real ledger
+(``CommLedger.replay_schedule``).  Because the python engine runs the
+*same* step functions, the replayed stream is bit-identical to the
+per-call stream — ``tests/test_ledger_invariance`` pins this.
+
+This module owns that contract for every engine and placement: segment
+capture (``capture``/``trace_segment``), the scan body that pins the
+global round index under a round-scheduled channel (``round_fn``) and
+the scanned ``(round index, x)`` input it reads (``scan_input``).  The
+local scan engine below, ``repro.api.batch.execute_group``,
+``repro.analysis.extract.trace_steps`` and
+``core.runtime.ShardedProgram`` all go through them, and all meter
+through ``replay_schedule``.
 
 **In-scan gap measurement.**  Passing ``measure`` (any traceable
 ``w_k -> scalar``, e.g. ``f(w_k) - f*``) folds suboptimality measurement
@@ -51,6 +60,7 @@ python engine would meter them; either corrupts the certification).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, List, Optional
 
 import numpy as np
@@ -173,14 +183,14 @@ def run_program(dist, program: RoundProgram, *, engine: Optional[str] = None,
 # python engine — the per-call reference
 # --------------------------------------------------------------------------
 
-def _engine_faults(dist):
+def active_faults(dist):
     """The communicator's active fault schedule, if any."""
     f = getattr(getattr(dist, "comm", None), "faults", None)
     return f if f is not None and f.active else None
 
 
 def _run_python(dist, program, measure, history) -> EngineResult:
-    faults = _engine_faults(dist)
+    faults = active_faults(dist)
     crash_at = None
     snap = flat = None
     if faults is not None and faults.crash_round is not None \
@@ -264,28 +274,42 @@ def _segment_xs(seg: Segment) -> np.ndarray:
     return np.arange(seg.count, dtype=np.int32)
 
 
-def _capture_schedule(dist, seg: Segment, carry, xs: np.ndarray):
-    """One abstract trace of the step against a scratch ledger: the
-    per-round op schedule (records + rounds + round-boundary marks) this
-    segment will replay."""
-    real = dist.comm.ledger
-    scratch = CommLedger()
-    dist.comm.ledger = scratch
-    dist.comm._tracing = True   # captured schedules stay fault-free;
-    try:                        # the ledger replay injects the faults
-        x_abs = jax.ShapeDtypeStruct(xs.shape[1:], xs.dtype)
-        jax.eval_shape(lambda c, x: seg.step(dist, c, x), carry, x_abs)
-    finally:
-        dist.comm.ledger = real
-        dist.comm._tracing = False
-    return list(scratch.records), scratch.rounds, list(scratch.round_marks)
-
-
 def scheduled_channel(dist):
     """The communicator's channel iff it is round-scheduled (the case
     where the scan engines must thread the round index), else None."""
     chan = getattr(getattr(dist, "comm", None), "channel", None)
     return chan if getattr(chan, "scheduled", False) else None
+
+
+def scan_input(xs, count: int, round_base: int, rounds_per_step: int,
+               scheduled: bool):
+    """A segment's scanned input: ``xs`` itself, or under a scheduled
+    channel ``(global round index, xs)`` pairs.  The index of repeat
+    ``k`` is ``round_base + k * rounds_per_step``; the schedule is a
+    pure function of it, so it is data-independent and precomputed."""
+    if not scheduled:
+        return xs
+    rid = round_base + np.arange(count, dtype=np.int32) * rounds_per_step
+    return jnp.asarray(rid), xs
+
+
+def round_fn(dist, step: Callable, scheduled: bool) -> Callable:
+    """``step`` as the scan body's ``fn(carry, x) -> (carry, w_k)``.
+    Under a scheduled channel ``x`` is a ``scan_input`` pair and the
+    round index is pinned (``begin_round``) for the step's channel
+    transforms, so the stage switches mid-scan."""
+    if not scheduled:
+        return lambda carry, x: step(dist, carry, x)
+
+    def fn(carry, rx):
+        rk, x = rx
+        dist.comm.begin_round(rk)
+        try:
+            return step(dist, carry, x)
+        finally:
+            dist.comm.reset_round()
+
+    return fn
 
 
 def trace_closure(fn: Callable, *example_args):
@@ -301,6 +325,76 @@ def trace_closure(fn: Callable, *example_args):
         return jax.tree.unflatten(out_tree, out)
 
     return closed, pure
+
+
+@dataclasses.dataclass
+class Capture:
+    """One trace of a metered function: the ``trace_closure`` split and
+    the ledger schedule the trace recorded — its records, the rounds it
+    ended and the round-boundary marks (record positions)."""
+
+    closed: Any                      # ClosedJaxpr
+    pure: Callable                   # pure(consts, *args)
+    records: list
+    rounds: int
+    marks: list
+
+    @property
+    def consts(self) -> list:
+        return self.closed.consts
+
+    @functools.cached_property
+    def structure(self) -> str:
+        """The jaxpr's text, consts abstracted: equal for two traces of
+        one computation on different data."""
+        return str(self.closed.jaxpr)
+
+    @property
+    def schedule(self):
+        """``(records, rounds, marks)``, as ``replay_schedule`` takes
+        them."""
+        return self.records, self.rounds, self.marks
+
+
+def capture(dist, fn: Callable, *example_args) -> Capture:
+    """Trace ``fn`` once against a scratch ledger.  The captured schedule
+    stays fault-free (``comm._tracing``): the ledger replay injects the
+    faults.  Every trace-once engine captures through here."""
+    comm = dist.comm
+    real, comm.ledger = comm.ledger, CommLedger()
+    comm._tracing = True
+    try:
+        closed, pure = trace_closure(fn, *example_args)
+        led = comm.ledger
+    finally:
+        comm.ledger = real
+        comm._tracing = False
+    return Capture(closed, pure, list(led.records), led.rounds,
+                   list(led.round_marks))
+
+
+def trace_segment(dist, seg: Segment, carry) -> Capture:
+    """Capture one round of ``seg`` at ``carry``: the traced scan body
+    (``round_fn``; under a scheduled channel it takes a symbolic round
+    index) and the per-round schedule the segment replays."""
+    scheduled = scheduled_channel(dist) is not None
+    x = jnp.asarray(_segment_xs(seg)[0])
+    return capture(dist, round_fn(dist, seg.step, scheduled), carry,
+                   (jnp.int32(0), x) if scheduled else x)
+
+
+def trace_segments(dist, program: RoundProgram) -> List[Capture]:
+    """``trace_segment`` for every segment of ``program`` at its initial
+    carry; segments sharing a step and an input signature share one
+    capture."""
+    by_key, out = {}, []
+    for seg in program.segments:
+        xs = _segment_xs(seg)
+        key = (id(seg.step), xs.dtype.str, xs.shape[1:])
+        if key not in by_key:
+            by_key[key] = trace_segment(dist, seg, program.init)
+        out.append(by_key[key])
+    return out
 
 
 # Closed-over arrays at least this large enter a compiled program as
@@ -349,14 +443,10 @@ def hoisted_jit(fn: Callable) -> Callable:
 
 def _build_runner(dist, step: Callable, measure, history, scheduled: bool):
     collect_w = history and measure is None
+    fn = round_fn(dist, step, scheduled)
 
     def body(carry, x):
-        if scheduled:
-            # xs carry (global round index, per-round input): pin the
-            # index so the channel transform switches stages mid-scan.
-            rk, x = x
-            dist.comm.begin_round(rk)
-        carry, w = step(dist, carry, x)
+        carry, w = fn(carry, x)
         if measure is not None:
             with jax.named_scope(GAP_SCOPE):
                 return carry, measure(w)
@@ -369,7 +459,7 @@ def _run_scan(dist, program, measure, history,
               session: EngineSession) -> EngineResult:
     ledger = dist.comm.ledger
     chan = scheduled_channel(dist)
-    faults = _engine_faults(dist)
+    faults = active_faults(dist)
     carry = program.init
     outs, rounds = [], 0
     # Spans, once per segment (``repro.metrics.spans``): ``repro.runner``
@@ -381,8 +471,8 @@ def _run_scan(dist, program, measure, history,
         with span("repro.runner"):
             sched_key = (seg.step, xs.dtype.str, xs.shape[1:])
             if sched_key not in session.schedules:
-                session.schedules[sched_key] = _capture_schedule(
-                    dist, seg, carry, xs)
+                session.schedules[sched_key] = trace_segment(
+                    dist, seg, carry).schedule
             records, rounds_per_step, marks = session.schedules[sched_key]
             run_key = (seg.step, measure, history, chan is not None)
             runner = session.runners.get(run_key)
@@ -390,16 +480,11 @@ def _run_scan(dist, program, measure, history,
                 runner = _build_runner(dist, seg.step, measure, history,
                                        chan is not None)
                 session.runners[run_key] = runner
-        xs_arg = jnp.asarray(xs)
-        if chan is not None:
-            # Global round index per scan step, precomputed as scanned
-            # xs (the schedule is a pure function of the round index, so
-            # this is data-independent): ledger.algo_rounds is exact
-            # here — every prior segment has already been replayed, and
-            # recovery rounds never shift the channel schedule.
-            rid = ledger.algo_rounds + np.arange(
-                seg.count, dtype=np.int32) * rounds_per_step
-            xs_arg = (jnp.asarray(rid), xs_arg)
+        # ledger.algo_rounds is exact here: every prior segment has
+        # already been replayed, and recovery rounds never shift the
+        # channel schedule.
+        xs_arg = scan_input(jnp.asarray(xs), seg.count, ledger.algo_rounds,
+                            rounds_per_step, chan is not None)
         # The compiled run records nothing: any trace-time metering goes
         # to a throwaway ledger (jit may or may not retrace — either way
         # the schedule replay below is the single source of truth).
@@ -409,8 +494,6 @@ def _run_scan(dist, program, measure, history,
                 carry, out = jax.block_until_ready(runner(carry, xs_arg))
         finally:
             dist.comm.ledger = ledger
-            if chan is not None:
-                dist.comm.reset_round()
         if measure is not None or history:
             outs.append(out)
         with span("repro.ledger_replay"):

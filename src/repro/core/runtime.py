@@ -57,8 +57,9 @@ an algorithm's rounds run as a per-call Python loop (``"python"``) or as
 one ``lax.scan``-compiled XLA program (``"scan"``).  ``ShardedProgram``
 accepts a step-form ``RoundProgram`` builder to compile the whole
 multi-round run, in-scan gap measure included, inside the ``shard_map``
-body; the ledger is expanded from the trace-once schedule to the same
-per-call stream the python loop produces.
+body.  Segment capture, round-index threading and the ledger replay
+are ``core.engine``'s, shared with the local engines, so the sharded
+ledger is the same per-call stream the python loop produces.
 
 All three axes are front-ended by ``repro.api``: a ``RunSpec`` names
 placement/backend/engine declaratively, ``plan`` resolves the ``auto``
@@ -70,7 +71,7 @@ shim is retired (it raises, naming the ``RunSpec`` replacement).
 from __future__ import annotations
 
 import functools
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import numpy as np
 import jax
@@ -523,6 +524,18 @@ def _mesh_and_data(prob: ERMProblem, mesh: Optional[Mesh], axis: str):
     return mesh, axis, A, pad
 
 
+class ScanSpan(NamedTuple):
+    """One scanned segment of a traced ``ShardedProgram``: its step's
+    records ``[start, end)`` in the trace-time ledger, rounds and
+    round-boundary marks (relative to ``start``), and scan length."""
+
+    start: int
+    end: int
+    rounds: int
+    count: int
+    marks: list
+
+
 class ShardedProgram:
     """An algorithm run under ``shard_map`` with the data matrix
     column-sharded over ``axis``, traced and compiled once.  (Machinery
@@ -538,13 +551,18 @@ class ShardedProgram:
     * ``"scan"`` — ``program_builder(dist, rounds) -> RoundProgram``
       (step-form, see ``core.engine``) is compiled segment-by-segment
       with ``lax.scan`` inside the shard_map body, so the traced program
-      is one scan per segment regardless of the round budget. Each
-      segment's step traces ONCE; the ledger is expanded from the
-      captured per-step schedule to the identical per-round stream the
-      python mode records.  ``measure(dist, w_loc) -> scalar`` (scan
-      only) is evaluated after every round under the ``repro.gap``
-      scope, as the local engine's in-scan measure is, and the run
-      returns its (K,) series.
+      is one scan per segment regardless of the round budget.  The scan
+      body and its scanned input are ``core.engine``'s ``round_fn`` and
+      ``scan_input``; under a round-scheduled channel each step is
+      first captured (``trace_segment``) for its rounds, which the
+      scanned round index needs.  ``measure(dist, w_loc) -> scalar``
+      (scan only) is evaluated after every round under the
+      ``repro.gap`` scope, as the local engine's in-scan measure is,
+      and the run returns its (K,) series.
+
+    Either way the ledger is the trace-time records of each
+    ``ScanSpan`` replayed ``count`` times through
+    ``CommLedger.replay_schedule``, as the local engines replay theirs.
 
     ``backend`` picks the oracle compute path (see
     ``resolve_oracle_backend``).  Calling the program runs it and meters
@@ -584,7 +602,8 @@ class ShardedProgram:
         self._check_vma = (self.backend not in ("kernel", "fused")
                            and engine != "scan")
         self.ledger = CommLedger()      # the trace-time records
-        self.spans = []     # (start, end, rounds_traced, count) per segment
+        self.spans = []     # ScanSpans: one per scanned segment, or
+                            # the python mode's whole unrolled run
         self._compiled = None
 
     def _body(self, A_loc, y):
@@ -593,51 +612,41 @@ class ShardedProgram:
                               self.prob.n, axis=self.axis, ledger=led,
                               backend=self.backend, channel=self.chan)
         if self.engine == "python":
-            return self.algorithm_body(dist, self.rounds)
-        from .engine import GAP_SCOPE
+            w = self.algorithm_body(dist, self.rounds)
+            # the unrolled run is one span, metered as traced
+            self.spans.append(ScanSpan(0, len(led.records), led.rounds, 1,
+                                       list(led.round_marks)))
+            return w
+        from .engine import GAP_SCOPE, round_fn, scan_input, trace_segment
         program = self.program_builder(dist, self.rounds)
         measure = self.measure
         carry, gaps = program.init, []
-        # run-time global round base of the next scanned segment (python
-        # int: each segment's rounds-per-step is concrete at trace time)
-        run_base = 0
+        run_base = 0        # global round index of the next segment's start
         for seg in program.segments:
             xs = (jnp.asarray(seg.xs) if seg.xs is not None
                   else jnp.arange(seg.count, dtype=jnp.int32))
-            start, r0 = len(led.records), led.rounds
+            # the scanned round index needs the step's rounds before
+            # lax.scan traces it: capture the step first
+            rps = (trace_segment(dist, seg, carry).rounds
+                   if self.scheduled else 0)
+            start, r0, m0 = len(led.records), led.rounds, len(led.round_marks)
 
-            def measured(c, w):
+            def scan_body(c, x, _fn=round_fn(dist, seg.step, self.scheduled)):
+                c, w = _fn(c, x)
                 if measure is None:
                     return c, None
                 with jax.named_scope(GAP_SCOPE):
                     return c, measure(dist, w)
 
-            def scan_body(c, x, _step=seg.step):
-                return measured(*_step(dist, c, x))
-
-            def sched_body(cr, x, _step=seg.step):
-                # scheduled channel: thread the global round index as a
-                # carried counter so the transform switches stages
-                # mid-scan; the per-step advance is concrete at trace
-                # time (the ledger meters eagerly while tracing).
-                c, rk = cr
-                dist.comm.begin_round(rk)
-                r_in = led.rounds
-                c, w = _step(dist, c, x)
-                dist.comm.reset_round()
-                c, out = measured(c, w)
-                return (c, rk + (led.rounds - r_in)), out
-
-            if self.scheduled:
-                (carry, _), out = lax.scan(
-                    sched_body, (carry, jnp.int32(run_base)), xs)
-            else:
-                carry, out = lax.scan(scan_body, carry, xs)
+            carry, out = lax.scan(
+                scan_body, carry,
+                scan_input(xs, seg.count, run_base, rps, self.scheduled))
             gaps.append(out)
-            r_traced = led.rounds - r0
-            run_base += r_traced * seg.count
-            self.spans.append((start, len(led.records), r_traced,
-                               seg.count))
+            rps = led.rounds - r0
+            self.spans.append(ScanSpan(
+                start, len(led.records), rps, seg.count,
+                [m - start for m in led.round_marks[m0:]]))
+            run_base += rps * seg.count
         w = program.final(carry)
         return w if measure is None else (w, jnp.concatenate(gaps))
 
@@ -676,52 +685,22 @@ class ShardedProgram:
         with span("repro.run"):
             out = jax.block_until_ready(self._compiled(self.A, self.prob.y))
         w, gaps = (out, None) if self.measure is None else out
-        led = ledger if ledger is not None else CommLedger()
         with span("repro.ledger_replay"):
-            self._meter(led)
+            # each span's trace-time schedule, repeated ``count`` times;
+            # scheduled prices are set from round 0, where the compiled
+            # channel stages start, so a caller's ledger takes the
+            # replay appended as it stands
+            led = CommLedger()
+            chan = self.chan if self.scheduled else None
+            for sp in self.spans:
+                led.replay_schedule(self.ledger.records[sp.start:sp.end],
+                                    sp.rounds, sp.marks, sp.count,
+                                    channel=chan)
+            if ledger is not None:
+                ledger.replay_schedule(led.records, led.rounds,
+                                       led.round_marks, 1)
+                led = ledger
         return (w[:self.prob.d] if self.pad else w), gaps, led
-
-    def _meter(self, led: CommLedger) -> None:
-        """Append the run's per-round stream to ``led``.  Scanned
-        segments expand their trace-once schedule: each segment's single
-        traced step stream repeats ``count`` times, reproducing the
-        per-round stream — round-boundary marks included — the python
-        mode records bit-identically.  Marks are record positions into
-        the trace-time stream; each region's marks are rebased onto the
-        expanded stream as the region is copied."""
-        records, marks = self.ledger.records, self.ledger.round_marks
-        expanded = []
-        new_marks = [m for m in marks if m == 0]
-        rnd, prev_end = 0, 0
-        for start, end, r_traced, count in self.spans:
-            # records (and any marks) traced outside the scans, if ever
-            new_marks.extend(len(expanded) + (m - prev_end)
-                             for m in marks if prev_end < m <= start)
-            expanded.extend(records[prev_end:start])
-            span_records = records[start:end]
-            span_marks = [m - start for m in marks if start < m <= end]
-            for _ in range(count):
-                base = len(expanded)
-                if self.scheduled:
-                    # trace-time prices are provisional (the round index
-                    # was a tracer): re-price each repeat from its
-                    # global round base, as the scan-engine replay does.
-                    from .comm import repriced_records
-                    expanded.extend(repriced_records(
-                        span_records, span_marks, rnd, self.chan))
-                else:
-                    expanded.extend(span_records)
-                new_marks.extend(base + m for m in span_marks)
-                rnd += r_traced
-            prev_end = end
-        new_marks.extend(len(expanded) + (m - prev_end)
-                         for m in marks if m > prev_end)
-        expanded.extend(records[prev_end:])
-        base = len(led.records)
-        led.records.extend(expanded)
-        led.round_marks.extend(base + m for m in new_marks)
-        led.rounds += self.ledger.rounds + sum(
-            r_traced * (count - 1) for _, _, r_traced, count in self.spans)
 
 
 def _run_sharded(prob: ERMProblem, algorithm_body: Optional[Callable],

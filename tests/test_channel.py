@@ -325,15 +325,25 @@ def test_execute_batch_groups_by_channel():
     assert [r.batched for r in execute_batch(mixed)] == [False, False]
 
 
-def test_sharded_placement_accepts_channel():
+@pytest.mark.parametrize("channel", ["fp16", "sched:int8@0,fp16@1"])
+def test_sharded_placement_accepts_channel(channel):
+    """The sharded placement meters the local run's stream record for
+    record, round marks included; under a round-scheduled channel each
+    round is priced at its own stage.  The int8 stage carries round 0's
+    upload, A 0 = 0, so the iterates stay comparable at the fp16
+    tolerance: on later rounds int8's rounding would turn the last-ulp
+    differences between the local einsum and the shard's matvec into
+    whole quanta."""
     from repro.api import RunSpec, run
     base = dict(instance="random_ridge",
                 instance_params=dict(n=16, d=12, m=1),
                 algorithm="dagd", rounds=6, measure="none")
-    loc = run(RunSpec(**base, channel="fp16"))
-    sh = run(RunSpec(**base, channel="fp16", placement="sharded"))
-    assert sh.channel == "fp16"
+    loc = run(RunSpec(**base, channel=channel))
+    sh = run(RunSpec(**base, channel=channel, placement="sharded"))
+    assert sh.channel == channel
     assert sh.ledger.total_bits() == loc.ledger.total_bits()
+    assert sh.ledger.typed_stream() == loc.ledger.typed_stream()
+    assert sh.ledger.round_marks == loc.ledger.round_marks
     assert len(sh.ledger.round_marks) == sh.ledger.rounds
     np.testing.assert_allclose(np.asarray(sh.w), np.asarray(loc.w),
                                atol=1e-5, rtol=1e-5)

@@ -99,7 +99,7 @@ def _stream(led):
 
 out = {}
 for name in sorted(ALGORITHM_REGISTRY):
-    iterates, op_counts, local_streams = {}, {}, {}
+    iterates, op_counts, local_streams, scan_streams = {}, {}, {}, {}
     for be in ORACLE_BACKENDS:
         for eng in ENGINES:
             dist = LocalDistERM(prob, part, backend=be)
@@ -121,6 +121,8 @@ for name in sorted(ALGORITHM_REGISTRY):
                                                                  **kw()))
             iterates[f"sharded/{be}/{eng}"] = w_sh
             op_counts[f"sharded/{be}/{eng}"] = led.op_counts()
+            if eng == "scan":
+                scan_streams[f"sharded/{be}/{eng}"] = _stream(led)
     ref = iterates["local/einsum/python"]
     ref_ops = op_counts["local/einsum/python"]
     ref_stream = local_streams["local/einsum/python"]
@@ -131,6 +133,8 @@ for name in sorted(ALGORITHM_REGISTRY):
         "ops_agree": all(ops == ref_ops for ops in op_counts.values()),
         "local_streams_identical": all(s == ref_stream
                                        for s in local_streams.values()),
+        "sharded_scan_streams_identical": all(
+            s == ref_stream for s in scan_streams.values()),
     }
 print(json.dumps(out))
 """
@@ -159,7 +163,8 @@ def test_shard_map_parity():
 def test_backend_conformance_matrix():
     """Every registered algorithm x {Local, Sharded} x {einsum, kernel,
     fused} x {python, scan}: matching final iterates, identical per-run
-    op counts, and (Local) bit-identical ledger record streams."""
+    op counts, and bit-identical ledger record streams for every local
+    run and every sharded scan run."""
     out = _run_script(MATRIX_SCRIPT)
     assert len(out) >= 6          # the six reference algorithms
     expected = sorted(f"{ex}/{be}/{eng}"
@@ -171,3 +176,4 @@ def test_backend_conformance_matrix():
         assert rec["max_diff"] < 1e-4, (name, rec)
         assert rec["ops_agree"], (name, rec)
         assert rec["local_streams_identical"], (name, rec)
+        assert rec["sharded_scan_streams_identical"], (name, rec)
