@@ -61,8 +61,8 @@ from jax import lax
 
 from ..core.comm import CommLedger, inject_crash_recovery
 from ..core.engine import (GAP_SCOPE, Capture, _segment_xs, active_faults,
-                           capture, scan_input, scheduled_channel,
-                           trace_segments)
+                           capture, count_fold, fold_measure, scan_input,
+                           scheduled_channel, trace_segments)
 from ..metrics.spans import span
 from .plan import ExecutionPlan, PlanError, RunResult
 
@@ -74,9 +74,10 @@ class Cell:
 
     plan: ExecutionPlan
     dist: object
-    program: object
+    program: object                   # as ``fold_measure`` leaves it
     steps: List[Capture]              # one per segment (shared by identity)
     meas: Optional[Capture]
+    folded: bool                      # rounds emit (w_k, A_j w_k)
 
     def group_key(self) -> tuple:
         """The grouping axis: cells batch iff their keys are equal.
@@ -126,16 +127,20 @@ def prepare_cell(plan: ExecutionPlan) -> Optional[Cell]:
 
 def _trace_cell(plan: ExecutionPlan, dist, program,
                 measure_fn) -> Cell:
+    program, measure_fn, folded = fold_measure(program, measure_fn)
     steps, meas = trace_segments(dist, program), None
     if measure_fn is not None:
         # every registered program emits the round iterate in stacked
-        # block form (m, d_max) — the same shape zeros_like_w builds
-        meas = capture(dist, measure_fn, dist.zeros_like_w())
+        # block form (m, d_max) — the same shape zeros_like_w builds —
+        # and a folded one the machines' responses (m, n) beside it
+        w = dist.zeros_like_w()
+        meas = capture(dist, measure_fn,
+                       (w, dist.zeros_like_response()) if folded else w)
         if meas.records or meas.rounds:
             raise PlanError("measure performed metered communication; "
                             "measurement must stay oracle-free")
     return Cell(plan=plan, dist=dist, program=program, steps=steps,
-                meas=meas)
+                meas=meas, folded=folded)
 
 
 # --------------------------------------------------------------------------
@@ -218,6 +223,8 @@ def _execute_group(cells: List[Cell],
         if meas0 is not None:
             outs.append(out)                        # (count, C)
     gaps_all = np.asarray(jnp.concatenate(outs, axis=0)) if outs else None
+    if cells[0].folded:     # group_key pins the steps, so all or none
+        count_fold(len(cells) * len(progs[0].segments))
 
     # all cells in a group share the fault schedule (group_key pins it);
     # each cell's replay draws its own fault stream into its own ledger

@@ -42,7 +42,7 @@ from jax.sharding import Mesh
 from ..core.bounds import (BoundReport, thm2_strongly_convex,
                            thm3_smooth_convex, thm4_incremental)
 from ..core.comm import CommLedger
-from ..core.engine import EngineSession, run_program
+from ..core.engine import EngineSession, Measure, run_program
 from ..experiments.instances import INSTANCE_BUILDERS, InstanceBundle, \
     build_instance, builds_sharded, instance_shape
 from ..experiments.registry import ALGORITHM_REGISTRY, AlgorithmSpec, \
@@ -290,8 +290,17 @@ class ExecutionPlan:
                 # subtraction produced; see execute_batch grouping)
                 fstar = jnp.float32(b.fstar)
 
-                def measure_fn(w_stk):
+                def at(w_stk):
                     return objective(dist.gather_w(w_stk)) - fstar
+
+                def from_response(out):
+                    return dist.objective_from(*out) - fstar
+
+                # the ERM objective reads the round's own response; one
+                # with a regularizer of its own (lasso) keeps its pass
+                measure_fn = Measure(
+                    at, from_response if objective == b.prob.value
+                    else None)
 
             self._cell_cache = (dist, program, measure_fn)
         return self._cell_cache
@@ -411,9 +420,9 @@ class ExecutionPlan:
                     f"ERM objective from the blocks — measure it with "
                     f"placement='local'")
             fstar = jnp.float32(b.fstar)
-
-            def measure(dist, w_loc):
-                return dist.objective(w_loc) - fstar
+            measure = Measure(
+                lambda dist, w_loc: dist.objective(w_loc) - fstar,
+                lambda dist, out: dist.objective_from(*out) - fstar)
 
         if engine == "python":
             return ShardedProgram(

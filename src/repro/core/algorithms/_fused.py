@@ -26,6 +26,25 @@ records, identical tags/shapes/pricing); the iterates agree with the
 composed ``kernel`` backend's to f32 rounding, since the whole-block
 dots and the composed tilings may round their sums differently in the
 last ulp (``tests/test_ledger_invariance.py`` pins both).
+
+**The folded round.**  A measured run evaluates f(x_new) after every
+round, which needs A x_new: a pass over A of its own, as long as the
+round's one A-side pass is the response at y.  The composed program
+therefore offers a ``folded`` variant (``RoundProgram.folded``, taken
+by ``core.engine.fold_measure`` when a run's measure can read a
+response) under the same rotation: the carry holds machine j's pending
+upload ``zloc = A_j y``, zeros at round 0 as the fused program's, and
+each round reduces it through the ordinary metered ReduceAll (the
+channel transforms it at this round's stage, as the composed round's
+``response`` does), takes the partial gradient and the update, then
+makes one two-vector pass ``A_j [x_new, y_new]`` (the backend's
+``response_pair``, ``feature_matvec`` at B = 2): it carries A_j y_new
+and hands A_j x_new to the measure.  Each column of that pass is
+bit-identical to the one-vector response, so the iterates, the ledger
+and its marks equal the composed program's; only the gap's sum of A x
+runs over the blocks' partial sums.  The fold engages only with a
+measure, on a backend with ``response_pair`` (``kernel`` and ``fused``,
+not ``einsum``), and where the whole-round kernel does not.
 """
 from __future__ import annotations
 
@@ -42,7 +61,8 @@ def linear_round_program(dist, rounds: int, update, *,
                          name: str = "") -> RoundProgram:
     """The RoundProgram of a response->pgrad->update round: the fused
     one-kernel program where the dist's backend can rotate the round,
-    else the composed one, both built from the same ``update``.
+    else the composed one (with its folded variant where the backend
+    offers the two-vector response), all built from the same ``update``.
 
     ``update(x, y, g, coeff) -> (x_new, y_new)`` is the algorithm's
     block-local update (elementwise over the coordinate blocks; the
@@ -53,29 +73,47 @@ def linear_round_program(dist, rounds: int, update, *,
     """
     zero = dist.zeros_like_w()
     no_coeff = jnp.float32(0.0)
+
+    def coeff_of(x):
+        return x if xs is not None else no_coeff
+
+    def program(step, init, folded=None):
+        return RoundProgram(init=init,
+                            segments=[Segment(step, rounds, xs=xs,
+                                              name=name)],
+                            final=lambda c: c[0], folded=folded)
+
     maker = getattr(dist, "fused_round_step", None)  # local placement only
     step_fn = maker(update) if maker is not None else None
-    if step_fn is None:
-        def step(dist, carry, x):
-            x_c, y_c = carry
-            z = dist.response(y_c)
-            g = dist.pgrad(y_c, z)
-            x_n, y_n = update(x_c, y_c, g, x if xs is not None else no_coeff)
-            dist.end_round()
-            return (x_n, y_n), x_n
-
-        init = (zero, zero)
-    else:
-        def step(dist, carry, x):
+    if step_fn is not None:
+        def fused_step(dist, carry, x):
             x_c, y_c, zloc = carry
-            z = dist.reduce_response(zloc)
-            coeff = x if xs is not None else no_coeff
+            z = dist.reduce_response(zloc, pretransformed=True)
             rnd = dist.comm._round_index()
-            x_n, y_n, zloc_n = step_fn(z, x_c, y_c, coeff, rnd)
+            x_n, y_n, zloc_n = step_fn(z, x_c, y_c, coeff_of(x), rnd)
             dist.end_round()
             return (x_n, y_n, zloc_n), x_n
 
-        init = (zero, zero, jnp.zeros((dist.part.m, dist.n)))
-    return RoundProgram(init=init,
-                        segments=[Segment(step, rounds, xs=xs, name=name)],
-                        final=lambda c: c[0])
+        return program(fused_step, (zero, zero, dist.zeros_like_response()))
+
+    def step(dist, carry, x):
+        x_c, y_c = carry
+        z = dist.response(y_c)
+        g = dist.pgrad(y_c, z)
+        x_n, y_n = update(x_c, y_c, g, coeff_of(x))
+        dist.end_round()
+        return (x_n, y_n), x_n
+
+    def folded_step(dist, carry, x):
+        x_c, y_c, zloc = carry
+        z = dist.reduce_response(zloc)
+        g = dist.pgrad(y_c, z)
+        x_n, y_n = update(x_c, y_c, g, coeff_of(x))
+        zx, zy = dist.local_response_pair(x_n, y_n)
+        dist.end_round()
+        return (x_n, y_n, zy), (x_n, zx)
+
+    folded = (program(folded_step,
+                      (zero, zero, dist.zeros_like_response()))
+              if dist.backend_impl.response_pair else None)
+    return program(step, (zero, zero), folded)

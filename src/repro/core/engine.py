@@ -56,6 +56,19 @@ gap series instead of a ``(K, m, d_max)`` iterate history.  ``measure``
 must not call metered oracles — it is measurement, not communication
 (the scan engine would bake its ops into the replayed schedule and the
 python engine would meter them; either corrupts the certification).
+
+The measure of f(w_k) needs A w_k, a pass over A of its own, unless the
+round already makes it.  ``fold_measure`` is the one hook every runner
+(the python and scan engines, ``repro.api.batch.execute_group``,
+``core.runtime.ShardedProgram``) passes its program and measure through:
+where the program offers a folded round (``RoundProgram.folded``: the
+composed first-order round whose response pass gives machine j both
+A_j x_new, handed to the measure, and A_j y_new, carried to the next
+round, ``core/algorithms/_fused.py``) and the measure can read a
+response (``Measure.from_response``), the run takes both, and the
+measure's own pass over A is gone.  Otherwise, and with no measure,
+nothing changes.  Each segment run folded ticks the ``repro.gap.folded``
+span once (``count_fold``), so ``spans.snapshot()`` shows the fold.
 """
 from __future__ import annotations
 
@@ -83,6 +96,10 @@ ENGINES = ("python", "scan")
 # The gap measure's ops carry this ``jax.named_scope`` in their HLO
 # metadata (``op_name``), so a device trace can tell them from the round's.
 GAP_SCOPE = "repro.gap"
+
+# A zero-length span, once per segment run with the measure folded into
+# the round's response pass (``fold_measure``).
+FOLD_SPAN = "repro.gap.folded"
 
 
 def resolve_engine(engine: Optional[str] = None) -> str:
@@ -130,15 +147,56 @@ class Segment:
 
 @dataclasses.dataclass
 class RoundProgram:
-    """An algorithm run: initial carry, round segments, final extractor."""
+    """An algorithm run: initial carry, round segments, final extractor,
+    and optionally the same run ``folded`` for an in-scan measure: equal
+    iterates and ledger, each round emitting ``(w_k, zloc_k)``, with
+    machine j's unreduced response A_j w_k (see ``fold_measure``)."""
 
     init: Any                        # carry pytree
     segments: List[Segment]
     final: Callable                  # carry -> final iterate w
+    folded: Optional["RoundProgram"] = None
 
     @property
     def rounds(self) -> int:
         return sum(seg.count for seg in self.segments)
+
+
+class Measure:
+    """An in-scan measure that can also read the round's response.
+
+    Calling it calls ``at`` (``w_k -> scalar``, or ``(dist, w_k) ->
+    scalar`` under ``ShardedProgram``).  ``from_response``, where given,
+    evaluates the same quantity from a folded round's output ``(w_k,
+    zloc_k)`` in place of ``w_k``, with the same leading arguments,
+    without a pass over A of its own."""
+
+    __slots__ = ("at", "from_response")
+
+    def __init__(self, at: Callable,
+                 from_response: Optional[Callable] = None):
+        self.at, self.from_response = at, from_response
+
+    def __call__(self, *args):
+        return self.at(*args)
+
+
+def fold_measure(program: RoundProgram, measure):
+    """``(program, measure, folded)``: the program a run executes and the
+    measure it applies to each round's output.  Where ``program`` offers
+    a folded run and ``measure`` can read a response, the folded program
+    and ``measure.from_response``; else both as given."""
+    from_response = getattr(measure, "from_response", None)
+    if from_response is None or program.folded is None:
+        return program, measure, False
+    return program.folded, from_response, True
+
+
+def count_fold(segments: int) -> None:
+    """Tick ``FOLD_SPAN`` once for each of ``segments`` segment runs."""
+    for _ in range(segments):
+        with span(FOLD_SPAN):
+            pass
 
 
 @dataclasses.dataclass
@@ -166,17 +224,23 @@ def run_program(dist, program: RoundProgram, *, engine: Optional[str] = None,
     """Execute a round program against a ``DistERM`` backend.
 
     ``measure``: traceable ``w_k -> scalar`` folded into the run as a
-    per-round output (the ``(K,)`` gap series).  ``history``: collect the
-    raw per-round iterates instead (debugging / parity; materializes
-    ``(K, m, d_max)``).  The two are mutually exclusive.
+    per-round output (the ``(K,)`` gap series); a ``Measure`` may read
+    the round's response instead (``fold_measure``).  ``history``:
+    collect the raw per-round iterates instead (debugging / parity;
+    materializes ``(K, m, d_max)``).  The two are mutually exclusive.
     """
     if measure is not None and history:
         raise ValueError("measure and history are mutually exclusive")
     engine = resolve_engine(engine)
+    program, measure, folded = fold_measure(program, measure)
     if engine == "python":
-        return _run_python(dist, program, measure, history)
-    return _run_scan(dist, program, measure, history,
-                     session if session is not None else EngineSession())
+        res = _run_python(dist, program, measure, history)
+    else:
+        res = _run_scan(dist, program, measure, history,
+                        session if session is not None else EngineSession())
+    if folded:
+        count_fold(len(program.segments))
+    return res
 
 
 # --------------------------------------------------------------------------

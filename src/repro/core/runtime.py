@@ -151,9 +151,16 @@ class OracleBackend:
     a fused one-kernel round step for the cell, or ``None`` when the
     backend (or the cell's channel/shape) cannot rotate the round —
     callers must then compose the round from the oracles above.
+
+    ``response_pair`` says whether the backend offers the two-vector
+    response ``response_pair_local``/``response_pair_shard``: machine
+    j's unreduced A_j u_j and A_j v_j from one pass over A_j, each
+    bit-identical to ``response_*`` on that vector alone.  The folded
+    first-order round (``core/algorithms/_fused.py``) needs it.
     """
 
     name: str = ""
+    response_pair: bool = False
 
     # ---- local placement: blocks stacked on a leading (m, ...) axis ----
     def response_local(self, dist, w_stk):
@@ -173,6 +180,13 @@ class OracleBackend:
         raise NotImplementedError
 
     def phvp_shard(self, dist, v_loc, h, av):
+        raise NotImplementedError
+
+    # ---- two-vector response capability ----
+    def response_pair_local(self, dist, u_stk, v_stk):
+        raise NotImplementedError
+
+    def response_pair_shard(self, dist, u_loc, v_loc):
         raise NotImplementedError
 
     # ---- whole-round capability ----
@@ -209,13 +223,24 @@ class EinsumBackend(OracleBackend):
 
 
 class KernelBackend(OracleBackend):
-    """The Pallas GEMV kernels (a VPU body at one right-hand side, MXU
-    tiles at several), composed with jnp epilogues."""
+    """The Pallas GEMV kernels (a VPU body at one right-hand side, and at
+    two for ``feature_matvec``; MXU tiles at more), composed with jnp
+    epilogues."""
 
     name = "kernel"
+    response_pair = True
 
     def response_local(self, dist, w_stk):
         return jax.vmap(kops.feature_matvec)(dist.A_stk, w_stk)
+
+    def response_pair_local(self, dist, u_stk, v_stk):
+        z = jax.vmap(kops.feature_matvec)(dist.A_stk,
+                                          jnp.stack([u_stk, v_stk], -1))
+        return z[..., 0], z[..., 1]
+
+    def response_pair_shard(self, dist, u_loc, v_loc):
+        z = kops.feature_matvec(dist.A_loc, jnp.stack([u_loc, v_loc], -1))
+        return z[:, 0], z[:, 1]
 
     def pgrad_local(self, dist, w_stk, lgrad):
         g = jax.vmap(kops.feature_rmatvec,
@@ -329,17 +354,29 @@ class LocalDistERM:
     def zeros_like_w(self):
         return jnp.zeros((self.part.m, self.part.d_max))
 
+    def zeros_like_response(self):
+        """Every machine's unreduced response summand, zero: (m, n)."""
+        return jnp.zeros((self.part.m, self.n))
+
     def response(self, w_stk, tag="z=Aw"):
         """z = sum_j A_j w_j : one ReduceAll of an R^n vector."""
-        local = self.backend_impl.response_local(self, w_stk)
-        return self.comm.reduce_all(local, tag=tag)
+        return self.reduce_response(
+            self.backend_impl.response_local(self, w_stk), tag=tag)
 
-    def reduce_response(self, zloc_stk, tag="z=Aw"):
-        """Reduce per-machine response summands a fused round-step
-        already computed AND channel-transformed in-kernel: the same
-        metered ReduceAll as ``response`` (record, pricing, faults all
-        byte-identical), minus the redundant second wire transform."""
-        return self.comm.reduce_all(zloc_stk, tag=tag, pretransformed=True)
+    def reduce_response(self, zloc_stk, tag="z=Aw", pretransformed=False):
+        """The metered ReduceAll of per-machine response summands
+        computed earlier (the rotated rounds of ``_fused.py``): the same
+        record, pricing and faults as ``response``.  ``pretransformed``
+        when a fused round-step already applied the channel in-kernel,
+        which skips the redundant second wire transform."""
+        return self.comm.reduce_all(zloc_stk, tag=tag,
+                                    pretransformed=pretransformed)
+
+    def local_response_pair(self, u_stk, v_stk):
+        """Machine j's unreduced A_j u_j and A_j v_j, (m, n) each, from
+        one pass over each A_j: local compute, nothing metered (where
+        the backend offers ``response_pair``)."""
+        return self.backend_impl.response_pair_local(self, u_stk, v_stk)
 
     def pgrad(self, w_stk, z):
         """f'_j(w) for every j, stacked — local compute only."""
@@ -378,6 +415,13 @@ class LocalDistERM:
         """f(w) given reduced z (needs one scalar reduce for |w|^2)."""
         sq = self.dot(w_stk, w_stk, tag="|w|^2")
         return jnp.sum(self.loss.value(z, self.y)) / self.n + 0.5 * self.lam * sq
+
+    def objective_from(self, w_stk, zloc_stk):
+        """The ERM objective f(w) from the machines' unreduced responses
+        A_j w_j, for the folded in-scan measure: its sum over machines
+        is measurement, not communication, so nothing is metered."""
+        return self.loss.full_value(jnp.sum(zloc_stk, axis=0), self.y,
+                                    self.gather_w(w_stk), self.lam)
 
     def end_round(self):
         self._round_cache.clear()
@@ -429,9 +473,18 @@ class ShardedDistERM:
     def zeros_like_w(self):
         return jnp.zeros((self.A_loc.shape[1],))
 
+    def zeros_like_response(self):
+        return jnp.zeros((self.n,))
+
     def response(self, w_loc, tag="z=Aw"):
-        local = self.backend_impl.response_shard(self, w_loc)
-        return self.comm.reduce_all(local, tag=tag)
+        return self.reduce_response(
+            self.backend_impl.response_shard(self, w_loc), tag=tag)
+
+    def reduce_response(self, zloc, tag="z=Aw"):
+        return self.comm.reduce_all(zloc, tag=tag)
+
+    def local_response_pair(self, u_loc, v_loc):
+        return self.backend_impl.response_pair_shard(self, u_loc, v_loc)
 
     def pgrad(self, w_loc, z):
         lgrad = self._loss_term("grad", z)
@@ -462,10 +515,14 @@ class ShardedDistERM:
         self.comm.end_round()
 
     def objective(self, w_loc):
-        """f(w) from this machine's block, for the in-scan measure: its
-        two psums are measurement, not communication, so nothing is
-        metered (the local placement's measure reads the whole A)."""
-        z = lax.psum(self.A_loc @ w_loc, self.comm.axis)
+        """f(w) from this machine's block, for the in-scan measure."""
+        return self.objective_from(w_loc, self.A_loc @ w_loc)
+
+    def objective_from(self, w_loc, zloc):
+        """f(w) from this machine's block and its unreduced response
+        A_j w_j (the folded round hands it over): its two psums are
+        measurement, not communication, so nothing is metered."""
+        z = lax.psum(zloc, self.comm.axis)
         sq = lax.psum(jnp.vdot(w_loc, w_loc), self.comm.axis)
         return jnp.sum(self.loss.value(z, self.y)) / self.n \
             + 0.5 * self.lam * sq
@@ -558,7 +615,8 @@ class ShardedProgram:
       scanned round index needs.  ``measure(dist, w_loc) -> scalar``
       (scan only) is evaluated after every round under the
       ``repro.gap`` scope, as the local engine's in-scan measure is,
-      and the run returns its (K,) series.
+      and the run returns its (K,) series; program and measure pass
+      through ``core.engine.fold_measure``, as every runner's do.
 
     Either way the ledger is the trace-time records of each
     ``ScanSpan`` replayed ``count`` times through
@@ -604,6 +662,7 @@ class ShardedProgram:
         self.ledger = CommLedger()      # the trace-time records
         self.spans = []     # ScanSpans: one per scanned segment, or
                             # the python mode's whole unrolled run
+        self.folded = False     # the traced program folds its measure
         self._compiled = None
 
     def _body(self, A_loc, y):
@@ -617,9 +676,10 @@ class ShardedProgram:
             self.spans.append(ScanSpan(0, len(led.records), led.rounds, 1,
                                        list(led.round_marks)))
             return w
-        from .engine import GAP_SCOPE, round_fn, scan_input, trace_segment
-        program = self.program_builder(dist, self.rounds)
-        measure = self.measure
+        from .engine import (GAP_SCOPE, fold_measure, round_fn, scan_input,
+                             trace_segment)
+        program, measure, self.folded = fold_measure(
+            self.program_builder(dist, self.rounds), self.measure)
         carry, gaps = program.init, []
         run_base = 0        # global round index of the next segment's start
         for seg in program.segments:
@@ -685,6 +745,9 @@ class ShardedProgram:
         with span("repro.run"):
             out = jax.block_until_ready(self._compiled(self.A, self.prob.y))
         w, gaps = (out, None) if self.measure is None else out
+        if self.folded:
+            from .engine import count_fold
+            count_fold(len(self.spans))
         with span("repro.ledger_replay"):
             # each span's trace-time schedule, repeated ``count`` times;
             # scheduled prices are set from round 0, where the compiled

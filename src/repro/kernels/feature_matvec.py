@@ -9,19 +9,25 @@ GEMVs per round on each machine:
 Each kernel has two bodies on one accumulation grid, chosen by the width
 B of the right-hand side:
 
-* B > 1 (DISCO-F's batched CG, the benchmark's panels): tall-skinny
-  matmuls on the MXU.  A_j is tiled into row-major VMEM blocks
-  (MXU-aligned, or a whole shorter dimension) and the right-hand side
-  into BLOCK_B-wide panels of its own (a third grid axis), so a wide
-  panel (B > 128) never forces the whole panel into one block.
-* B = 1 (every round of every first-order solve): a GEMV on the VPU in
-  f32.  An MXU product would pad the vector to a 128-lane panel and
-  multiply it in six bf16 passes, which makes the kernel MXU-bound on
-  127 lanes of zeros; the VPU needs two f32 operations per element of
-  A_j and is bound by the one pass over A_j in HBM.  The body reads
-  A_j^T, a (d_j, n) array with n along the lanes, in tiles of
-  ``GEMV_TILE_BYTES``: z = A_j w sums ``At * w[:, None]`` over the
-  sublanes into a lane-dense row of z, and A_j^T r sums
+* B > 2 for ``feature_matvec``, B > 1 for the A_j^T kernels (DISCO-F's
+  batched CG, the benchmark's panels): tall-skinny matmuls on the MXU.
+  A_j is tiled into row-major VMEM blocks (MXU-aligned, or a whole
+  shorter dimension) and the right-hand side into BLOCK_B-wide panels
+  of its own (a third grid axis), so a wide panel (B > 128) never
+  forces the whole panel into one block.
+* B = 1 (every round of every first-order solve), and B = 2 for
+  ``feature_matvec`` (the folded round's A_j [x_new, y_new], which
+  feeds the in-scan gap and the next round's response): a GEMV on the
+  VPU in f32.  An MXU product would pad the vectors to a 128-lane panel
+  and multiply it in six bf16 passes, which makes the kernel MXU-bound
+  on lanes of zeros; the VPU needs two f32 operations per element of
+  A_j and right-hand side and is bound by the one pass over A_j in HBM.
+  The body reads A_j^T, a (d_j, n) array with n along the lanes, in
+  tiles of ``GEMV_TILE_BYTES`` per right-hand side: z = A_j w sums
+  ``At * w[:, None]`` over the sublanes into a lane-dense row of z, one
+  row per column of w, each chunk of A_j^T loaded once for both
+  columns, and each column summed in exactly the one-column order, so
+  it is bit-identical to a call on that column alone; A_j^T r sums
   ``At * r[None, :]`` over 128-lane groups and then across the lanes
   into a column of g.  Every R^n vector crosses HBM lane-dense, with no
   pad.  The call runs under the ``repro.gemv`` scope.  Its work grows
@@ -34,12 +40,12 @@ block stays resident in VMEM while partial products accumulate into it
 
 A_j is read where it lies, never copied to the block grid.  A dimension
 no longer than its block takes the whole dimension as its block (the
-B = 1 body rounds it up to whole native tiles instead: 128 lanes, 8 f32
+VPU body rounds it up to whole native tiles instead: 128 lanes, 8 f32
 or 16 bf16 rows); a longer one that is not a multiple of its block ends
 in a block that overhangs the array.  Past the array's end a TPU reads
 unspecified values (NaN among them, and NaN x 0 is NaN), so on the last
 block along the contraction axis the overhang is kept out of the sums:
-the MXU body zeroes the A tile past the extent in VMEM, and the B = 1
+the MXU body zeroes the A tile past the extent in VMEM, and the VPU
 body skips the whole row chunks or lane groups past it and masks the
 one it cuts.  Every other block runs the unmasked body, and a shape its
 blocks divide traces no mask at all.  Overhang along the other axis
@@ -48,7 +54,7 @@ body's vectors (w, r, h, masks) are padded to its grid.
 
 XLA may keep A_j with its rows as the minor dimension (it does for
 400,000 x 500 on a v5e, the layout with the least padding).  That is
-A_j^T in row-major tiles, what the B = 1 body reads; the MXU body reads
+A_j^T in row-major tiles, what the VPU body reads; the MXU body reads
 row-major tiles of A_j, so XLA inserts one copy of A_j into that layout
 before it.  A jitted loop that holds A_j fixed hoists such a copy out of
 the loop, so it is paid once a solve, not once a round.
@@ -76,15 +82,22 @@ BLOCK_N = 512
 BLOCK_D = 512
 BLOCK_B = 128
 
-# The B = 1 body's A_j^T tile: BLOCK_D rows (or all of d_j) by as many
-# 128-lane columns of n as fit this many bytes, so that a grid step's
-# fixed cost stays a few percent of its tile's HBM read.  Two tiles are
-# in flight, within the kernel's VMEM limit.
+# The VPU body's A_j^T tile: BLOCK_D rows (or all of d_j) by as many
+# 128-lane columns of n as fit this many bytes over the right-hand
+# sides, so that a grid step's fixed cost stays a few percent of its
+# tile's HBM read and the f32 accumulators of B columns take the
+# registers one column's took.  Two tiles are in flight, within the
+# kernel's VMEM limit.
 GEMV_TILE_BYTES = 8 * 1024 * 1024
 GEMV_VMEM_BYTES = 4 * GEMV_TILE_BYTES
 
+# The widest right-hand side the VPU body takes, by contraction axis:
+# z = A_j w (``"d"``) loads each chunk of A_j^T once for up to two
+# columns; the A_j^T products (``"n"``) take one.
+GEMV_MAX_B = {"d": 2, "n": 1}
+
 # Padding of the vectors to the block grid (A itself is read in place)
-# runs under this ``jax.named_scope``, and so does the B = 1 body's
+# runs under this ``jax.named_scope``, and so does the VPU body's
 # kernel, so their device ops carry it in their HLO ``op_name``.  Each
 # kernel's ``pallas_call`` is named for its entry point, whichever body
 # it runs: the name is the kernel's label in compiled programs and
@@ -127,7 +140,7 @@ def feature_matvec(A_j, w_j, *, block_n: int | None = None,
         [t.a(A_j), t.dvec(w_j)], t.nvec_spec(),
         t.nvec_shape(_acc_dtype(A_j.dtype)), "feature_matvec",
         interpret)
-    out = (out[0, :n, None] if t.gemv else out[:n, :b]).astype(A_j.dtype)
+    out = (out[:, :n].T if t.gemv else out[:n, :b]).astype(A_j.dtype)
     return out[:, 0] if squeeze else out
 
 
@@ -221,22 +234,23 @@ class _Tiles:
 
     ``contract`` names A_j's contraction axis, ``"d"`` for z = A_j w and
     ``"n"`` for the A_j^T products; the grid is (other axis, right-hand
-    side blocks, contraction axis).  At B = 1 (``gemv``) the operands
-    take the VPU body's layout: A_j^T, R^n vectors as (1, n) rows and
-    R^d vectors as (d_j, 1) columns, none of them padded; otherwise the
-    MXU body's: A_j, and vectors padded to the block grid.  A
-    ``block_n`` of None takes the body's own: BLOCK_N rows of A_j, or as
-    many lanes of A_j^T as fit ``GEMV_TILE_BYTES``."""
+    side blocks, contraction axis).  Up to ``GEMV_MAX_B`` right-hand
+    sides (``gemv``) the operands take the VPU body's layout: A_j^T,
+    R^n vectors as (B, n) rows and R^d vectors as (d_j, B) columns, none
+    of them padded; otherwise the MXU body's: A_j, and vectors padded to
+    the block grid.  A ``block_n`` of None takes the body's own:
+    BLOCK_N rows of A_j, or as many lanes of A_j^T as fit
+    ``GEMV_TILE_BYTES`` over the B right-hand sides."""
 
     def __init__(self, A_j, b, contract, block_n, block_d, block_b):
         n, dj = A_j.shape
-        self.gemv = b == 1
+        self.gemv = b <= GEMV_MAX_B[contract]
         self.contract = contract
         if self.gemv:
             self.bd = min(block_d, _rup(dj, _sublanes(A_j.dtype)))
-            fit = GEMV_TILE_BYTES // (self.bd * A_j.dtype.itemsize)
+            fit = GEMV_TILE_BYTES // (self.bd * A_j.dtype.itemsize * b)
             self.bn = min(block_n or max(128, fit // 128 * 128), _rup(n))
-            self.bb = 1
+            self.bb = b
         else:
             self.bd = min(block_d, dj)
             self.bn = min(block_n or BLOCK_N, n)
@@ -279,7 +293,7 @@ class _Tiles:
 
     def nvec_spec(self):
         if self.gemv:
-            return self._spec((1, self.bn), lambda i, j, k: (0, i))
+            return self._spec((self.bb, self.bn), lambda i, j, k: (0, i))
         return self._spec((self.bn, self.bb), lambda i, j, k: (i, k))
 
     def dvec_spec(self):
@@ -287,7 +301,7 @@ class _Tiles:
 
     def nvec_shape(self, dtype):
         n_out, b_out = self.n_blocks * self.bn, self.b_blocks * self.bb
-        shape = (1, n_out) if self.gemv else (n_out, b_out)
+        shape = (b_out, n_out) if self.gemv else (n_out, b_out)
         return jax.ShapeDtypeStruct(shape, dtype)
 
     def dvec_shape(self, dtype):
@@ -309,31 +323,43 @@ class _Tiles:
             return call(*args)
 
 
-# ---- the B = 1 body --------------------------------------------------------
+# ---- the VPU body ----------------------------------------------------------
 
 def _gemv_rows(a_ref, w_ref, o_ref, *, extent: int):
-    """o (1, bn) += sum over the tile's rows k of At[k, :] * w[k] in f32:
-    the z = A_j w side.  ``extent`` is d_j; on an overhanging last block
-    only its rows inside d_j count (the last partial chunk is masked,
-    since A and w may both be NaN past the end).  Chunks of one native
-    tile of rows accumulate elementwise, and the sublanes are summed once,
-    at the end."""
+    """o (B, bn) += sum over the tile's rows k of At[k, :] * w[k, c] in
+    f32, row c of o for each column c of w (B <= 2): the z = A_j w side.
+    ``extent`` is d_j; on an overhanging last block only its rows inside
+    d_j count (the last partial chunk is masked, since A and w may both
+    be NaN past the end).  Chunks of one native tile of rows accumulate
+    elementwise, and the sublanes are summed once, at the end.  Each
+    chunk of A_j^T is loaded once for every column, and each column
+    runs the one-column arithmetic in its order (the same chunks, tail
+    mask and sublane sum), so its row is bit-identical to a call on
+    that column alone."""
     sub = _sublanes(a_ref.dtype)
+    cols = w_ref.shape[1]
 
     def rows_dot(rows):
-        def part(start):
-            return (_f32(a_ref[pl.ds(start, sub), :])
-                    * _f32(w_ref[pl.ds(start, sub), :]))
+        def parts(start):
+            a = _f32(a_ref[pl.ds(start, sub), :])
+            return [a * _f32(w_ref[pl.ds(start, sub), pl.ds(c, 1)])
+                    for c in range(cols)]
+
+        def chunk(c, accs):
+            start = pl.multiple_of(c * sub, sub)
+            return [acc + p for acc, p in zip(accs, parts(start))]
 
         full, tail = divmod(rows, sub)
-        acc = lax.fori_loop(
-            0, full, lambda c, acc: acc + part(pl.multiple_of(c * sub, sub)),
-            jnp.zeros((sub, a_ref.shape[1]), jnp.float32))
+        accs = lax.fori_loop(
+            0, full, chunk,
+            [jnp.zeros((sub, a_ref.shape[1]), jnp.float32)] * cols)
         if tail:
-            t = part(full * sub)
-            row = lax.broadcasted_iota(jnp.int32, t.shape, 0)
-            acc = acc + jnp.where(row < tail, t, 0.0)
-        o_ref[...] += jnp.sum(acc, axis=0, keepdims=True)
+            ts = parts(full * sub)
+            row = lax.broadcasted_iota(jnp.int32, ts[0].shape, 0)
+            accs = [acc + jnp.where(row < tail, t, 0.0)
+                    for acc, t in zip(accs, ts)]
+        for c, acc in enumerate(accs):
+            o_ref[pl.ds(c, 1), :] += jnp.sum(acc, axis=0, keepdims=True)
 
     _with_extent(a_ref.shape[0], extent, rows_dot)
 

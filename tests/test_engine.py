@@ -4,7 +4,9 @@ schedules, in-scan gap measurement, and the runtime satellites (masked
 
 The heavier cross-product suites live in ``test_runtime_parity.py``
 (engines x oracle backends x execution backends, slow-marked) and
-``test_ledger_invariance.py``; this file is the fast tier-1 coverage.
+``test_ledger_invariance.py``; this file is the fast tier-1 coverage,
+and the folded round's check under ``ShardedProgram`` on four virtual
+devices (one subprocess).
 """
 import dataclasses
 
@@ -298,3 +300,112 @@ def test_hoisted_jit_passes_large_closures_as_arguments():
     finally:
         jax.config.update("jax_captured_constants_warn_bytes", before)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# The CPU backend's op fusion lets LLVM contract a multiply and an add
+# into one FMA where both land in one fused loop, which depends on the
+# whole program: the sharded scan bodies of the folded and the unfolded
+# round fuse their updates differently and so round differently in the
+# last bit.  With fusion off every op rounds on its own, and the two
+# programs' arithmetic can be compared bit for bit.
+SHARDED_FOLD_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=4 "
+                           "--xla_disable_hlo_passes=fusion")
+import json
+import numpy as np
+from repro import api
+from repro.core import runtime
+from repro.core.engine import FOLD_SPAN
+from repro.metrics import spans
+
+EPS = (0.3, 0.03)
+
+
+def run(algo, kw, backend, channel, audit):
+    pl = api.plan(api.RunSpec(
+        instance="logistic",
+        instance_params=dict(n=520, d=40, m=4, lam=1e-4, seed=0),
+        algorithm=algo, rounds=12, eps=EPS, eps_mode="rel",
+        placement="sharded", backend=backend, channel=channel,
+        algo_kwargs=kw))
+    before = spans.snapshot()
+    res = pl.execute()
+    folds = spans.since(before)["spans"].get(FOLD_SPAN, {}).get("count", 0)
+    hlo = pl.audit_hlo_bytes() if audit else None
+    return dict(
+        w=np.asarray(res.w).tobytes().hex(), gaps=np.asarray(res.gaps),
+        stream=repr(res.ledger.typed_stream()),
+        marks=list(res.ledger.round_marks), folds=folds,
+        rounds=[res.measured_rounds(pl.eps_abs(e)) for e in EPS],
+        hlo=hlo and [hlo.total_bytes, hlo.wire_bytes,
+                     sorted(hlo.measure_bytes_by_op.items())],
+        ulp=float(np.finfo(np.float32).eps * abs(pl.bundle.fstar)))
+
+
+out = {}
+cases = [(a, kw, be, "identity")
+         for a, kw in [("dagd", {}), ("dagd", {"lam": 0.0}), ("dgd", {}),
+                       ("prox_dagd", {})]
+         for be in ("kernel", "fused")]
+cases += [("dagd", {}, be, "sched:int8@0,fp16@4")
+          for be in ("kernel", "fused")]
+cases += [("dagd", {}, "einsum", "identity")]
+for algo, kw, be, ch in cases:
+    audit = algo == "dagd" and not kw        # one audit a wire and backend
+    folded = run(algo, kw, be, ch, audit)
+    runtime.KernelBackend.response_pair = False
+    try:
+        plain = run(algo, kw, be, ch, audit)
+    finally:
+        runtime.KernelBackend.response_pair = True
+    key = f"{algo}{'-fista' if kw else ''}/{be}/{ch}"
+    out[key] = dict(
+        folds=[folded["folds"], plain["folds"]],
+        w_equal=folded["w"] == plain["w"],
+        stream_equal=folded["stream"] == plain["stream"],
+        marks_equal=folded["marks"] == plain["marks"],
+        gap_diff=float(np.max(np.abs(folded["gaps"] - plain["gaps"]))),
+        ulp=folded["ulp"], rounds=[folded["rounds"], plain["rounds"]],
+        hlo=[folded["hlo"], plain["hlo"]])
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def sharded_fold():
+    import json
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    proc = subprocess.run([sys.executable, "-c", SHARDED_FOLD_SCRIPT],
+                          env=env, capture_output=True, text=True,
+                          timeout=900)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_sharded_folded_round_matches_the_unfolded_round(sharded_fold):
+    """``ShardedProgram`` on four virtual devices folds the same way:
+    for ``dagd`` (both branches), ``dgd`` and ``prox_dagd`` on ``kernel``
+    and ``fused``, and under a scheduled channel, the folded program's
+    iterates, typed stream and marks are the unfolded one's, its gaps
+    agree to f32 rounding with the same rounds to eps, and (for ``dagd``
+    on each wire and backend) the HLO-bytes audit of the module
+    ``execute()`` runs is unchanged: the measure's psums stay
+    measurement.  ``einsum`` does not fold."""
+    assert len(sharded_fold) == 11
+    for key, rec in sharded_fold.items():
+        want = [0, 0] if key.startswith("dagd/einsum") else [1, 0]
+        assert rec["folds"] == want, key
+        assert rec["w_equal"] and rec["stream_equal"], key
+        assert rec["marks_equal"], key
+        assert rec["gap_diff"] <= 8 * rec["ulp"], (key, rec)
+        assert rec["rounds"][0] == rec["rounds"][1], (key, rec)
+        assert None not in rec["rounds"][0], key
+        assert rec["hlo"][0] == rec["hlo"][1], (key, rec)
+        if key.startswith("dagd/"):
+            assert rec["hlo"][0][2], key      # the measure's psums

@@ -313,6 +313,55 @@ def test_gemv_and_mxu_bodies_agree(name):
             atol=1e-5 * np.abs(panel[:, col]).max())
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("n,dj,blocks", [
+    (1100, 300, "small"), (513, 129, "small"), (1100, 100, "small"),
+    (20_000, 500, "default"), (9_000, 289, "default")],
+    ids=["ragged", "overhang", "short-d", "epsilon-like", "ocr-like"])
+def test_two_vector_body_columns_are_one_vector_calls(n, dj, blocks,
+                                                      dtype):
+    """``feature_matvec`` at B = 2 (the folded round's A_j [x, y]) loads
+    each chunk of A_j^T once for both columns, and each column is
+    bit-identical to the B = 1 call on that column alone: ragged and
+    overhanging blocks at small tiles, where reads past the end give
+    NaN, and the default tiles at an epsilon-like block (many lane
+    tiles, 500 rows of A_j^T) and an ocr-like one (289)."""
+    kw = (dict(SMALL_BLOCKS) if blocks == "small"
+          else dict(interpret=NAN_PAST_END))
+    ks = jax.random.split(jax.random.PRNGKey(n + dj), 2)
+    A = jax.random.normal(ks[0], (n, dj)).astype(dtype)
+    W = jax.random.normal(ks[1], (dj, 2)).astype(dtype)
+    two = np.asarray(feature_matvec(A, W, **kw))
+    assert two.shape == (n, 2) and np.isfinite(two).all()
+    for col in range(2):
+        one = np.asarray(feature_matvec(A, W[:, col], **kw))
+        np.testing.assert_array_equal(two[:, col], one)
+
+
+@pytest.mark.parametrize("b", [1, 2, 3])
+def test_feature_matvec_takes_the_vpu_body_up_to_two_columns(b):
+    """The width picks the body: at the epsilon block, B = 1 and B = 2
+    read A_j^T in VPU tiles (at B = 2 half the lanes, the same bytes a
+    right-hand side) under the ``repro.gemv`` scope, and B = 3 keeps the
+    MXU body's 512-row tiles of A_j.  The A_j^T kernels take the VPU
+    body at B = 1 only."""
+    call = _composed_call("feature_matvec", EPS_N, EPS_DJ, b)
+    grid = call.params["grid_mapping"]
+    got = [getattr(x, "block_size", x)
+           for x in grid.block_mappings[0].block_shape]
+    if b <= 2:
+        lanes = GEMV_EPS_TILE[1] // b
+        assert got == [GEMV_EPS_TILE[0], lanes]
+        assert sorted(grid.grid) == [1, 1, -(-EPS_N // lanes)]
+    else:
+        assert got == [512, 500]
+    assert ("repro.gemv" in str(call.source_info.name_stack)) == (b <= 2)
+    rgrid = _composed_call("feature_rmatvec", EPS_N, EPS_DJ,
+                           2).params["grid_mapping"]
+    assert [getattr(x, "block_size", x) for x in
+            rgrid.block_mappings[0].block_shape] == [512, 500]
+
+
 def test_batched_rhs_matches_loop():
     k = jax.random.PRNGKey(3)
     A = jax.random.normal(k, (64, 40))

@@ -301,3 +301,107 @@ def test_faults_none_leaves_ledger_bit_identical():
     assert led_none.recovery_rounds == 0
     assert led_none.retransmit_bits() == 0
     assert not any(r[-1] for r in led_none.typed_stream())
+
+
+# ---- the folded round: the in-scan gap rides the response pass ----------
+
+# A local logistic cell too tall for the whole-round kernel (n = 520 pads
+# past one 512-row tile), so the fused backend composes its round too;
+# lam = 1e-4 keeps the gap above f32 noise for the rounds run.
+FOLD_ROUNDS, FOLD_EPS = 12, (0.3, 0.03)
+FOLD_ALGOS = {"dagd": {}, "dagd-fista": dict(lam=0.0), "dgd": {},
+              "prox_dagd": {}}
+
+
+def _fold_plans(algo, backend, engine, channel, seeds):
+    from repro import api
+    return [api.plan(api.RunSpec(
+        instance="logistic",
+        instance_params=dict(n=520, d=40, m=4, lam=1e-4, seed=s),
+        algorithm=algo.split("-")[0], rounds=FOLD_ROUNDS, eps=FOLD_EPS,
+        eps_mode="rel", placement="local", backend=backend, engine=engine,
+        channel=channel, algo_kwargs=FOLD_ALGOS[algo])) for s in seeds]
+
+
+def _counted_run(runner, plans):
+    """The runs of ``plans`` under ``runner`` (``group``: one
+    ``execute_group``), and how often they ticked the fold span."""
+    from repro.api.batch import execute_group, prepare_cell
+    from repro.core.engine import FOLD_SPAN
+    from repro.metrics import spans
+
+    before = spans.snapshot()
+    if runner == "group":
+        cells = [prepare_cell(p) for p in plans]
+        assert len({c.group_key() for c in cells}) == 1
+        results = execute_group(cells)
+    else:
+        results = [p.execute() for p in plans]
+    moved = spans.since(before)["spans"].get(FOLD_SPAN, {})
+    return results, moved.get("count", 0)
+
+
+@pytest.mark.parametrize("channel", ["identity", "sched:int8@0,fp16@4"])
+@pytest.mark.parametrize("runner", ["scan", "python", "group"])
+@pytest.mark.parametrize("backend", ["kernel", "fused"])
+@pytest.mark.parametrize("algo", sorted(FOLD_ALGOS))
+def test_folded_round_matches_the_unfolded_round(monkeypatch, algo, backend,
+                                                 runner, channel):
+    """A measured run on a backend with the two-vector response folds the
+    gap's pass over A into the round's response pass.  Against the same
+    run with the capability withdrawn (today's round, and the measure's
+    own pass): bit-identical iterates, the same typed ledger stream and
+    marks, gaps equal to f32 rounding and the same rounds to eps, under
+    the local scan engine, the python engine and ``execute_group``."""
+    from repro.core.runtime import KernelBackend
+
+    engine = "python" if runner == "python" else "scan"
+    seeds = (0, 1) if runner == "group" else (0,)
+    args = (algo, backend, engine, channel, seeds)
+    folded, n_folded = _counted_run(runner, _fold_plans(*args))
+    with monkeypatch.context() as mp:
+        mp.setattr(KernelBackend, "response_pair", False)
+        plans = _fold_plans(*args)
+        plain, n_plain = _counted_run(runner, plans)
+    assert (n_folded, n_plain) == (len(seeds), 0)     # one segment a solve
+    for got, ref, pl in zip(folded, plain, plans):
+        assert np.array_equal(np.asarray(got.w), np.asarray(ref.w))
+        assert got.ledger.typed_stream() == ref.ledger.typed_stream()
+        assert got.ledger.round_marks == ref.ledger.round_marks
+        # the gap sums A x over the blocks' partials, not in one dot: a
+        # few f32 ulps of f(w) apart
+        ulp = np.finfo(np.float32).eps * abs(pl.bundle.fstar)
+        np.testing.assert_allclose(got.gaps, ref.gaps, rtol=0, atol=8 * ulp)
+        for eps in FOLD_EPS:
+            want = ref.measured_rounds(pl.eps_abs(eps))
+            assert want is not None
+            assert got.measured_rounds(pl.eps_abs(eps)) == want
+
+
+@pytest.mark.parametrize("case", ["einsum", "measure-none", "thm2-fused"])
+def test_fold_stays_off_where_the_round_has_no_response_pass(case):
+    """No fold without the two-vector response (``einsum``), without a
+    measure, or where the whole-round kernel runs the round (the
+    ``thm2-serve`` cell's fused single-tile rounds): the fold span does
+    not tick, and those runs are today's."""
+    from repro import api
+
+    spec = dict(instance="logistic",
+                instance_params=dict(n=520, d=40, m=4, lam=1e-4),
+                algorithm="dagd", rounds=FOLD_ROUNDS, eps=FOLD_EPS,
+                eps_mode="rel", placement="local", backend="kernel")
+    if case == "einsum":
+        spec.update(backend="einsum")
+    elif case == "measure-none":
+        spec.update(eps=(), measure="none")
+    else:
+        spec.update(instance="thm2_chain",
+                    instance_params=dict(d=24, kappa=16.0, lam=0.5, m=4),
+                    eps=(1e-3,), eps_mode="abs", backend="fused")
+    pl = api.plan(api.RunSpec(**spec))
+    if case == "thm2-fused":
+        dist, _, _ = pl._cell()
+        assert dist.fused_round_step(lambda x, y, g, c: (x, y)) is not None
+    for runner in ("scan", "group"):
+        _, n_folded = _counted_run(runner, [pl])
+        assert n_folded == 0, runner

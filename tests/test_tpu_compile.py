@@ -184,6 +184,22 @@ def test_gemv_body_compiles_at_deployment_blocks(one_chip, name, block):
     assert compiled.memory_analysis().temp_size_in_bytes < n * 4 * 8
 
 
+@pytest.mark.parametrize("block", sorted(GEMV_BLOCKS))
+def test_two_vector_body_compiles_at_deployment_blocks(one_chip, block):
+    """``feature_matvec`` at B = 2, the folded round's A_j [x, y]: the
+    VPU body loads column c of the (d_j, 2) panel at lane offset c and
+    stores row c of the (2, n) output, which interpret mode accepts
+    whatever the chip's compiler says; no copy of A_j, and no lane panel
+    of z."""
+    n, dj = GEMV_BLOCKS[block]
+    compiled = jax.jit(
+        lambda A, w: feature_matvec(A, w, interpret=False)).lower(
+        _shape(one_chip, (n, dj)), _shape(one_chip, (dj, 2))).compile()
+    text = compiled.as_text()
+    assert "feature_matvec" in text and "tpu_custom_call" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < n * 4 * 8
+
+
 # Every composed kernel, at a block a little wider than one tile.
 NAMED_N, NAMED_DJ = 4096, 500
 NAMED = {
